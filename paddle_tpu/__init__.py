@@ -23,10 +23,7 @@ def _configure_jax():
     """
     import jax
 
-    try:
-        jax.config.update("jax_default_prng_impl", "rbg")
-    except Exception:
-        pass  # older/newer jax without the option — keep defaults
+    jax.config.update("jax_default_prng_impl", "rbg")
 
 
 _configure_jax()

@@ -26,7 +26,8 @@ Env knobs:
   nothing is written, all block sizes / thresholds fall back to their
   hand-set defaults (bit-exact pre-autotune behavior);
 * ``PADDLE_TPU_AUTOTUNE_CACHE`` — cache file path (default
-  ``~/.cache/paddle_tpu/autotune-v1.json``).
+  ``<checkout>/.autotune_cache/autotune-v1.json``: inside the checkout,
+  so what a run decides never depends on a file outside it).
 """
 
 import json
@@ -58,11 +59,13 @@ def autotune_enabled():
 
 
 def cache_path():
-    """``PADDLE_TPU_AUTOTUNE_CACHE`` or the per-user default."""
+    """``PADDLE_TPU_AUTOTUNE_CACHE`` or the per-checkout default."""
     p = os.environ.get("PADDLE_TPU_AUTOTUNE_CACHE", "").strip()
     if p:
         return p
-    return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, ".autotune_cache",
                         "autotune-v%d.json" % SCHEMA_VERSION)
 
 

@@ -13,6 +13,44 @@ import enum
 import numpy as np
 
 
+def configure_compile_cache():
+    """Place JAX's persistent compilation cache; returns the directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache is left entirely
+    to JAX and nothing is set in code.  Otherwise it lives at
+    ``<checkout>/.jax_cache`` — a fixed path, because the path is part of
+    the cache key and a directory that moves never hits.  Called by the
+    entry points that compile on the chip (``chip_smoke.py``, ``bench.py``
+    children, ``tools/``) before their first compile."""
+    import os
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    cache_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
+def require_tpu(what):
+    """The first device, for code that measures or validates the chip
+    (``bench.py`` chip children, ``tools/``): any backend but the TPU is
+    an error here, never a fallback — ``TPUPlace()`` itself resolves to
+    whatever backend there is, because tests drive it on the CPU."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            "%s runs on the chip only and JAX reports platform %r — "
+            "refusing to run" % (what, dev.platform))
+    return dev
+
+
 class VarDesc:
     """Namespace mirroring the reference's VarDesc proto enums
     (``framework.proto:105-163``)."""

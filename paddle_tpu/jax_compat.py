@@ -1,63 +1,32 @@
-"""Version-compatibility shims for the pinned jax.
+"""The jax names the codebase reaches through one module.
 
-The container pins jax 0.4.x while parts of this codebase were written
-against newer jax: ``jax.shard_map`` only became a top-level export
-(with ``check_rep`` renamed ``check_vma``) after 0.4, and
-``Lowered.as_text(debug_info=True)`` grew the kwarg later too.  Every
-post-0.4 API goes through here so a version gap degrades gracefully
-instead of killing ~150 tier-1 tests at import time (the PR-2 lesson —
-see ``ops/registry.py``'s ``jax.typeof`` guard).
+Written for the installed jax (0.9): ``jax.shard_map`` with the
+``check_vma`` spelling, ``jax.lax.axis_size``, and
+``Lowered.as_text(debug_info=...)``.  The module stays so that call sites
+(and tests) keep one import point for them.
 """
 
+import jax
+
 __all__ = ["shard_map", "lowered_as_text", "axis_size"]
-
-try:  # jax >= 0.6: top-level export, check_vma spelling
-    from jax import shard_map as _shard_map
-
-    _NATIVE_VMA = True
-except ImportError:  # jax 0.4.x: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NATIVE_VMA = False
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None,
               check_vma=None, **kw):
-    """``jax.shard_map`` across jax versions: resolves the export
-    location and translates ``check_vma`` to the old ``check_rep``
-    spelling when running on 0.4.x."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
+    """``jax.shard_map`` with positional ``mesh``/``in_specs``/
+    ``out_specs`` (the call shape used throughout ``parallel/``)."""
     if check_vma is not None:
-        kwargs["check_vma" if _NATIVE_VMA else "check_rep"] = check_vma
-    return _shard_map(f, **kwargs)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (post-0.4) or its 0.4.x equivalent — a
-    ``psum(1)`` over the axis, which XLA constant-folds to the same
-    static mesh-axis size without emitting a collective."""
-    import jax
-
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a mapped mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def lowered_as_text(lowered, debug_info=False):
-    """``jax.stages.Lowered.as_text`` with the ``debug_info`` kwarg
-    when this jax supports it.  On 0.4.x (no such kwarg, and the plain
-    text drops location metadata) a debug request renders the MLIR
-    module with ``enable_debug_info`` instead, which carries the same
-    ``named_scope`` attribution the profiler tooling greps for."""
-    try:
-        return lowered.as_text(debug_info=debug_info)
-    except TypeError:
-        if debug_info:
-            try:
-                return lowered.compiler_ir().operation.get_asm(
-                    enable_debug_info=True)
-            except Exception:  # pragma: no cover - fall through
-                pass
-        return lowered.as_text()
+    """``jax.stages.Lowered.as_text``; ``debug_info=True`` keeps the
+    ``named_scope`` location metadata the profiler tooling greps for."""
+    return lowered.as_text(debug_info=debug_info)

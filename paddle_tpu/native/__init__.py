@@ -12,6 +12,7 @@ active.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,7 +20,6 @@ import threading
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_HERE, "_paddle_tpu_native.so")
 _SOURCES = ["recordio.cc", "multislot.cc", "blocking_queue.cc"]
 
 _lib = None
@@ -27,19 +27,28 @@ _lib_lock = threading.Lock()
 _build_attempted = False
 
 
-def _build():
-    srcs = [os.path.join(_HERE, "src", s) for s in _SOURCES]
+def _so_path(srcs):
+    """The library is named by a digest of its sources, so a binary built
+    from other sources (a stale ``.so`` that travelled with a copy of the
+    tree, whatever its mtime) is never the one that gets loaded."""
+    digest = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    return os.path.join(
+        _HERE, "_paddle_tpu_native-%s.so" % digest.hexdigest()[:12])
+
+
+def _build(srcs, so_path):
+    tmp = "%s.%d.tmp" % (so_path, os.getpid())
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++14", "-pthread",
-           "-o", _SO_PATH] + srcs
+           "-o", tmp] + srcs
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so_path)  # atomic: concurrent builders agree
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
-
-
-def _newest_mtime(paths):
-    return max(os.path.getmtime(p) for p in paths)
 
 
 def get_lib():
@@ -50,16 +59,15 @@ def get_lib():
         if _lib is not None:
             return _lib
         srcs = [os.path.join(_HERE, "src", s) for s in _SOURCES]
-        stale = (not os.path.exists(_SO_PATH)
-                 or os.path.getmtime(_SO_PATH) < _newest_mtime(srcs))
-        if stale:
+        so_path = _so_path(srcs)
+        if not os.path.exists(so_path):
             if _build_attempted:
                 return None
             _build_attempted = True
-            if not _build():
+            if not _build(srcs, so_path):
                 return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(so_path)
         except OSError:
             return None
         # signatures

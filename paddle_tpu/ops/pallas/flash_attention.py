@@ -35,10 +35,10 @@ replicated across lanes; rows are recovered with a lanes-reduce and moved
 between row/column orientation with 2-D reshapes (both verified supported
 by Mosaic on v5e).
 
-Everything falls back to a pure-XLA implementation off-TPU or for shapes
-the kernel does not cover; set ``PADDLE_TPU_PALLAS=interpret`` to force the
-Pallas kernels in interpreter mode (CPU correctness tests), or ``=off`` to
-force the XLA path.
+Off-TPU, and for shapes the kernel does not cover, the entry point routes
+to a pure-XLA implementation; set ``PADDLE_TPU_PALLAS=interpret`` to force
+the Pallas kernels in interpreter mode (CPU correctness tests), or ``=off``
+to force the XLA path (see :func:`paddle_tpu.ops.pallas.use_pallas`).
 """
 
 import functools
@@ -48,44 +48,12 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:  # pallas itself may be absent/broken on older jax (the container
-    # pins 0.4.x — post-0.4 pallas API moves must not take the whole op
-    # library down; the XLA composite below is the supported fallback)
-    from jax.experimental import pallas as pl
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = None
-    _HAS_PALLAS = False
-
-try:  # pallas TPU backend may be absent on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from . import use_pallas
 
 NEG_INF = -1e30
-
-
-def pallas_supported():
-    """Whether the Pallas kernels CAN run here (import succeeded).  On
-    jax builds without a working ``jax.experimental.pallas`` every entry
-    point silently takes the pure-XLA composite, so the fusion-pass
-    plumbing (and tier-1 CPU tests) exercise the rewrites regardless."""
-    return _HAS_PALLAS
-
-
-def _use_pallas():
-    if not _HAS_PALLAS:
-        return False, False  # even PADDLE_TPU_PALLAS=interpret falls back
-    mode = os.environ.get("PADDLE_TPU_PALLAS", "auto")
-    if mode == "off":
-        return False, False
-    if mode == "interpret":
-        return True, True
-    return jax.default_backend() == "tpu" and _HAS_PLTPU, False
 
 
 def _row(x2d):
@@ -265,6 +233,7 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
 
     o, m_out, l_out = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -472,6 +441,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_specs,
         out_specs=[
@@ -520,6 +490,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_dq",
         grid=(bh, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -613,8 +584,8 @@ def _pick_blocks(tq, tk):
 
 def flash_min_t():
     """The sequence length at which the blocked Pallas kernel starts
-    beating XLA's fused unblocked attention.  r05 v5e sweep
-    (hw_results/bench_flash_sweep.txt): XLA wins at T=128 (model-level
+    beating XLA's fused unblocked attention.  Round-5 v5e sweep
+    (tools/bench_flash.py; the capture predates PR 1): XLA wins at T=128 (model-level
     +26%) and still edges the kernel at T=256 (attention-level 7-16%,
     both dropout regimes); the kernel wins at T=512 (+15% model-level,
     2.1x over XLA / 4.8x over the upstream jax kernel at T=2048) — so
@@ -654,8 +625,7 @@ def _kernel_applicable(q, k, bias):
     # dropout the break-even may sit lower, since the XLA path then pays
     # a materialized [B,H,T,T] mask the kernel never writes.
     min_t = flash_min_t()
-    if max(tq, tk) < min_t and \
-            os.environ.get("PADDLE_TPU_PALLAS") != "interpret":
+    if max(tq, tk) < min_t and not use_pallas()[1]:
         return False
     bq, bk = _pick_blocks(tq, tk)
     if tq % bq or tk % bk or bq < 8 or bq % 8 or bk < 128 or bk % 128:
@@ -724,7 +694,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
             "upscale by 1/0)" % dropout_rate)
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
-    use, interpret = _use_pallas()
+    use, interpret = use_pallas()
     debug = _dropout_debug()
     b, h, tq, _ = q.shape
     tk = k.shape[2]

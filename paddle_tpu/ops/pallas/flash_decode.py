@@ -11,7 +11,7 @@ skipping the chunks past the cursor entirely (a request 40 tokens into a
 shape-stable primitive — applied to the flash-decoding decomposition.
 
 Like ``flash_attention.py`` the kernel ships with an XLA composite
-(:func:`decode_reference`) that is both the CPU/GPU fallback and the
+(:func:`decode_reference`) that is both the off-TPU route and the
 numerical oracle (documented tolerance: ≤1e-5 relative); the Pallas path
 engages on TPU (or under ``PADDLE_TPU_PALLAS=interpret`` for CPU tests).
 
@@ -29,12 +29,14 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import (NEG_INF, _HAS_PALLAS, _HAS_PLTPU, pl, pltpu,
-                              pallas_supported, _use_pallas)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import use_pallas
+from .flash_attention import NEG_INF
 
 __all__ = [
-    "flash_decode", "decode_reference", "pallas_supported",
-    "decode_block_k", "decode_min_t",
+    "flash_decode", "decode_reference", "decode_block_k", "decode_min_t",
 ]
 
 # hand-set defaults: the pre-autotune behavior PADDLE_TPU_AUTOTUNE=0
@@ -177,6 +179,7 @@ def _flash_decode_call(q, k, v, lengths, sm_scale, block_k, interpret):
                                block_k=block_k)
     return pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -211,7 +214,7 @@ def flash_decode(q, k, v, lengths, sm_scale=None):
     t = k.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    use, interpret = _use_pallas()
+    use, interpret = use_pallas()
     block_k = decode_block_k(t, d)
     if (not use or t < decode_min_t()
             or not _kernel_applicable(t, d, block_k)):

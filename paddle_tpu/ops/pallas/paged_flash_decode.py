@@ -37,7 +37,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import NEG_INF, _HAS_PLTPU, pl, pltpu, _use_pallas
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import use_pallas
+from .flash_attention import NEG_INF
 from .flash_decode import decode_min_t, decode_reference, _norm_lengths
 
 __all__ = [
@@ -184,6 +188,7 @@ def _paged_flash_decode_call(q, k, v, lengths, table, sm_scale,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, 1, d), q.dtype),
         interpret=interpret,
@@ -208,7 +213,7 @@ def paged_flash_decode(q, k_cache, v_cache, lengths, table,
     mb = table.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    use, interpret = _use_pallas()
+    use, interpret = use_pallas()
     if not use or mb * bl < decode_min_t() or bl < 1:
         return paged_decode_reference(q, k_cache, v_cache, lengths,
                                       table, sm_scale=sm_scale)

@@ -260,18 +260,9 @@ def _make_generic_grad_def(fwd_def):
                 # under shard_map the primal may be varying over manual
                 # mesh axes; a freshly built cotangent is replicated and
                 # jax rejects the vma mismatch — promote it to match.
-                # (jax.typeof only exists on jax versions that track vma
-                # avals; without it there is no mismatch to repair)
-                _typeof = getattr(jax, "typeof", None)
-                missing = frozenset() if _typeof is None else (
-                    getattr(_typeof(p), "vma", frozenset())
-                    - getattr(_typeof(g), "vma", frozenset()))
+                missing = jax.typeof(p).vma - jax.typeof(g).vma
                 if missing:
-                    if hasattr(jax.lax, "pcast"):
-                        g = jax.lax.pcast(
-                            g, tuple(missing), to="varying")
-                    else:
-                        g = jax.lax.pvary(g, tuple(missing))
+                    g = jax.lax.pcast(g, tuple(missing), to="varying")
                 lst.append(g)
             cot[slot] = lst
         (gin,) = vjp_fn(cot)

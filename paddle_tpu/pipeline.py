@@ -85,12 +85,7 @@ def reset_sync_stats():
 def _block_all(dev_vals):
     import jax
 
-    blocker = getattr(jax, "block_until_ready", None)
-    if blocker is not None:
-        blocker(dev_vals)
-    else:  # pragma: no cover - very old jax
-        for v in dev_vals:
-            v.block_until_ready()
+    jax.block_until_ready(dev_vals)
 
 
 def host_values(values):

@@ -157,14 +157,24 @@ def conv_bn_min_bytes():
         return 4096
 
 
+# No slab is big enough by default.  On a TPU v5e (chip run, PR 22) the
+# Pallas gather took 2.2-5.7x XLA's own gather at every table measured
+# (30522x768, 512x768, 2x768 in f32 and bf16; 1000003x128 f32) and cost the
+# BERT-base seq128 step 1.0%: it moves whole 8-row tiles per id and pays a
+# grid step per 8 ids.  The env gate (or a sweep's calibration factor)
+# re-decides this for a kernel that earns it.
+EMBED_FUSE_NEVER = 1 << 62
+
+
 def embed_fuse_min_bytes():
     """Minimum gathered-slab bytes for the embedding-gather rewrite
-    (``PADDLE_TPU_EMBED_FUSE_MIN_BYTES``, default 4096)."""
+    (``PADDLE_TPU_EMBED_FUSE_MIN_BYTES``, default: never — see
+    ``EMBED_FUSE_NEVER``)."""
     try:
-        return int(os.environ.get(
-            "PADDLE_TPU_EMBED_FUSE_MIN_BYTES", "4096") or 4096)
+        return int(os.environ.get("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", "")
+                   or EMBED_FUSE_NEVER)
     except ValueError:
-        return 4096
+        return EMBED_FUSE_NEVER
 
 
 def _autotune_state():
@@ -235,13 +245,10 @@ def optimizer_fuse_overhead_bytes():
     if _BACKEND_DEFAULT_OVERHEAD is None:
         # backend identity is fixed for the process; signature() calls
         # this on the dispatch hot path
-        try:
-            import jax
+        from ..ops.pallas import device_platform
 
-            tpu = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - no backend at all
-            tpu = False
-        _BACKEND_DEFAULT_OVERHEAD = (8 << 20) if tpu else (256 << 10)
+        _BACKEND_DEFAULT_OVERHEAD = (
+            (8 << 20) if device_platform() == "tpu" else (256 << 10))
     return _BACKEND_DEFAULT_OVERHEAD
 
 

@@ -381,7 +381,9 @@ def _run_elastic_driver(args):
     procs, logs = [], []
     for rank in range(world):
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        # workers always train on the CPU: a chip belongs to one process,
+        # and several workers (or a parent that holds it) cannot share it
+        env["JAX_PLATFORMS"] = "cpu"
         env["PADDLE_TPU_TELEMETRY_DIR"] = telemetry_dir
         env["PADDLE_TPU_TRACEPARENT"] = drill_tp
         # drills are short and killed mid-flight: flush every span so
@@ -446,7 +448,7 @@ def _run_elastic_driver(args):
             return _abort("survivors never resumed at world %d; cannot "
                           "stage the rejoin" % (world - 1))
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"  # as the first lives: never the chip
         env["PADDLE_TPU_TELEMETRY_DIR"] = telemetry_dir
         env["PADDLE_TPU_TRACEPARENT"] = drill_tp
         env.setdefault("PADDLE_TPU_TELEMETRY_FLUSH", "1")
@@ -821,7 +823,9 @@ def _run_driver(args):
                 os.path.join(ckpt_dir, "fault_state.json"),
             "PADDLE_TPU_NAN_GUARD": "1",
             "PADDLE_TPU_TRACEPARENT": drill_tp,
-            "JAX_PLATFORMS": env.get("JAX_PLATFORMS", "cpu"),
+            # the worker trains on the CPU: a parent that holds the
+            # chip cannot share it with a child
+            "JAX_PLATFORMS": "cpu",
         })
         env.setdefault("PADDLE_TPU_TELEMETRY_FLUSH", "1")
         if telemetry_dir:

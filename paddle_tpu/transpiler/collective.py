@@ -492,11 +492,8 @@ def async_two_worker_probe(devices, lr=0.1):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.8 fallback
-        from jax.experimental.shard_map import shard_map
-
     from ..executor import _run_ops_into_env
+    from ..jax_compat import shard_map
     from ..ops import registry as op_registry
 
     main, startup, _loss, w0 = build_toy_async_program(lr=lr)

@@ -1,0 +1,206 @@
+"""Every Pallas kernel compiles for a described (not attached) TPU v5e.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that ``topologies.get_topology_desc`` describes, so what Mosaic refuses
+(block shapes off the (8, 128) tiling, dynamic sublane offsets on packed
+dtypes, too much VMEM) fails HERE, at no chip time — interpret mode
+accepts all of it.  Each case goes through the kernel's public entry
+point at a real width, so it also proves the routing SELECTS the kernel
+there: the compiled module must contain a ``tpu_custom_call``.  Nothing
+runs; a compile that passes is not a chip run (``chip_smoke.py`` is).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu.ops.pallas as pallas
+from paddle_tpu.ops.pallas.conv_bn_act import (bn_act_epilogue,
+                                               epilogue_eligible)
+from paddle_tpu.ops.pallas.embedding import embedding_gather
+from paddle_tpu.ops.pallas.flash_attention import flash_attention
+from paddle_tpu.ops.pallas.flash_decode import flash_decode
+from paddle_tpu.ops.pallas.fused_ln import fused_dropout_add_ln
+from paddle_tpu.ops.pallas.paged_flash_decode import paged_flash_decode
+from paddle_tpu.quant.blockwise import block_dequantize, block_quantize
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip("cannot describe a v5e here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def as_on_chip(monkeypatch):
+    """Steer the one gating predicate to its on-chip answer (the code
+    sees the CPU backend here), with the cache off: an executable built
+    for a described chip cannot be read back without one."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_FLASH_DROPOUT_DEBUG", raising=False)
+    monkeypatch.setattr(pallas, "device_platform", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernels_in(fn, chip, *structs):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in structs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return sum(pallas.pallas_kernels_in(text).values())
+
+
+def _grad_sum(fn, argnums):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums)
+
+
+# -- fused dropout+add+LayerNorm -------------------------------------------
+
+def _ln(rate):
+    return lambda x, r, g, b, seed: fused_dropout_add_ln(
+        x, r, g, b, dropout_rate=rate, seed=seed)
+
+
+def _ln_args(n, d, dt):
+    return (((n, d), dt), ((n, d), dt), ((d,), F32), ((d,), F32),
+            ((1,), I32))
+
+
+@pytest.mark.parametrize("n,d,dt,rate", [
+    (8192, 768, BF16, 0.1),     # BERT-base seq128 bs64 / seq512 bs16
+    (8192, 768, F32, 0.0),
+    (128, 768, BF16, 0.0),      # serving, batch bucket 1
+])
+def test_fused_ln_forward(chip, n, d, dt, rate):
+    assert _kernels_in(_ln(rate), chip, *_ln_args(n, d, dt)) == 1
+
+
+def test_fused_ln_backward(chip):
+    g = _grad_sum(_ln(0.1), (0, 1, 2, 3))
+    assert _kernels_in(g, chip, *_ln_args(8192, 768, BF16)) == 2
+
+
+def test_fused_ln_routes_around_unaligned_row_blocks(chip):
+    """264 rows only split into 8-row blocks, whose (1, 8) statistics
+    tile the lowering refuses — routing must pick the composite."""
+    assert _kernels_in(_ln(0.0), chip, *_ln_args(264, 768, BF16)) == 0
+
+
+# -- flash attention ---------------------------------------------------------
+
+def _flash(causal, rate, bias):
+    def fn(q, k, v, *rest):
+        rest = list(rest)
+        b = rest.pop(0) if bias else None
+        seed = rest.pop(0) if rate else None
+        return flash_attention(q, k, v, bias=b, causal=causal,
+                               dropout_rate=rate, dropout_seed=seed)
+    return fn
+
+
+def _flash_args(b, h, t, d, dt, rate, bias):
+    qkv = [((b, h, t, d), dt)] * 3
+    return qkv + ([((b, t), F32)] if bias else []) \
+        + ([((1,), I32)] if rate else [])
+
+
+@pytest.mark.parametrize("b,h,t,d,causal,rate,bias,grad", [
+    (16, 12, 512, 64, False, 0.1, True, False),   # BERT-base seq512 bs16
+    (16, 12, 512, 64, False, 0.1, True, True),
+    (4, 12, 2048, 64, True, 0.0, False, False),   # long causal
+    (4, 12, 2048, 64, True, 0.0, False, True),
+])
+def test_flash_attention(chip, b, h, t, d, causal, rate, bias, grad):
+    fn = _flash(causal, rate, bias)
+    if grad:
+        fn = _grad_sum(fn, (0, 1, 2))
+    n = _kernels_in(fn, chip, *_flash_args(b, h, t, d, BF16, rate, bias))
+    assert n == (3 if grad else 1)  # fwd + (dK/dV, dQ)
+
+
+# -- decode kernels (forward only) -------------------------------------------
+
+def test_flash_decode(chip):
+    b, h, t, d = 16, 16, 4096, 128
+    n = _kernels_in(flash_decode, chip, ((b, h, d), BF16),
+                    ((b, h, t, d), BF16), ((b, h, t, d), BF16),
+                    ((b,), I32))
+    assert n == 1
+
+
+@pytest.mark.parametrize("dt", [BF16, F32])
+def test_paged_flash_decode(chip, dt):
+    s, h, d, bl, nblk, mb = 16, 16, 128, 16, 4096, 256
+    n = _kernels_in(paged_flash_decode, chip, ((s, h, d), dt),
+                    ((nblk, h, bl, d), dt), ((nblk, h, bl, d), dt),
+                    ((s,), I32), ((s, mb), I32))
+    assert n == 1
+
+
+# -- conv + batch-norm + activation epilogue ---------------------------------
+
+def _bn_args(r, c):
+    return (((r, c), BF16),) + (((c,), F32),) * 4
+
+
+def _bn_act(y, g, b, m, r):
+    assert epilogue_eligible(y.shape[0], y.shape[1], "relu")
+    return bn_act_epilogue(y, g, b, m, r, act="relu")
+
+
+@pytest.mark.parametrize("grad", [False, True])
+def test_conv_bn_act(chip, grad):
+    fn = _grad_sum(_bn_act, (0, 1, 2, 3, 4)) if grad else _bn_act
+    # ResNet-50 stage 1 at batch 128: 128*56*56 rows x 256 channels
+    assert _kernels_in(fn, chip, *_bn_args(401408, 256)) == 1
+
+
+# -- embedding gather --------------------------------------------------------
+
+@pytest.mark.parametrize("rows,dim,dt", [
+    (30522, 768, F32),      # BERT-base word table
+    (30522, 768, BF16),
+    (512, 768, F32),        # position table
+    (2, 768, F32),          # token-type table: fewer rows than a tile
+    (2, 768, BF16),
+    (1000003, 128, F32),    # DeepFM-scale table
+])
+def test_embedding_gather(chip, rows, dim, dt):
+    n = _kernels_in(embedding_gather, chip, ((rows, dim), dt),
+                    ((64, 128, 1), I32))
+    assert n == 1
+
+
+def test_embedding_gather_pads_partial_tile(chip):
+    assert _kernels_in(embedding_gather, chip, ((30522, 768), F32),
+                       ((13,), I32)) == 1
+
+
+# -- block quantize / dequantize ---------------------------------------------
+
+@pytest.mark.parametrize("numel,kernels", [
+    (768 * 3072, 2),     # one BERT-base FFN weight gradient
+    (8 * 264 * 256, 0),  # 2112 blocks: row blocks of 64, off the 128 lanes
+])
+def test_block_quant_roundtrip(chip, numel, kernels):
+    def fn(x):
+        q, s = block_quantize(x, block=256)
+        return block_dequantize(q, s, size=numel)
+
+    assert _kernels_in(fn, chip, ((numel,), F32)) == kernels

@@ -113,7 +113,7 @@ class TestFlashDecodeKernel:
         lens = {"full": jnp.asarray([t, t], jnp.int32),
                 "ragged": jnp.asarray([7, 300], jnp.int32),
                 "shallow": jnp.asarray([1, 2], jnp.int32)}[lens_kind]
-        use, _ = FD._use_pallas()
+        use, _ = FD.use_pallas()
         assert use, "interpret mode must engage the kernel path"
         o_kernel = FD.flash_decode(q, k, v, lens)
         o_ref = FD.decode_reference(q, k, v, lens)

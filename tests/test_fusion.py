@@ -772,10 +772,16 @@ class TestLintChecks:
 # ---------------------------------------------------------------------------
 
 class TestPallasFallback:
-    def test_pallas_supported_flag_exists(self):
-        from paddle_tpu.ops.pallas.flash_attention import pallas_supported
+    def test_gating_predicate_modes(self, monkeypatch):
+        """``off`` wins over everything and an unknown mode is an error,
+        not a silent ``auto``."""
+        from paddle_tpu.ops.pallas import use_pallas
 
-        assert isinstance(pallas_supported(), bool)
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", "off")
+        assert use_pallas() == (False, False)
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", "on")
+        with pytest.raises(ValueError, match="PADDLE_TPU_PALLAS"):
+            use_pallas()
 
     def test_rewritten_attention_runs_on_cpu_without_pallas(
             self, monkeypatch):
@@ -1028,6 +1034,23 @@ def build_embedding(dim=128, vocab=100, slot_len=16, train=True):
 
 
 class TestEmbeddingGatherFamily:
+    @pytest.fixture(autouse=True)
+    def gate_open(self, monkeypatch):
+        """The family is gated off by default (the kernel measured slower
+        than XLA's gather on the chip); these tests open the gate."""
+        monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", "4096")
+
+    def test_gated_off_by_default(self, monkeypatch):
+        monkeypatch.delenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES")
+        main, startup, loss = build_embedding()
+        fused, report = fusion.resolve_fused_program(
+            main, targets=[loss.name])
+        assert report.counts().get("embedding_gather") is None
+        assert "lookup_table" in op_types(fused)
+        skips = [s for s in report.skipped
+                 if s.family == "embedding_gather"]
+        assert skips and "cost model" in skips[0].reason
+
     def test_rewrite_golden(self):
         main, startup, loss = build_embedding()
         fused, report = fusion.resolve_fused_program(
@@ -1046,19 +1069,41 @@ class TestEmbeddingGatherFamily:
                 "label": rng.randint(0, 10, (4, 1)).astype("int64")}
 
         def arm(gate):
-            if gate is not None:
-                monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES",
-                                   gate)
-            else:
-                monkeypatch.delenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES",
-                                   raising=False)
+            monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", gate)
             main, startup, loss = build_embedding()
             out, _ = run_steps(main, startup, feed, [loss.name], steps=4)
             return out
 
-        on = arm(None)
+        on = arm("4096")
         off = arm("1000000000000")
         assert np.array_equal(on, off)
+
+    @pytest.mark.parametrize("rows,n,dtype", [
+        (30522, 16, "float32"),   # table rows not a multiple of the tile
+        (30522, 16, "bfloat16"),  # packed dtype: masked row select
+        (2, 13, "float32"),       # table smaller than a tile, ragged ids
+        (2, 13, "bfloat16"),
+    ])
+    def test_kernel_matches_take(self, monkeypatch, rows, n, dtype):
+        """The Pallas gather (interpret mode) returns exactly the rows
+        ``jnp.take`` does, padding_idx row zeroed."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.embedding import embedding_gather
+
+        rng = np.random.RandomState(0)
+        table = jnp.asarray(rng.randn(rows, 128), dtype)
+        ids = rng.randint(0, rows, (n, 1)).astype("int64")
+        ids[0] = rows - 1
+        ids[1] = 0
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", "off")
+        want = embedding_gather(table, jnp.asarray(ids), padding_idx=0)
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+        got = embedding_gather(table, jnp.asarray(ids), padding_idx=0)
+        assert got.dtype == want.dtype and got.shape == (n, 128)
+        assert np.array_equal(np.asarray(got.astype("float32")),
+                              np.asarray(want.astype("float32")))
+        assert not np.asarray(got[1].astype("float32")).any()
 
     def test_unaligned_dim_skips_with_reason(self):
         main, startup, loss = build_embedding(dim=48)

@@ -41,7 +41,7 @@ class TestGeoDeltaAverageUnderPsum:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.jax_compat import shard_map
 
         mesh = Mesh(np.array(jax.devices()[:2]), ("workers",))
         block = main.global_block()

@@ -127,7 +127,7 @@ class TestLocalSGDDeltaAverageUnderPsum:
     def test_diverged_workers_average(self):
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from paddle_tpu.jax_compat import shard_map
         import jax
 
         from paddle_tpu.executor import _run_ops_into_env

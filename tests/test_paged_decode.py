@@ -273,8 +273,8 @@ class TestPagedKernelParity:
                 "shallow": [1, 2]}[lens_kind]
         lens = jnp.asarray(lens, jnp.int32)
         table = jnp.asarray(table)
-        from paddle_tpu.ops.pallas.flash_attention import _use_pallas
-        assert _use_pallas()[0], "interpret mode must engage the kernel"
+        from paddle_tpu.ops.pallas import use_pallas
+        assert use_pallas()[0], "interpret mode must engage the kernel"
         o_kernel = PFD.paged_flash_decode(q, kc, vc, lens, table)
         o_ref = PFD.paged_decode_reference(q, kc, vc, lens, table)
         np.testing.assert_allclose(o_kernel, o_ref, rtol=1e-5,

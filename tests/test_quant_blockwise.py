@@ -157,10 +157,8 @@ class TestPallasParity:
         the Pallas interpreter on CPU; its bits must match the XLA
         composite fallback (the autotune ``quant`` family swaps grid
         shapes, never values)."""
-        from paddle_tpu.ops.pallas.flash_attention import pallas_supported
+        from paddle_tpu.ops.pallas import use_pallas
 
-        if not pallas_supported():
-            pytest.skip("pallas unavailable in this jax build")
         rng = np.random.RandomState(7)
         # eligible shape: block % 128 == 0, nblocks % 8 == 0
         x = jnp.asarray(rng.randn(8 * 256).astype("float32"))
@@ -168,6 +166,7 @@ class TestPallasParity:
         q_x, s_x = block_quantize(x, block=256)
         back_x = block_dequantize(q_x, s_x)
         monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+        assert use_pallas() == (True, True)
         q_p, s_p = block_quantize(x, block=256)
         back_p = block_dequantize(q_p, s_p)
         assert np.array_equal(np.asarray(q_x), np.asarray(q_p))

@@ -45,6 +45,10 @@ BASE = dict(vocab_size=30522, hidden=768, layers=12, heads=12, ffn=3072,
             max_seq=512)
 
 if __name__ == "__main__":
+    from paddle_tpu.core import configure_compile_cache, require_tpu
+
+    require_tpu("tools/bench_sweep.py")
+    configure_compile_cache()
     which = sys.argv[1] if len(sys.argv) > 1 else "128"
     if which == "128":
         run_variant("baseline (dropout .1, unfused attn)", dict(BASE), 64)
