@@ -207,7 +207,7 @@ def as_numpy(value):
 
 
 def _finish_fetches(fetches, return_numpy, fetch_names=(),
-                    state_names=()):
+                    state_names=(), step_info=None):
     """Fetch-return protocol shared by Executor.run and SPMDRunner.run.
 
     ``return_numpy=True``: ONE batched device→host sync issued after the
@@ -223,7 +223,10 @@ def _finish_fetches(fetches, return_numpy, fetch_names=(),
     ``donated-buffer-live-read`` hazard the concurrency analyzer flags.
     Lazy handles for those are detached with a device-side copy (async,
     no host sync) so a handle materialized after later steps dispatched
-    still reads this step's value instead of a deleted buffer."""
+    still reads this step's value instead of a deleted buffer.
+
+    ``step_info`` rides on the lazy handles (see :class:`FetchHandle`),
+    so that materialising one tells the program its step is done."""
     if return_numpy:
         return _pipeline.host_values(fetches)
     out = []
@@ -233,7 +236,8 @@ def _finish_fetches(fetches, return_numpy, fetch_names=(),
                 and fetch_names[i] in state
                 and not isinstance(v, FetchHandle)):
             v = _pipeline.detach_device(v)
-        out.append(v if isinstance(v, FetchHandle) else FetchHandle(v))
+        out.append(v if isinstance(v, FetchHandle)
+                   else FetchHandle(v, step_info))
     return out
 
 
@@ -1091,6 +1095,113 @@ def _check_feed_shapes(program, feed_vals):
                        ("\n" + hint) if hint else ""))
 
 
+def _stage_feeds(runner, feed, cache, batch_sharding=None,
+                 check_against=None):
+    """The ``feed_stage`` phase of both runners: every fed value onto
+    the device.  A chained :class:`FetchHandle` gives its device value; a
+    host array goes through the placement cache (the SAME array re-fed
+    step after step, a constant mask or a benchmark batch, is copied
+    once; reference ``_feed_data`` → ``set_feed_variable``); device
+    arrays, staged by ``DeviceFeedPipeline`` say, pass through free.
+    ``batch_sharding`` (a multi-process cluster, reference nccl2 mode):
+    each process feeds its LOCAL batch shard and the global batch-sharded
+    array is assembled over the cross-process mesh (the reference's
+    feed_and_split_tensor_into_local_scopes, inverted).  The staged
+    values are held to the data layers ``check_against`` declares."""
+    import jax
+    import jax.numpy as jnp
+
+    feed_vals = {}
+    with _tr.phase(runner + ".feed_stage", bytes=0, hits=0, misses=0):
+        for name, value in feed.items():
+            if batch_sharding is not None:
+                value = jax.make_array_from_process_local_data(
+                    batch_sharding, np.asarray(value))
+            elif isinstance(value, FetchHandle):
+                value = value.device_value
+            if isinstance(value, np.ndarray):
+                value = _pipeline._stage(value, name=name, cache=cache)
+            elif isinstance(value, (list, tuple, int, float)):
+                value = jnp.asarray(value)
+            feed_vals[name] = value
+        if check_against is not None:
+            _check_feed_shapes(check_against, feed_vals)
+    return feed_vals
+
+
+def _feed_signature(feed_vals):
+    return tuple((n, tuple(v.shape), str(v.dtype))
+                 for n, v in sorted(feed_vals.items()))
+
+
+def _compile_step(runner, build, program, feed_vals, fetch_names):
+    """The ``compile`` phase of both runners: ``build()`` makes the
+    :class:`_CompiledBlock` (trace, lower, jit)."""
+    with _tr.phase(runner + ".compile") as ph:
+        compiled = build()
+    ph.set_attr("compile_ms", round(ph.dur_ms, 2))
+    _obs.record_compile(ph.dur_ms, runner=runner)
+    _register_compile_telemetry(compiled, program, feed_vals, fetch_names)
+    return compiled
+
+
+def _dispatch_step(runner, step_phase, compiled, program, scope, feed_vals,
+                   executor, cur_step, fetch_names, host_active,
+                   host_grad_fetches, return_numpy, has_host_io=False):
+    """From the compiled block to the caller's fetches: the phases
+    ``gather_state``, ``rng_key``, ``dispatch``, ``apply_results`` and
+    ``finish_fetches`` of both runners, then the step's telemetry.
+
+    ``dispatch`` is the jitted call alone: under jax async dispatch it
+    returns once the step is ENQUEUED.  With ``return_numpy=False``
+    nothing here waits for the device, so no step time is recorded: the
+    handles carry the step and its dispatch stamp to the place that
+    materialises them (``pipeline.host_values``, a ``host.sync``
+    phase)."""
+    import jax
+
+    with _tr.phase(runner + ".gather_state", n_rw=len(compiled.rw_names),
+                   n_ro=len(compiled.ro_names)):
+        rw = {n: scope.get(n) for n in compiled.rw_names}
+        ro = promote_readonly_scope_arrays(scope, compiled)
+    with _tr.phase(runner + ".rng_key"):
+        base_key = jax.random.fold_in(
+            rng_key(program.random_seed or 0), executor._step)
+    executor._step += 1
+    # per-ring collective launches ride as attributes (cheap, and a
+    # per-launch span would dwarf the thing it measures)
+    with _tr.phase(runner + ".dispatch",
+                   **_obs.collective_step_shape()) as dispatch:
+        fetches, new_rw, fresh = compiled.jitted(feed_vals, rw, ro, base_key)
+    with _tr.phase(runner + ".apply_results"):
+        fetches = _apply_step_results(
+            compiled, scope, fetches, new_rw, fresh, fetch_names,
+            host_active, host_grad_fetches, cur_step)
+        if has_host_io:
+            from .ops.io_ops import run_host_io_block
+
+            run_host_io_block(program.global_block(), scope, phase="save")
+        # the scope holds the new state: the old (donated) arrays die
+        # here, in the phase that replaced them, not in the step's self
+        # time when this frame goes (a millisecond for 750 of them)
+        del rw, ro, new_rw, fresh
+    drift_key = getattr(compiled, "_drift_key", None)
+    with _tr.phase(runner + ".finish_fetches",
+                   handles=0 if return_numpy else len(fetches)):
+        result = _finish_fetches(
+            fetches, return_numpy, fetch_names=fetch_names,
+            state_names=(tuple(compiled.rw_names)
+                         + tuple(compiled.fresh_persist)),
+            step_info=(runner, cur_step, dispatch.t1_ns, drift_key,
+                       executor._last_done))
+    _obs.record_step(
+        runner, cur_step, dispatch.dur_ms,
+        wall_ms=(_time.perf_counter_ns() - step_phase.t0_ns) / 1e6
+        if return_numpy else None,
+        drift_key=drift_key, last_done=executor._last_done)
+    return result
+
+
 class Executor:
     """Reference API: ``Executor(place).run(program, feed, fetch_list)``
     (``python/paddle/fluid/executor.py:565``)."""
@@ -1100,6 +1211,10 @@ class Executor:
         self._cache = {}
         self._feed_cache = _pipeline.FeedCache()
         self._step = 0
+        # [step, perf_counter_ns] of the newest step of this executor
+        # seen complete (observability.runtime.record_step_done): step
+        # numbers are per executor, so the intervals between them are too
+        self._last_done = [None, 0]
 
     def close(self):
         self._cache.clear()
@@ -1119,9 +1234,6 @@ class Executor:
         verify=False,
         _fusion_config=None,
     ):
-        import jax
-        import jax.numpy as jnp
-
         from .compiler import CompiledProgram
 
         if program is None:
@@ -1150,10 +1262,27 @@ class Executor:
                     and getattr(program, "_program", None) is not None:
                 _check_feed_shapes(program._program, feed)
             return program._run(self, feed, fetch_list, scope, return_numpy)
-        if scope is None:
-            scope = global_scope()
-        feed = feed or {}
-        fetch_list = fetch_list or []
+        # ---- resilience hooks (all no-ops without a fault spec /
+        # PADDLE_TPU_NAN_GUARD — see resilience/) ----
+        from .resilience import faults as _rfaults
+
+        inj = _rfaults.get_injector()
+        # fires worker_kill / worker_hang process faults at their step
+        cur_step = inj.on_step() if inj.active else self._step
+        # the whole call is the step: its parts are the phases below and
+        # in _dispatch_step, and what no phase covers is its self time
+        with _tr.phase("executor.step", step=cur_step, head_sample=True,
+                       runner="executor",
+                       lazy=not return_numpy) as step_phase:
+            return self._run_step(
+                step_phase, program, feed or {}, fetch_list or [],
+                global_scope() if scope is None else scope, return_numpy,
+                use_program_cache, _fusion_config, inj, cur_step)
+
+    def _run_step(self, step_phase, program, feed, fetch_list, scope,
+                  return_numpy, use_program_cache, fusion_config, inj,
+                  cur_step):
+        import jax.numpy as jnp
 
         fetch_names = [
             v.name if isinstance(v, Variable) else str(v) for v in fetch_list
@@ -1172,18 +1301,13 @@ class Executor:
         # silently re-enabling families the user disabled.
         from .static_analysis import fusion as _fusion
 
-        program, _fusion_report = _fusion.resolve_fused_program(
-            program, config=_fusion_config, targets=fetch_names)
+        with _tr.phase("executor.fusion_resolve"):
+            program, _fusion_report = _fusion.resolve_fused_program(
+                program, config=fusion_config, targets=fetch_names)
 
-        # ---- resilience hooks (all no-ops without a fault spec /
-        # PADDLE_TPU_NAN_GUARD — see resilience/) ----
-        from .resilience import faults as _rfaults
         from .resilience import guard as _rguard
         from .resilience import retry as _rretry
 
-        inj = _rfaults.get_injector()
-        # fires worker_kill / worker_hang process faults at their step
-        cur_step = inj.on_step() if inj.active else self._step
         nan_guard = _rguard.guard_enabled(program)
 
         # save/load ops are host IO, never jitted (reference save_op.cc).
@@ -1206,23 +1330,8 @@ class Executor:
                                        fetch_names=fetch_names,
                                        state_names=fetch_names)
 
-        # device transfer of feeds (reference: _feed_data → set_feed_variable)
-        # with a placement cache: the SAME host array re-fed step after
-        # step (a constant attention-mask bias, a benchmark batch) is
-        # transferred once and its device placement reused — device
-        # arrays (e.g. staged by DeviceFeedPipeline) pass through free
-        feed_vals = {}
-        for name, value in feed.items():
-            if isinstance(value, FetchHandle):
-                # chaining: a previous run's lazy fetch feeds this one
-                value = value.device_value
-            if isinstance(value, np.ndarray):
-                value = _pipeline._stage(value, name=name,
-                                         cache=self._feed_cache)
-            elif isinstance(value, (list, tuple, int, float)):
-                value = jnp.asarray(value)
-            feed_vals[name] = value
-        _check_feed_shapes(program, feed_vals)
+        feed_vals = _stage_feeds("executor", feed, self._feed_cache,
+                                 check_against=program)
 
         # fault-injection gate vector: one fed scalar per value fault, so
         # the step-dependent corruption never recompiles the block.
@@ -1242,35 +1351,31 @@ class Executor:
             program, feed, feed_vals)
         fetch_names = fetch_names + host_grad_fetches
 
-        sig = tuple(
-            (n, tuple(v.shape), str(v.dtype)) for n, v in sorted(feed_vals.items())
-        )
-        mode = "train"
-        # two-pass unbounded-while gradients: probe concrete trip counts
-        # first; they become static scan lengths, so they join the cache
-        # key (a longer loop must recompile)
-        trip_counts = None
-        if _has_unbounded_while_grad(program):
-            trip_counts = _probe_trip_counts(
-                program.global_block(), feed_vals, scope, fetch_names)
-        key_tuple = (
-            id(program),
-            program._version,
-            id(scope),
-            sig,
-            tuple(fetch_names),
-            tuple(sorted((trip_counts or {}).items())),
-            nan_guard,
-            # fusion config is part of the compilation identity: the
-            # same source program under a different fusion config is a
-            # different (cloned) program object, and the signature makes
-            # the separation explicit/debuggable
-            getattr(program, "_fusion_sig", None),
-        )
-        from . import profiler as _prof
-
-        compiled = self._cache.get(key_tuple) if use_program_cache else None
-        _obs.record_jit_cache(compiled is not None)
+        with _tr.phase("executor.lookup"):
+            # two-pass unbounded-while gradients: probe concrete trip
+            # counts first; they become static scan lengths, so they join
+            # the cache key (a longer loop must recompile)
+            trip_counts = None
+            if _has_unbounded_while_grad(program):
+                trip_counts = _probe_trip_counts(
+                    program.global_block(), feed_vals, scope, fetch_names)
+            key_tuple = (
+                id(program),
+                program._version,
+                id(scope),
+                _feed_signature(feed_vals),
+                tuple(fetch_names),
+                tuple(sorted((trip_counts or {}).items())),
+                nan_guard,
+                # fusion config is part of the compilation identity: the
+                # same source program under a different fusion config is
+                # a different (cloned) program object, and the signature
+                # makes the separation explicit/debuggable
+                getattr(program, "_fusion_sig", None),
+            )
+            compiled = (self._cache.get(key_tuple) if use_program_cache
+                        else None)
+            _obs.record_jit_cache(compiled is not None)
         if compiled is None:
             def _compile():
                 # injectable site (compile_fail) — and transient
@@ -1284,77 +1389,23 @@ class Executor:
                     list(feed_vals),
                     fetch_names,
                     scope,
-                    mode,
+                    "train",
                     trip_counts=trip_counts,
                     nan_guard=nan_guard,
                 )
 
-            _t_compile = _time.perf_counter()
-            with _tr.span("executor.compile", step=cur_step):
-                with _prof.record_event("executor.lower_and_jit"):
-                    compiled = _rretry.retry_call(
-                        _compile, site="executor.compile")
-            _obs.record_compile(
-                (_time.perf_counter() - _t_compile) * 1000.0)
+            compiled = _compile_step(
+                "executor",
+                lambda: _rretry.retry_call(_compile,
+                                           site="executor.compile"),
+                program, feed_vals, fetch_names)
             if use_program_cache:
                 self._cache[key_tuple] = compiled
-            _register_compile_telemetry(compiled, program, feed_vals,
-                                        fetch_names)
 
-        rw = {n: scope.get(n) for n in compiled.rw_names}
-        ro = promote_readonly_scope_arrays(scope, compiled)
-        seed = program.random_seed or 0
-        base_key = jax.random.fold_in(rng_key(seed), self._step)
-        self._step += 1
-
-        import contextlib
-
-        profiling = _prof.is_profiler_enabled()
-        run_ctx = (_prof.record_event("executor.run") if profiling
-                   else contextlib.nullcontext())
-        _t_step = _time.perf_counter()
-        # the step span activates on this thread, so the dispatch child
-        # and any host.sync recorded at the fetch point nest under it;
-        # per-ring collective launches ride as attributes (cheap, and a
-        # per-launch span would dwarf the thing it measures).  Steps
-        # inside a trace record fully; standalone loops sample 1-of-N
-        # (the dispatch/sync children gate on the same decision via
-        # span_if_traced — no ambient context when sampled out)
-        step_span = (_tr.span("executor.step", step=cur_step)
-                     if _tr.sample_step(cur_step) else _tr.NULL_SPAN)
-        if step_span.recording:
-            for ring, shape in _obs.collective_step_shape().items():
-                step_span.set_attr(ring, shape)
-        with step_span, run_ctx:
-            # dispatch only: under jax async dispatch the jitted call
-            # returns once the step is ENQUEUED — the matching
-            # device_compute/host_sync phases are recorded at the fetch
-            # sync point (pipeline.host_values), so a profile shows how
-            # much host work overlapped the in-flight step
-            disp_ctx = (_prof.record_event("executor.dispatch")
-                        if profiling else contextlib.nullcontext())
-            with _tr.span_if_traced("executor.dispatch"), disp_ctx:
-                fetches, new_rw, fresh = compiled.jitted(
-                    feed_vals, rw, ro, base_key)
-            _dispatch_ms = (_time.perf_counter() - _t_step) * 1000.0
-            fetches = _apply_step_results(
-                compiled, scope, fetches, new_rw, fresh, fetch_names,
-                host_active, host_grad_fetches, cur_step)
-
-            if has_host_io:
-                run_host_io_block(program.global_block(), scope,
-                                  phase="save")
-
-            result = _finish_fetches(
-                fetches, return_numpy, fetch_names=fetch_names,
-                state_names=(tuple(compiled.rw_names)
-                             + tuple(compiled.fresh_persist)))
-        _obs.record_step(
-            "executor", cur_step,
-            (_time.perf_counter() - _t_step) * 1000.0,
-            dispatch_ms=_dispatch_ms,
-            drift_key=getattr(compiled, "_drift_key", None))
-        return result
+        return _dispatch_step(
+            "executor", step_phase, compiled, program, scope, feed_vals,
+            self, cur_step, fetch_names, host_active, host_grad_fetches,
+            return_numpy, has_host_io=has_host_io)
 
     # ------ dataset entry points (reference executor.py:909) — see
     # paddle_tpu/trainer.py once the dataset path lands ------

@@ -36,11 +36,11 @@ from .metrics import (DEFAULT_LATENCY_BUCKETS_MS, Counter, Gauge,
                       set_telemetry_enabled, telemetry_enabled)
 from .tracing import (NULL_SPAN, Span, SpanContext, Tracer,
                       capture_context, current_span, current_trace_id,
-                      current_traceparent, flight_dump, get_tracer,
+                      current_traceparent, flight_dump, get_tracer, phase,
                       read_flight_records, read_traces, reset_tracing,
                       sample_step, set_rank, set_tracing_enabled, span,
-                      span_if_traced, start_span, step_sample_every,
-                      tracing_enabled, use_context)
+                      start_span, step_sample_every, tracing_enabled,
+                      use_context)
 
 __all__ = [
     # metrics
@@ -59,7 +59,7 @@ __all__ = [
     "write_chrome_trace",
     # tracing
     "Span", "SpanContext", "Tracer", "NULL_SPAN", "span", "start_span",
-    "span_if_traced", "sample_step", "step_sample_every",
+    "phase", "sample_step", "step_sample_every",
     "current_span", "current_trace_id", "current_traceparent",
     "capture_context", "use_context", "get_tracer", "flight_dump",
     "read_traces", "read_flight_records", "tracing_enabled",

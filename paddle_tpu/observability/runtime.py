@@ -17,9 +17,9 @@ from . import tracing as _tracing
 from .metrics import telemetry_enabled
 
 __all__ = [
-    "record_step", "record_jit_cache", "record_compile",
-    "record_fusion_resolve", "record_feed_cache",
-    "record_feed_cache_eviction", "record_sync",
+    "record_step", "record_step_done", "record_jit_cache",
+    "record_compile", "record_fusion_resolve", "record_feed_cache",
+    "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
     "record_prefetch", "record_guard_step", "record_guard_skip",
     "record_serving_request", "record_serving_reject",
     "record_serving_shed", "record_serving_batch",
@@ -77,7 +77,9 @@ def _step_h(runner):
     if h is None:
         h = (_m.counter("steps_total", runner=runner),
              _m.histogram("step_wall_ms", runner=runner),
-             _m.histogram("step_dispatch_ms", runner=runner))
+             _m.histogram("step_enqueue_ms", runner=runner),
+             _m.histogram("step_interval_ms", runner=runner),
+             _m.histogram("step_latency_ms", runner=runner))
         _step_handles[runner] = h
     return h
 
@@ -113,7 +115,7 @@ def _step_event_every():
     return max(_env_int("PADDLE_TPU_TELEMETRY_STEP_EVERY", 10), 1)
 
 
-_snapshot_state = {"steps": 0, "last_write": 0.0}
+_snapshot_state = {"steps": 0, "last_write": 0.0, "step_times": 0}
 
 
 def _maybe_write_snapshot():
@@ -148,38 +150,79 @@ def _maybe_write_snapshot():
 # executor / SPMD runner
 # ---------------------------------------------------------------------------
 
-def record_step(runner, step, wall_ms, dispatch_ms=None,
-                drift_key=None):
-    """One completed training/inference step."""
+def record_step(runner, step, enqueue_ms, wall_ms=None, drift_key=None,
+                last_done=None):
+    """One dispatched training/inference step.  ``enqueue_ms`` is the
+    host's time inside the jitted call (``step_enqueue_ms``).  ``wall_ms``
+    is the step's time when the call synced (``return_numpy=True``), and
+    None when it returned lazy handles: the enqueue is no step time, so
+    the step's time is then observed where a handle is materialised
+    (:func:`record_step_done`), or never.  ``last_done`` is the
+    executor's ``[step, perf_counter_ns]`` of the newest completion seen:
+    step numbers are each executor's own, so the state is too."""
     if not telemetry_enabled():
         return
-    steps_c, wall_h, disp_h = _step_h(runner)
+    steps_c, _, enqueue_h, _, _ = _step_h(runner)
     steps_c.inc()
-    wall_h.observe(wall_ms)
-    if dispatch_ms is not None:
-        disp_h.observe(dispatch_ms)
-    with _last_step_lock:
-        _last_step["step"] = step
-        _last_step["step_ms"] = wall_ms
-        _last_step["ts"] = time.time()
+    enqueue_h.observe(enqueue_ms)
     for launches_c, payload_c, launches, payload in _collective_per_step:
         launches_c.inc(launches)
         payload_c.inc(payload)
-    ev = _step_event_every()
-    if ev == 1 or steps_c.value % ev == 1:
-        _journal.emit("step", runner=runner, step=step,
-                      wall_ms=round(wall_ms, 4),
-                      dispatch_ms=None if dispatch_ms is None
-                      else round(dispatch_ms, 4))
-    if drift_key is not None:
-        from . import drift as _drift
-
-        _drift.monitor().observe_step(wall_ms, key=drift_key,
-                                      step=step)
+    if wall_ms is not None:
+        if last_done is not None:
+            with _last_step_lock:
+                last_done[:] = step, time.perf_counter_ns()
+        _observe_step_time(runner, step, wall_ms, drift_key)
     _maybe_write_snapshot()
 
 
+def record_step_done(runner, step, dispatch_ns, drift_key, last_done):
+    """A lazy fetch of ``step`` has reached the host (no sync is added:
+    this runs where the caller materialised the handle).  Observes
+    ``step_latency_ms`` (dispatch to done) and, when an earlier completion
+    of the same executor was observed (``last_done``, as in
+    :func:`record_step`), ``step_interval_ms``: the time since it, per
+    step advanced.  The interval is the loop's true step time and feeds
+    what ``wall_ms`` feeds in a synced loop."""
+    if not telemetry_enabled():
+        return
+    now = time.perf_counter_ns()
+    _, _, _, interval_h, latency_h = _step_h(runner)
+    latency_h.observe((now - dispatch_ns) / 1e6)
+    with _last_step_lock:
+        prev_step, prev_ns = last_done
+        if prev_step is not None and step <= prev_step:
+            return      # a second handle of a step already seen done
+        last_done[:] = step, now
+    if prev_step is not None:
+        interval_ms = (now - prev_ns) / 1e6 / (step - prev_step)
+        interval_h.observe(interval_ms)
+        _observe_step_time(runner, step, interval_ms, drift_key)
+
+
+def _observe_step_time(runner, step, step_ms, drift_key):
+    """What every reader of a step's time is fed: ``step_wall_ms``, the
+    watchdog's ``last_step_info``, the journal's sampled ``step`` event
+    and the drift monitor."""
+    _step_h(runner)[1].observe(step_ms)
+    with _last_step_lock:
+        _last_step["step"] = step
+        _last_step["step_ms"] = step_ms
+        _last_step["ts"] = time.time()
+        _snapshot_state["step_times"] += 1
+        seen = _snapshot_state["step_times"]
+    ev = _step_event_every()
+    if ev == 1 or seen % ev == 1:
+        _journal.emit("step", runner=runner, step=step,
+                      wall_ms=round(step_ms, 4))
+    if drift_key is not None:
+        from . import drift as _drift
+
+        _drift.monitor().observe_step(step_ms, key=drift_key, step=step)
+
+
 def record_jit_cache(hit, runner="executor"):
+    _tracing.phase_attr("hit", bool(hit))
     if not telemetry_enabled():
         return
     key = (runner, bool(hit))
@@ -199,6 +242,7 @@ def record_compile(ms, runner="executor"):
 
 
 def record_fusion_resolve(hit):
+    _tracing.phase_attr("hit", bool(hit))
     if not telemetry_enabled():
         return
     _named(_m.counter,
@@ -211,11 +255,21 @@ def record_fusion_resolve(hit):
 # ---------------------------------------------------------------------------
 
 def record_feed_cache(hit):
+    _tracing.phase_count("hits" if hit else "misses")
     if not telemetry_enabled():
         return
     _named(_m.counter,
            "feed_cache_hits_total" if hit
            else "feed_cache_misses_total").inc()
+
+
+def record_feed_h2d(nbytes):
+    """One host array copied to the device (a feed-cache miss, or a feed
+    staged with no cache)."""
+    _tracing.phase_count("bytes", nbytes)
+    if not telemetry_enabled():
+        return
+    _named(_m.counter, "feed_h2d_bytes_total").inc(nbytes)
 
 
 def record_feed_cache_eviction(n=1):
@@ -678,7 +732,7 @@ def reset_runtime():
     with _last_step_lock:
         _last_step.update(step=None, step_ms=None, ts=None)
     _collective_per_step = []
-    _snapshot_state.update(steps=0, last_write=0.0)
+    _snapshot_state.update(steps=0, last_write=0.0, step_times=0)
     _step_handles.clear()
     _jit_handles.clear()
     _named_handles.clear()

@@ -26,16 +26,31 @@ state as ``flight-r<rank>-<pid>.json`` — the resilience layer calls it
 on ``WorkerLostError`` / ``DispatcherCrashedError`` / guard abort, so a
 hang postmortem shows which span every thread and rank was inside.
 
+Step phases (:class:`phase`): the two runners time every part of a step
+with one helper.  A phase is a ``jax.profiler.TraceAnnotation`` (so it
+lies on the host plane of whatever device trace is running, on the
+device ops' clock) plus two ``time.perf_counter_ns()`` stamps in a
+per-step list.  The stamps become spans only when the step is *kept*:
+inside an ambient trace, head-sampled 1 in ``PADDLE_TPU_TRACE_SAMPLE``,
+or **slow** (over :data:`SLOW_FACTOR` times the running median of its
+kind).  GC pauses of a millisecond or more are ``host.gc`` spans; the
+``gc.callbacks`` hook that notes them takes no lock and builds no span
+(it runs wherever a collection fires, inside this module's own critical
+sections too): what it noted is recorded later, outside any lock.
+
 Reconstruct and analyze with ``python -m paddle_tpu.tools.trace DIR``.
 """
 
 import atexit
+import gc
 import json
 import os
 import threading
 import time
 from collections import deque, namedtuple
 
+from .. import profiler as _prof
+from . import metrics as _m
 from .journal import _rank, journal_dir
 from .metrics import _FALSY
 
@@ -43,14 +58,15 @@ __all__ = [
     "SCHEMA_VERSION", "TRACEPARENT_ENV", "SpanContext", "Span",
     "Tracer", "get_tracer", "reset_tracing", "tracing_enabled",
     "set_tracing_enabled", "set_rank", "span", "start_span",
-    "span_if_traced", "sample_step", "step_sample_every",
+    "phase", "phase_attr", "phase_count",
+    "sample_step", "step_sample_every",
     "current_span",
     "current_context", "current_trace_id", "current_traceparent",
     "capture_context", "use_context", "parse_traceparent",
     "format_traceparent", "inject_env", "remote_parent",
     "set_remote_parent", "flight_dump", "read_traces",
     "read_flight_records", "spans_to_chrome_events",
-    "fused_op_sources", "NULL_SPAN",
+    "NULL_SPAN",
 ]
 
 SCHEMA_VERSION = 1
@@ -318,21 +334,28 @@ class Span:
     serving request span lives across threads that way."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attrs",
-                 "status", "start_ts", "dur_ms", "rank", "thread",
-                 "_t0", "_tracer", "_ended", "_active")
+                 "status", "start_ts", "t0_ns", "dur_ms", "rank", "thread",
+                 "_tracer", "_ended", "_active")
 
     recording = True
 
     def __init__(self, name, trace_id, parent_id, tracer, attrs=None,
-                 start_ts=None):
+                 start_ts=None, t0_ns=None):
         self.name = str(name)
         self.trace_id = trace_id
         self.span_id = _new_id(8)
         self.parent_id = parent_id
         self.attrs = dict(attrs) if attrs else {}
         self.status = "ok"
-        self.start_ts = time.time() if start_ts is None else start_ts
-        self._t0 = time.perf_counter()
+        now_ns = time.perf_counter_ns()
+        if start_ts is None:
+            start_ts = time.time()
+        elif t0_ns is None:
+            # backdated on the wall clock alone: the same distance back
+            # on the monotonic one
+            t0_ns = now_ns - int((time.time() - start_ts) * 1e9)
+        self.start_ts = start_ts
+        self.t0_ns = now_ns if t0_ns is None else t0_ns
         self.dur_ms = None
         self.rank = tracer.rank
         self.thread = _thread_name()
@@ -362,19 +385,25 @@ class Span:
         ``dur_ms`` overrides it (retroactive spans reconstructed from
         measured windows, e.g. device-compute between dispatch and
         sync)."""
+        if self._close(status, dur_ms):
+            self._tracer._on_end([self])
+        return self
+
+    def _close(self, status, dur_ms):
+        """Mark ended without telling the tracer; False if it was."""
         if self._ended:
-            return self
+            return False
         self._ended = True
         if status is not None:
             self.status = str(status)
         self.dur_ms = (float(dur_ms) if dur_ms is not None
-                       else (time.perf_counter() - self._t0) * 1000.0)
-        self._tracer._on_end(self)
-        return self
+                       else (time.perf_counter_ns() - self.t0_ns) / 1e6)
+        return True
 
     def to_record(self):
         rec = {"schema": SCHEMA_VERSION, "kind": "span",
-               "ts": self.start_ts, "rank": self.rank,
+               "ts": self.start_ts, "t0_ns": self.t0_ns,
+               "rank": self.rank,
                "pid": os.getpid(), "thread": self.thread,
                "trace": self.trace_id, "span": self.span_id,
                "parent": self.parent_id, "name": self.name,
@@ -422,12 +451,14 @@ def _resolve_parent(parent):
     return None
 
 
-def start_span(name, parent=None, start_ts=None, **attrs):
+def start_span(name, parent=None, start_ts=None, t0_ns=None, **attrs):
     """Create a span WITHOUT activating it on this thread (hold it
     across threads; call ``.end()`` when done).  ``parent`` may be a
     Span, :class:`SpanContext` or traceparent string; defaults to the
     current context (new trace root when there is none).  ``start_ts``
-    backdates the wall-clock start (retroactive spans)."""
+    backdates the wall-clock start (retroactive spans) and ``t0_ns``
+    the monotonic one (``time.perf_counter_ns()``: the record's
+    ``t0_ns``, the clock a caller's loop cuts its windows on)."""
     if not tracing_enabled():
         return NULL_SPAN
     ctx = _resolve_parent(parent)
@@ -436,7 +467,7 @@ def start_span(name, parent=None, start_ts=None, **attrs):
     else:
         trace_id, parent_id = ctx.trace_id, ctx.span_id
     return Span(name, trace_id, parent_id, get_tracer(), attrs=attrs,
-                start_ts=start_ts)
+                start_ts=start_ts, t0_ns=t0_ns)
 
 
 def span(name, parent=None, start_ts=None, **attrs):
@@ -490,14 +521,307 @@ def sample_step(step):
         return True
 
 
-def span_if_traced(name, **attrs):
-    """A span only when it joins an existing trace; NULL_SPAN when it
-    would start a fresh root.  Interior step phases (dispatch, host
-    sync) use this so the root-level :func:`sample_step` decision gates
-    the whole subtree."""
-    if not tracing_enabled() or current_context() is None:
-        return NULL_SPAN
-    return span(name, **attrs)
+# ---------------------------------------------------------------------------
+# step phases: stamps every step, spans for the steps that are kept
+# ---------------------------------------------------------------------------
+
+#: a step (or a ``host.sync`` outside any step) whose host time is over
+#: this many times the running median of its kind is kept, whatever the
+#: head sampling said, with the attribute ``slow=true``
+SLOW_FACTOR = 3.0
+_MEDIAN_WINDOW = 31     # durations the running median looks back over
+_MEDIAN_MIN = 8         # and how many it wants before it calls one slow
+# nothing under a millisecond is slow: at that size three times the
+# median is the scheduler's noise
+_SLOW_FLOOR_NS = 1_000_000
+#: a collection shorter than this only counts; a longer one is a span
+GC_SPAN_NS = 1_000_000
+
+# entry of a per-step list: name, start and end on perf_counter_ns, the
+# parent's index in the list (-1: the root), attrs, status
+_NAME, _T0, _T1, _PARENT, _ATTRS, _STATUS = range(6)
+
+# thread ident -> (thread name, that thread's open list), so that the
+# flight recorder shows the phase each thread is inside
+_open_phases = {}
+
+
+def _innermost():
+    """The innermost open phase's entry on this thread, or None."""
+    entries = getattr(_tls, "phases", None)
+    return entries[_tls.cur] if entries else None
+
+
+def phase_attr(key, value):
+    """Set an attribute on the innermost open phase of this thread, from
+    the place where the fact is known (a cache hit, say), without handing
+    the phase down.  Nothing outside a phase."""
+    entry = _innermost()
+    if entry is not None:
+        entry[_ATTRS][key] = value
+
+
+def phase_count(key, n=1):
+    """Add ``n`` to a counting attribute of the innermost open phase."""
+    entry = _innermost()
+    if entry is not None:
+        attrs = entry[_ATTRS]
+        attrs[key] = attrs.get(key, 0) + n
+
+
+class phase:
+    """One timed part of a step: ``with phase("executor.feed_stage"):``.
+
+    Always: a ``jax.profiler.TraceAnnotation`` of that name with the
+    step's number as its ``step`` (on the host plane of whatever device
+    trace is running, whoever started it; outside a session it costs
+    well under a microsecond), and ``time.perf_counter_ns()`` at entry
+    and exit into this thread's per-step list.  With ``fluid.profiler``
+    on, also a row of its host-event table.  Nothing else: no ``Span``,
+    no lock.
+
+    The outermost phase on a thread is the root of its list (a runner's
+    ``*.step``, or a ``host.sync`` outside any step); phases inside it
+    are its descendants and inherit its ``step``.  When the root exits,
+    the list becomes ``Span`` records (:func:`start_span` backdated, so
+    ids, parents, the ring, JSONL and the flight recorder are the
+    ordinary ones) if the step is inside an ambient trace, if
+    ``head_sample`` and :func:`sample_step` says so, **or if it was
+    slow** (:data:`SLOW_FACTOR`; attribute ``slow=true``, not a status).
+    Otherwise the list is dropped; a GC pause noted inside it is then a
+    ``host.gc`` span of its own."""
+
+    __slots__ = ("_entry", "_head_sample", "_ann", "_wall0")
+
+    def __init__(self, name, step=None, head_sample=False, **attrs):
+        if step is not None:
+            attrs["step"] = step
+        self._entry = [name, 0, None, -1, attrs, "ok"]
+        self._head_sample = head_sample
+
+    @property
+    def t0_ns(self):
+        """``time.perf_counter_ns()`` at entry."""
+        return self._entry[_T0]
+
+    @property
+    def t1_ns(self):
+        """``time.perf_counter_ns()`` at exit (None while open)."""
+        return self._entry[_T1]
+
+    @property
+    def dur_ms(self):
+        return (self._entry[_T1] - self._entry[_T0]) / 1e6
+
+    def set_attr(self, key, value):
+        self._entry[_ATTRS][key] = value
+        return self
+
+    def __enter__(self):
+        tls, entry = _tls, self._entry
+        entries = getattr(tls, "phases", None)
+        if entries is None:
+            entries = tls.phases = []
+            tls.cur = -1
+            _open_phases[threading.get_ident()] = (_thread_name(), entries)
+        entry[_PARENT] = tls.cur
+        tls.cur = len(entries)
+        entries.append(entry)
+        self._wall0 = time.time_ns() if _prof.is_profiler_enabled() \
+            else None
+        step = entries[0][_ATTRS].get("step")
+        ann = _prof.trace_annotation()
+        self._ann = ann(entry[_NAME]) if step is None \
+            else ann(entry[_NAME], step=step)
+        self._ann.__enter__()
+        entry[_T0] = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        entry = self._entry
+        entry[_T1] = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._wall0 is not None:
+            _prof.add_host_event(entry[_NAME], self._wall0 // 1000,
+                                 time.time_ns() // 1000)
+        if exc_type is not None:
+            entry[_STATUS] = "error:%s" % exc_type.__name__
+        tls = _tls
+        tls.cur = entry[_PARENT]
+        if entry[_PARENT] < 0:
+            entries, tls.phases = tls.phases, None
+            _open_phases.pop(threading.get_ident(), None)
+            _finish_root(entries, self._head_sample)
+        return False
+
+
+def _is_slow(name, dur_ns):
+    """Over :data:`SLOW_FACTOR` times the median of this thread's last
+    durations of that root?  Then folds ``dur_ns`` in."""
+    windows = getattr(_tls, "medians", None)
+    if windows is None:
+        windows = _tls.medians = {}
+    win = windows.get(name)
+    if win is None:
+        win = windows[name] = deque(maxlen=_MEDIAN_WINDOW)
+    slow = (len(win) >= _MEDIAN_MIN and dur_ns >= _SLOW_FLOOR_NS
+            and dur_ns > SLOW_FACTOR * sorted(win)[len(win) // 2])
+    win.append(dur_ns)
+    return slow
+
+
+def _finish_root(entries, head_sample):
+    """Keep or drop one closed per-step list (the rule is
+    :class:`phase`'s).  Runs outside any lock, so it is also where what
+    the GC hook noted is recorded."""
+    if not tracing_enabled():
+        return
+    get_tracer()        # from the first step on: the GC hook comes with it
+    root = entries[0]
+    slow = _is_slow(root[_NAME], root[_T1] - root[_T0])
+    if slow:
+        root[_ATTRS]["slow"] = True
+    keep = (slow or current_context() is not None
+            or (head_sample and sample_step(root[_ATTRS].get("step"))))
+    for e in entries:
+        if e[_NAME] != "host.gc":
+            continue
+        if keep:        # a child of the phase it interrupted
+            _observe_pause(e[_T0], e[_T1], e[_ATTRS]["generation"])
+        else:           # the step goes, the pause stays: a span of its own
+            _gc_pauses.append((e[_T0], e[_T1], e[_ATTRS], _thread_name()))
+    _drain_gc()
+    if not keep:
+        return
+    now_ns, now_ts = time.perf_counter_ns(), time.time()
+    spans = []
+    for e in entries:
+        spans.append(start_span(
+            e[_NAME], parent=spans[e[_PARENT]] if e[_PARENT] >= 0 else None,
+            start_ts=now_ts - (now_ns - e[_T0]) / 1e9, t0_ns=e[_T0],
+            **e[_ATTRS]))
+    if not spans[0].recording:      # switched off under our feet
+        return
+    for s, e in zip(spans, entries):
+        s._close(e[_STATUS], (e[_T1] - e[_T0]) / 1e6)
+    # children before their parents, as live spans would have closed;
+    # the whole tree under one lock and in one write
+    get_tracer()._on_end(spans[::-1])
+
+
+def _open_phase_records(rank, now_ns):
+    """What :meth:`Tracer.open_spans` adds for the flight recorder: the
+    phases every thread is inside right now."""
+    out, now_ts = [], time.time()
+    for thread, entries in list(_open_phases.values()):
+        for e in list(entries):
+            if e[_T1] is None and e[_T0]:
+                out.append({
+                    "schema": SCHEMA_VERSION, "kind": "span",
+                    "ts": now_ts - (now_ns - e[_T0]) / 1e9,
+                    "t0_ns": e[_T0], "rank": rank, "pid": os.getpid(),
+                    "thread": thread, "trace": None, "span": None,
+                    "parent": None, "name": e[_NAME],
+                    "dur_ms": round((now_ns - e[_T0]) / 1e6, 4),
+                    "status": e[_STATUS], "attrs": dict(e[_ATTRS]),
+                    "open": True})
+    return out
+
+
+# ---------------------------------------------------------------------------
+# GC pauses: one gc.callbacks hook, installed with the tracer
+# ---------------------------------------------------------------------------
+
+# What the hook notes, for :func:`_drain_gc`: collections so far per
+# generation, and the pauses of GC_SPAN_NS or more that fell outside any
+# phase as (t0_ns, t1_ns, attrs, thread name).  CPython runs one
+# collection at a time in a process, callbacks included, so the hook
+# needs no lock for them.
+_gc_counts = [0, 0, 0]
+_gc_pauses = deque()
+_gc_folded = [0, 0, 0]  # of _gc_counts, what the counters already hold
+_gc_handles = {}        # generation -> (counter, histogram)
+_gc_drain_lock = threading.Lock()
+
+
+def _on_gc(when, info):
+    """``gc.callbacks`` hook.  It runs on whichever thread triggered the
+    collection, at any bytecode boundary, inside the critical sections of
+    this module and of the metrics registry too: so it **takes no lock
+    and builds no span**.  It counts the collection and, for a pause of
+    :data:`GC_SPAN_NS` or more, notes the two stamps: in the per-step
+    list of the phase it interrupted, or outside any phase in
+    ``_gc_pauses``.  :func:`_drain_gc` makes counters, observations and
+    spans of the notes."""
+    if when == "start":
+        _tls.gc_t0 = time.perf_counter_ns()
+        return
+    t1 = time.perf_counter_ns()
+    t0 = getattr(_tls, "gc_t0", None)
+    if t0 is None:
+        return
+    _tls.gc_t0 = None
+    gen = info.get("generation")
+    _gc_counts[gen] += 1
+    if t1 - t0 < GC_SPAN_NS:
+        return
+    attrs = {"generation": gen, "collected": info.get("collected")}
+    entries = getattr(_tls, "phases", None)
+    if entries:
+        entries.append(["host.gc", t0, t1, _tls.cur, attrs, "ok"])
+    else:
+        # not _thread_name(): threading.current_thread() takes a lock
+        # on a thread that Python did not start
+        _gc_pauses.append((t0, t1, attrs, getattr(_tls, "name", None)
+                           or "thread-%d" % threading.get_ident()))
+
+
+def _gc_metrics(gen):
+    h = _gc_handles.get(gen)
+    if h is None:
+        h = _gc_handles[gen] = (
+            _m.counter("gc_collections_total", generation=str(gen)),
+            _m.histogram("gc_pause_ms", generation=str(gen)))
+    return h
+
+
+def _observe_pause(t0, t1, gen):
+    if _m.telemetry_enabled():
+        _gc_metrics(gen)[1].observe((t1 - t0) / 1e6)
+
+
+def _drain_gc():
+    """Record what :func:`_on_gc` noted: every collection in
+    ``gc_collections_total{generation}``; every pause outside a kept
+    step in ``gc_pause_ms{generation}`` and as a ``host.gc`` span, a
+    trace of its own (a collection is no part of whatever request or
+    recovery it fell into; a reader places it by its ``t0_ns``).  Called
+    where no lock is held: a root phase's exit, and the tracer's
+    ``records()``, ``open_spans()`` and ``flush()`` before they take
+    theirs."""
+    if not (_gc_pauses or _gc_counts != _gc_folded) \
+            or not _gc_drain_lock.acquire(blocking=False):
+        return          # nothing noted, or another thread is at it
+    try:
+        telemetry = _m.telemetry_enabled()
+        for gen, total in enumerate(_gc_counts):
+            n = total - _gc_folded[gen]
+            _gc_folded[gen] = total
+            if n and telemetry:
+                _gc_metrics(gen)[0].inc(n)
+        # what is there now: recording a pause allocates, and a pause
+        # noted meanwhile waits for the next drain
+        for _ in range(len(_gc_pauses)):
+            t0, t1, attrs, thread = _gc_pauses.popleft()
+            _observe_pause(t0, t1, attrs["generation"])
+            if tracing_enabled() and _tracer is not None:
+                s = Span("host.gc", _new_id(16), None, _tracer,
+                         attrs=attrs, t0_ns=t0, start_ts=time.time()
+                         - (time.perf_counter_ns() - t0) / 1e9)
+                s.thread = thread
+                s.end(dur_ms=(t1 - t0) / 1e6)
+    finally:
+        _gc_drain_lock.release()
 
 
 # ---------------------------------------------------------------------------
@@ -544,37 +868,42 @@ class Tracer:
         with self._lock:
             self._open[s.span_id] = s
 
-    def _on_end(self, s):
-        record = s.to_record()
+    def _on_end(self, spans):
+        """Closed spans into the ring and the file: one, or a step's
+        whole tree at once (one lock, at most one write)."""
+        records = [s.to_record() for s in spans]
         with self._lock:
-            self._open.pop(s.span_id, None)
-            self._ring.append(record)
+            for s in spans:
+                self._open.pop(s.span_id, None)
+            self._ring.extend(records)
             if self._path is not None:
-                self._pending.append(record)
+                self._pending.extend(records)
                 # error/shed/crash terminals are the spans a dying
                 # process must not lose — the journal's URGENT rule
                 if (len(self._pending) >= self.flush_every
-                        or s.status != "ok"):
+                        or any(s.status != "ok" for s in spans)):
                     self._flush_locked()
 
     def records(self):
         """Closed-span ring contents (oldest first)."""
+        _drain_gc()
         with self._lock:
             return list(self._ring)
 
     def open_spans(self):
         """Snapshot of every currently-open span's record (duration =
         time open so far)."""
-        now = time.perf_counter()
+        _drain_gc()
+        now = time.perf_counter_ns()
         with self._lock:
             spans = list(self._open.values())
         out = []
         for s in spans:
             rec = s.to_record()
             rec["open"] = True
-            rec["dur_ms"] = round((now - s._t0) * 1000.0, 4)
+            rec["dur_ms"] = round((now - s.t0_ns) / 1e6, 4)
             out.append(rec)
-        return out
+        return out + _open_phase_records(self.rank, now)
 
     def _flush_locked(self):
         if not self._pending or self._path is None:
@@ -592,6 +921,7 @@ class Tracer:
             pass  # shared-fs hiccup: the ring still has the spans
 
     def flush(self):
+        _drain_gc()
         with self._lock:
             self._flush_locked()
 
@@ -651,6 +981,8 @@ def get_tracer():
             if _tracer is None:
                 t = Tracer(dirname=journal_dir())
                 atexit.register(t.close)
+                if _on_gc not in gc.callbacks:
+                    gc.callbacks.append(_on_gc)
                 _tracer = t
     return _tracer
 
@@ -683,6 +1015,12 @@ def reset_tracing():
         t, _tracer = _tracer, None
     if t is not None:
         t.close()
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    _gc_counts[:] = _gc_folded[:] = [0, 0, 0]
+    for noted in (_gc_handles, _gc_pauses, _open_phases):
+        noted.clear()
+    _tls.phases = _tls.medians = None
     set_tracing_enabled(None)
     set_remote_parent(None)
     global _sample_every
@@ -822,36 +1160,3 @@ def spans_to_chrome_events(records, flow=True):
         events.append({"name": "process_name", "ph": "M", "pid": pid,
                        "args": {"name": "spans:%s" % pid}})
     return events
-
-
-# ---------------------------------------------------------------------------
-# fused-op attribution (reuses the compiler's __fwd_op_id__ breadcrumbs)
-# ---------------------------------------------------------------------------
-
-def fused_op_sources(program):
-    """Map each fused op in ``program`` back to its source ops: fusion
-    rewrites replace N source ops with one ``fused_*`` op but stamp
-    ``__fwd_op_id__`` (backward.py / fusion.py), so a device-trace row
-    named after the fused kernel can be attributed to the Program ops
-    it absorbed.  Returns ``[{"idx", "op", "fwd_op_id", "sources"}]``
-    — ``sources`` are the op types in the program sharing that forward
-    id (empty when the breadcrumb is missing)."""
-    try:
-        ops = list(program.global_block().ops)
-    except Exception:  # noqa: BLE001 - attribution is best-effort
-        return []
-    by_fwd_id = {}
-    for op in ops:
-        fid = op.attrs.get("__op_id__")
-        if fid is not None:
-            by_fwd_id.setdefault(fid, []).append(op.type)
-    out = []
-    for i, op in enumerate(ops):
-        if not op.type.startswith("fused_"):
-            continue
-        fid = op.attrs.get("__fwd_op_id__", op.attrs.get("__op_id__"))
-        sources = [t for t in by_fwd_id.get(fid, [])
-                   if t != op.type]
-        out.append({"idx": i, "op": op.type, "fwd_op_id": fid,
-                    "sources": sources})
-    return out

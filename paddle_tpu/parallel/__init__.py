@@ -11,16 +11,12 @@ The reference's BuildStrategy reduce/fuse/hierarchical knobs are subsumed by
 the XLA partitioner.
 """
 
-import time as _time
-
 import numpy as np
 
 from .. import core
-from ..executor import (_CompiledBlock, _apply_step_results,
-                        _finish_fetches, _host_table_prefetch,
-                        _host_table_push, _register_compile_telemetry,
-                        global_scope, promote_readonly_scope_arrays,
-                        rng_key)
+from ..executor import (_CompiledBlock, _compile_step, _dispatch_step,
+                        _feed_signature, _host_table_prefetch,
+                        _stage_feeds, global_scope)
 from ..observability import runtime as _obs
 from ..observability import tracing as _tr
 from ..framework import Variable, default_main_program
@@ -78,13 +74,26 @@ class SPMDRunner:
         self._feed_cache = FeedCache()
 
     def run(self, executor, feed, fetch_list, scope, return_numpy):
-        import jax
-        import jax.numpy as jnp
+        # resilience hooks (see resilience/): process faults fire here
+        # too, and the finite step-guard covers the DP/ZeRO paths.
+        # (Value-fault gates stay single-process-executor-only — a fed
+        # scalar cannot take the batch sharding this path pins on feeds.)
+        from ..resilience import faults as _rfaults
 
-        if scope is None:
-            scope = global_scope()
-        feed = feed or {}
-        fetch_list = fetch_list or []
+        inj = _rfaults.get_injector()
+        cur_step = inj.on_step() if inj.active else executor._step
+        # the same phases as Executor.run, under this runner's name
+        with _tr.phase("spmd.step", step=cur_step, head_sample=True,
+                       runner="spmd", lazy=not return_numpy) as step_phase:
+            return self._run_step(
+                step_phase, executor, feed or {}, fetch_list or [],
+                global_scope() if scope is None else scope, return_numpy,
+                cur_step)
+
+    def _run_step(self, step_phase, executor, feed, fetch_list, scope,
+                  return_numpy, cur_step):
+        import jax
+
         fetch_names = [
             v.name if isinstance(v, Variable) else str(v) for v in fetch_list
         ]
@@ -93,49 +102,26 @@ class SPMDRunner:
         # (cached clone; the wrapped program itself is never mutated)
         from ..static_analysis import fusion as _fusion
 
-        program, self._last_fusion_report = _fusion.resolve_fused_program(
-            self.program,
-            config=_fusion.FusionConfig.from_build_strategy(
-                self.build_strategy),
-            targets=fetch_names)
+        with _tr.phase("spmd.fusion_resolve"):
+            program, self._last_fusion_report = \
+                _fusion.resolve_fused_program(
+                    self.program,
+                    config=_fusion.FusionConfig.from_build_strategy(
+                        self.build_strategy),
+                    targets=fetch_names)
 
-        # resilience hooks (see resilience/): process faults fire here
-        # too, and the finite step-guard covers the DP/ZeRO paths.
-        # (Value-fault gates stay single-process-executor-only — a fed
-        # scalar cannot take the batch sharding this path pins on feeds.)
-        from ..resilience import faults as _rfaults
         from ..resilience import guard as _rguard
 
-        inj = _rfaults.get_injector()
-        cur_step = inj.on_step() if inj.active else executor._step
         nan_guard = _rguard.guard_enabled(program)
+        batch = None
         if jax.process_count() > 1 and self.mesh is not None:
-            # multi-process cluster (reference nccl2 mode): each process
-            # feeds its LOCAL batch shard; assemble the global batch-
-            # sharded array over the cross-process mesh (the reference's
-            # feed_and_split_tensor_into_local_scopes, inverted — shards
-            # come in, the global view is constructed)
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             batch = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
-            feed_vals = {
-                n: jax.make_array_from_process_local_data(
-                    batch, np.asarray(v))
-                for n, v in feed.items()
-            }
-        else:
-            # same placement cache as Executor.run: an identical host
-            # array re-fed across steps transfers once (the partitioner
-            # re-shards the staged array on later dispatches)
-            from ..pipeline import FetchHandle, _stage
-
-            feed_vals = {}
-            for n, v in feed.items():
-                if isinstance(v, FetchHandle):
-                    v = v.device_value  # chained lazy fetch
-                feed_vals[n] = (
-                    _stage(v, name=n, cache=self._feed_cache)
-                    if isinstance(v, np.ndarray) else jnp.asarray(v))
+        # same placement cache as Executor.run (the partitioner re-shards
+        # the staged array on later dispatches)
+        feed_vals = _stage_feeds("spmd", feed, self._feed_cache,
+                                 batch_sharding=batch)
         # host-resident tables under DP: prefetch the GLOBAL batch's
         # slab (GSPMD shards it over the data axis like any feed)
         if (getattr(program, "_host_tables", None)
@@ -159,66 +145,34 @@ class SPMDRunner:
         host_active, host_grad_fetches = _host_table_prefetch(
             program, feed, feed_vals)
         fetch_names = fetch_names + host_grad_fetches
-        sig = tuple(
-            (n, tuple(v.shape), str(v.dtype))
-            for n, v in sorted(feed_vals.items())
-        )
-        key_tuple = (id(program), program._version, id(scope), sig,
-                     tuple(fetch_names), nan_guard,
-                     getattr(program, "_fusion_sig", None))
-        compiled = self._cache.get(key_tuple)
-        _obs.record_jit_cache(compiled is not None, runner="spmd")
+        with _tr.phase("spmd.lookup"):
+            key_tuple = (id(program), program._version, id(scope),
+                         _feed_signature(feed_vals), tuple(fetch_names),
+                         nan_guard, getattr(program, "_fusion_sig", None))
+            compiled = self._cache.get(key_tuple)
+            _obs.record_jit_cache(compiled is not None, runner="spmd")
         if compiled is None:
-            _t_compile = _time.perf_counter()
-            compiled = _CompiledBlock(
-                program,
-                program.global_block(),
-                list(feed_vals),
-                fetch_names,
-                scope,
-                "train",
-                mesh=self.mesh,
-                accumulate_steps=self.accumulate_steps,
-                iters_per_run=self.iters_per_run,
-                shard_opt_state=self.shard_opt_state,
-                nan_guard=nan_guard,
-            )
-            _obs.record_compile(
-                (_time.perf_counter() - _t_compile) * 1000.0,
-                runner="spmd")
-            self._cache[key_tuple] = compiled
-            _register_compile_telemetry(compiled, program, feed_vals,
-                                        fetch_names)
+            compiled = self._cache[key_tuple] = _compile_step(
+                "spmd",
+                lambda: _CompiledBlock(
+                    program,
+                    program.global_block(),
+                    list(feed_vals),
+                    fetch_names,
+                    scope,
+                    "train",
+                    mesh=self.mesh,
+                    accumulate_steps=self.accumulate_steps,
+                    iters_per_run=self.iters_per_run,
+                    shard_opt_state=self.shard_opt_state,
+                    nan_guard=nan_guard,
+                ),
+                program, feed_vals, fetch_names)
 
-        rw = {n: scope.get(n) for n in compiled.rw_names}
-        ro = promote_readonly_scope_arrays(scope, compiled)
-        seed = program.random_seed or 0
-        base_key = jax.random.fold_in(rng_key(seed), executor._step)
-        executor._step += 1
-        _t_step = _time.perf_counter()
-        step_span = (_tr.span("spmd.step", step=cur_step)
-                     if _tr.sample_step(cur_step) else _tr.NULL_SPAN)
-        if step_span.recording:
-            for ring, shape in _obs.collective_step_shape().items():
-                step_span.set_attr(ring, shape)
-        with step_span:
-            with _tr.span_if_traced("spmd.dispatch"):
-                fetches, new_rw, fresh = compiled.jitted(
-                    feed_vals, rw, ro, base_key)
-            _dispatch_ms = (_time.perf_counter() - _t_step) * 1000.0
-            fetches = _apply_step_results(
-                compiled, scope, fetches, new_rw, fresh, fetch_names,
-                host_active, host_grad_fetches, cur_step)
-            result = _finish_fetches(
-                fetches, return_numpy, fetch_names=fetch_names,
-                state_names=(tuple(compiled.rw_names)
-                             + tuple(compiled.fresh_persist)))
-        _obs.record_step(
-            "spmd", cur_step,
-            (_time.perf_counter() - _t_step) * 1000.0,
-            dispatch_ms=_dispatch_ms,
-            drift_key=getattr(compiled, "_drift_key", None))
-        return result
+        return _dispatch_step(
+            "spmd", step_phase, compiled, program, scope, feed_vals,
+            executor, cur_step, fetch_names, host_active,
+            host_grad_fetches, return_numpy)
 
 
 class ParallelExecutor:

@@ -16,8 +16,13 @@ step**.  This module owns the three pieces that keep the loop sync-free:
 * :func:`host_values` / :func:`materialize` — the ONE device→host sync
   point: start every D2H copy asynchronously, then gather, so N fetches
   cost one pipeline-ordered round trip instead of N blocking
-  ``np.asarray`` calls.  Profiler-visible as ``executor.device_compute``
-  (waiting for the in-flight step) + ``executor.host_sync`` (the copy).
+  ``np.asarray`` calls.  One ``host.sync`` phase (on a device trace's
+  host plane too); with ``fluid.profiler`` on it splits into
+  ``executor.device_compute`` (waiting for the in-flight step) +
+  ``executor.host_sync`` (the copy).  A handle that a lazy
+  ``Executor.run`` returned carries its step, and materialising it is
+  where the program learns that the step is done
+  (``step_interval_ms``, ``step_latency_ms``).
 * :class:`DeviceFeedPipeline` — background-thread prefetch that
   ``jax.device_put``\\ s upcoming feed batches with a configurable depth
   (default 2, env ``PADDLE_TPU_PIPELINE_DEPTH``), so H2D transfer of
@@ -110,10 +115,20 @@ def host_values(values):
         return [np.asarray(v) for v in vals]
 
     from . import profiler as _prof
+    from .observability import runtime as _obs
     from .observability import tracing as _tr
 
-    t0 = time.perf_counter()
-    with _tr.span_if_traced("host.sync", handles=len(dev)):
+    # per executor (its last_done list), the step_info of the newest step
+    # among the handles waited for
+    done = {}
+    for v in values:
+        info = getattr(v, "step_info", None)
+        if info is not None and not v.synced:
+            prev = done.get(id(info[4]))
+            if prev is None or info[1] > prev[1]:
+                done[id(info[4])] = info
+    step = max((info[1] for info in done.values()), default=None)
+    with _tr.phase("host.sync", step=step, handles=len(dev)) as sync:
         if _prof.is_profiler_enabled():
             with _prof.record_event("executor.device_compute"):
                 _block_all(dev)
@@ -121,13 +136,12 @@ def host_values(values):
                 out = _copy_all(vals)
         else:
             out = _copy_all(vals)
-    wait_ms = (time.perf_counter() - t0) * 1e3
+    for info in done.values():
+        _obs.record_step_done(*info)
     with _sync_lock:
         _sync_count += 1
-        _sync_wait_ms += wait_ms
-    from .observability import runtime as _obs
-
-    _obs.record_sync(wait_ms, handles=len(dev))
+        _sync_wait_ms += sync.dur_ms
+    _obs.record_sync(sync.dur_ms, handles=len(dev))
     return out
 
 
@@ -154,13 +168,20 @@ class FetchHandle:
 
     Materializing RELEASES the device buffer (the host copy takes over),
     so a loop that accumulates handles and syncs them in windows holds
-    device memory proportional to the un-synced window, not the run."""
+    device memory proportional to the un-synced window, not the run.
 
-    __slots__ = ("_dev", "_host")
+    ``step_info`` is ``(runner, step, dispatch_ns, drift_key,
+    last_done)`` on a handle that a lazy run returned (None otherwise):
+    which step this value belongs to, when that step was dispatched
+    (``time.perf_counter_ns()``) and its executor's newest completion
+    seen, for :func:`host_values`."""
 
-    def __init__(self, device_value):
+    __slots__ = ("_dev", "_host", "step_info")
+
+    def __init__(self, device_value, step_info=None):
         self._dev = device_value
         self._host = None
+        self.step_info = step_info
 
     @property
     def device_value(self):
@@ -175,7 +196,7 @@ class FetchHandle:
 
     def numpy(self):
         if self._host is None:
-            self._host = host_values([self._dev])[0]
+            self._host = host_values([self])[0]
             self._dev = None  # release the device buffer
         return self._host
 
@@ -240,7 +261,7 @@ def materialize(fetches):
     need = [h for h in flat
             if isinstance(h, FetchHandle) and not h.synced]
     if need:
-        hosts = host_values([h.device_value for h in need])
+        hosts = host_values(need)
         for h, a in zip(need, hosts):
             h._host = a
             h._dev = None  # release the device buffer
@@ -382,7 +403,10 @@ def _stage(value, name=None, cache=None):
             return hit
     import jax
 
+    from .observability import runtime as _obs
+
     dev = jax.device_put(value)
+    _obs.record_feed_h2d(value.nbytes)
     if cache is not None and name is not None:
         cache.put(name, value, dev)
     return dev

@@ -4,10 +4,11 @@ Reference surfaces reproduced:
 * ``platform/profiler.h`` — RAII ``RecordEvent`` wrapped around every op
   run, thread-local ``EventList``, ``EnableProfiler/DisableProfiler``
   printing tables aggregated by total/max/ave/calls.  Here host events
-  come from ``record_event`` scopes and the Executor's phase hooks
-  (``executor.lower_and_jit`` / ``executor.dispatch`` /
-  ``executor.device_compute`` / ``executor.host_sync`` — the async-
-  dispatch split :func:`host_event_stats` documents) — per-op host
+  come from ``record_event`` scopes and the runners' step phases
+  (``observability.tracing.phase``: ``executor.step`` and its children
+  ``feed_stage`` / ``rng_key`` / ``dispatch`` / ..., ``host.sync`` with
+  its ``executor.device_compute`` / ``executor.host_sync`` split
+  :func:`host_event_stats` documents) — per-op host
   timing does not exist under a whole-block jit, so phases are the
   host-side unit of accounting (the per-op cost lives in the device
   trace, which XLA annotates with HLO op names).
@@ -74,8 +75,9 @@ def _aggregate():
 
 def host_event_stats():
     """Aggregated host events while profiling is (or was) on:
-    ``{name: {"calls", "total_ms", "max_ms", "min_ms"}}``.  The executor
-    splits every run into ``executor.dispatch`` (enqueue under async
+    ``{name: {"calls", "total_ms", "max_ms", "min_ms"}}``.  Beside the
+    other phases of ``executor.step``, a profile has
+    ``executor.dispatch`` (enqueue under async
     dispatch), ``executor.device_compute`` (waiting for the in-flight
     step at a sync point) and ``executor.host_sync`` (D2H copies) — so
     ``dispatch ≪ device_compute`` in a profile means the loop overlaps,
@@ -436,10 +438,10 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile",
 _trace_annotation = None
 
 
-def _get_trace_annotation():
+def trace_annotation():
     """``jax.profiler.TraceAnnotation``, imported once — record_event
-    sits on the executor's per-step path, so the disabled case must not
-    pay an ``import jax`` lookup every call."""
+    and ``observability.tracing.phase`` sit on the executor's per-step
+    path, so they must not pay an ``import jax`` lookup every call."""
     global _trace_annotation
     if _trace_annotation is None:
         import jax
@@ -448,26 +450,28 @@ def _get_trace_annotation():
     return _trace_annotation
 
 
+def add_host_event(name, t0_us, t1_us):
+    """One row of the host-event table (wall-clock epoch microseconds,
+    so traces from different hosts merge sensibly in tools/timeline.py);
+    dropped unless profiling."""
+    if _enabled:
+        with _events_lock:
+            _events.append((name, threading.get_ident() % 10000,
+                            t0_us, t1_us))
+
+
 @contextlib.contextmanager
 def record_event(name):
     """Scoped annotation: host event (when profiling) + device trace
     annotation (reference RecordEvent, profiler.h:81)."""
-    if not _enabled:
-        # still forward to the device tracer so annotations show up in
-        # externally started jax traces
-        with _get_trace_annotation()(name):
-            yield
-        return
-    # wall-clock epoch so traces from different hosts merge sensibly in
-    # tools/timeline.py
+    # forwarded to the device tracer whether profiling or not, so that
+    # annotations show up in externally started jax traces
     t0 = time.time_ns() // 1000
     try:
-        with _get_trace_annotation()(name):
+        with trace_annotation()(name):
             yield
     finally:
-        t1 = time.time_ns() // 1000
-        with _events_lock:
-            _events.append((name, threading.get_ident() % 10000, t0, t1))
+        add_host_event(name, t0, time.time_ns() // 1000)
 
 
 @contextlib.contextmanager

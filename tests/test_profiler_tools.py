@@ -38,14 +38,16 @@ class TestProfiler:
         profiler.stop_profiler(sorted_key="total", profile_path=trace)
         out = capsys.readouterr().out
         assert "Profiling Report" in out
-        assert "executor.run" in out
+        assert "executor.step" in out
         assert "user_scope" in out
 
         with open(trace) as f:
             t = json.load(f)
         names = {ev["name"] for ev in t["traceEvents"]}
-        assert {"user_scope", "executor.run",
-                "executor.lower_and_jit"} <= names
+        # the step's phases are rows of the host-event table
+        assert {"user_scope", "executor.step", "executor.compile",
+                "executor.feed_stage", "executor.dispatch",
+                "host.sync"} <= names
         # unified export: host/span events are X (complete) with real
         # durations; the tracing merge may add metadata (M) rows and
         # flow arrows (s/f) for cross-thread/rank causality

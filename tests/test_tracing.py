@@ -7,6 +7,7 @@ a dispatcher crash.  The full multi-process elastic drill (ONE trace
 across victim + survivors) is the slow-marked acceptance test.
 """
 
+import gc
 import json
 import os
 import subprocess
@@ -309,6 +310,444 @@ class TestCriticalPath:
     def test_empty_dir_exits_2(self, tmp_path, capsys):
         assert trace_cli.main([str(tmp_path)]) == 2
         capsys.readouterr()
+
+
+# ---------------------------------------------------------------------------
+# step phases (ISSUE 26): the step's span tree, tail sampling, a true
+# step time, GC pauses
+# ---------------------------------------------------------------------------
+PHASES = ["fusion_resolve", "feed_stage", "lookup", "gather_state",
+          "rng_key", "dispatch", "apply_results", "finish_fetches"]
+
+
+def _train_program():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        loss = fluid.layers.mean(fluid.layers.fc(x, size=4))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    return main, startup, loss
+
+
+def _batch(i=0):
+    return {"x": np.random.RandomState(i).rand(8, 8).astype("float32")}
+
+
+def _steps_of(records, runner):
+    """Kept steps of ``runner`` as ``(step record, its descendants)``."""
+    out = []
+    for root in records:
+        if root["name"] != runner + ".step":
+            continue
+        ids, kids = {root["span"]}, []
+        for r in sorted(records, key=lambda r: r["t0_ns"]):
+            if r["parent"] in ids:
+                ids.add(r["span"])
+                kids.append(r)
+        out.append((root, kids))
+    return out
+
+
+def _end_ns(rec):
+    return rec["t0_ns"] + rec["dur_ms"] * 1e6
+
+
+def _collect_cycles(n=300000):
+    """Force a collection that takes well over a millisecond: ``n`` lists
+    in one cycle, garbage by the time ``gc.collect()`` runs."""
+    junk = [[] for _ in range(n)]
+    for a, b in zip(junk, junk[1:]):
+        a.append(b)
+    junk[-1].append(junk[0])
+    del junk, a, b
+    gc.collect()
+
+
+class TestStepPhases:
+    @pytest.mark.parametrize("runner", ["executor", "spmd"])
+    def test_kept_step_tree(self, runner, monkeypatch):
+        """Both runners: a kept step's tree has exactly the table's
+        children under the runner's prefix, in order, none overlapping,
+        each inside its parent; ``compile`` on the first step alone;
+        ``host.sync`` inside ``finish_fetches`` when the call synced."""
+        monkeypatch.setenv("PADDLE_TPU_TRACE_SAMPLE", "1")
+        obs.reset_telemetry()
+        main, startup, loss = _train_program()
+        prog = main if runner == "executor" else \
+            fluid.CompiledProgram(main).with_data_parallel(
+                loss_name=loss.name)
+        exe = fluid.Executor(fluid.CPUPlace())
+        with scope_guard(Scope()):
+            exe.run(startup)
+            exe.run(prog, feed=_batch(0), fetch_list=[loss])
+            exe.run(prog, feed=_batch(1), fetch_list=[loss])
+            h = exe.run(prog, feed=_batch(2), fetch_list=[loss],
+                        return_numpy=False)
+            recs = tr.get_tracer().records()
+            float(h[0])
+        first, steady, lazy = _steps_of(recs, runner)[-3:]
+        with_compile = PHASES[:3] + ["compile"] + PHASES[3:]
+        for (root, kids), names, synced in ((first, with_compile, True),
+                                            (steady, PHASES, True),
+                                            (lazy, PHASES, False)):
+            # a collection may fall anywhere (the compile allocates)
+            kids = [k for k in kids if k["name"] != "host.gc"]
+            direct = [k for k in kids if k["parent"] == root["span"]]
+            assert [k["name"] for k in direct] == [
+                "%s.%s" % (runner, n) for n in names]
+            assert root["attrs"]["runner"] == runner
+            assert root["attrs"]["lazy"] is (not synced)
+            for k in kids:
+                assert k["trace"] == root["trace"]
+                parent = root if k["parent"] == root["span"] else \
+                    [p for p in kids if p["span"] == k["parent"]][0]
+                assert parent["t0_ns"] <= k["t0_ns"]
+                assert _end_ns(k) <= _end_ns(parent) + 1e3
+            for a, b in zip(direct, direct[1:]):
+                assert _end_ns(a) <= b["t0_ns"] + 1e3, (a, b)
+            inner = [k for k in kids if k["parent"] != root["span"]]
+            assert [k["name"] for k in inner] == (
+                ["host.sync"] if synced else [])
+            if synced:
+                assert inner[0]["parent"] == direct[-1]["span"]
+            stage = direct[1]["attrs"]
+            assert stage["bytes"] == 8 * 8 * 4 and stage["misses"] == 1
+            assert direct[2]["attrs"]["hit"] is (names is PHASES)
+            assert direct[-1]["attrs"]["handles"] == (0 if synced else 1)
+        compile_, = [k for k in first[1]
+                     if k["name"] == runner + ".compile"]
+        assert compile_["attrs"]["compile_ms"] > 0
+        # the tool's critical path takes the children as they are
+        root, kids = steady
+        kids = [k for k in kids if k["name"] != "host.gc"]
+        segments = trace_cli.critical_path([root] + kids)
+        assert {rec["name"] for rec, _ in segments} >= {
+            "%s.%s" % (runner, n) for n in PHASES}
+        assert sum(ms for _, ms in segments) == pytest.approx(
+            root["dur_ms"], rel=0.05)
+
+    def test_slow_step_is_kept_though_sampled_out(self, monkeypatch):
+        monkeypatch.setenv("PADDLE_TPU_TRACE_SAMPLE", "0")
+        obs.reset_telemetry()
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        from paddle_tpu import pipeline
+
+        stage = pipeline._stage
+
+        def slow_stage(*args, **kwargs):
+            time.sleep(0.25)
+            return stage(*args, **kwargs)
+
+        with scope_guard(Scope()):
+            exe.run(startup)
+            for i in range(12):
+                exe.run(main, feed=_batch(i), fetch_list=[loss])
+            # sampled out: whatever the ring holds so far was kept for
+            # being slow (a busy CPU can make a plain step three times
+            # the median)
+            assert all(s["attrs"].get("slow") for s, _ in _steps_of(
+                tr.get_tracer().records(), "executor"))
+            monkeypatch.setattr(pipeline, "_stage", slow_stage)
+            exe.run(main, feed=_batch(13), fetch_list=[loss])
+        (root, kids), = [s for s in _steps_of(tr.get_tracer().records(),
+                                              "executor")
+                         if s[0]["dur_ms"] >= 250]
+        assert root["attrs"]["slow"] is True and root["status"] == "ok"
+        longest = max(kids, key=lambda k: k["dur_ms"])
+        assert longest["name"] == "executor.feed_stage"
+        assert longest["dur_ms"] >= 250
+
+    def test_slow_host_sync_outside_a_step_is_kept(self, monkeypatch):
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        from paddle_tpu import pipeline
+
+        copy_all = pipeline._copy_all
+
+        def slow_copy(vals):
+            time.sleep(0.25)
+            return copy_all(vals)
+
+        with scope_guard(Scope()):
+            exe.run(startup)
+            for i in range(12):
+                float(exe.run(main, feed=_batch(i), fetch_list=[loss],
+                              return_numpy=False)[0])
+            monkeypatch.setattr(pipeline, "_copy_all", slow_copy)
+            h = exe.run(main, feed=_batch(12), fetch_list=[loss],
+                        return_numpy=False)[0]
+            float(h)
+        slow = [r for r in tr.get_tracer().records()
+                if r["name"] == "host.sync" and r["dur_ms"] >= 250]
+        assert len(slow) == 1 and slow[0]["parent"] is None
+        assert slow[0]["attrs"]["slow"] is True
+        assert slow[0]["attrs"]["step"] == h.step_info[1] == 13
+        assert slow[0]["dur_ms"] >= 250
+
+    def test_lazy_step_time_is_the_interval_not_the_enqueue(self):
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+
+        def count(name):
+            return obs.histogram(name, runner="executor").count
+
+        with scope_guard(Scope()):
+            exe.run(startup)
+            exe.run(main, feed=_batch(0), fetch_list=[loss])
+            wall0, enq0 = count("step_wall_ms"), count("step_enqueue_ms")
+            handles = [exe.run(main, feed=_batch(i), fetch_list=[loss],
+                               return_numpy=False)[0] for i in range(4)]
+            # four steps enqueued, none read: no step time was observed
+            assert count("step_enqueue_ms") == enq0 + 4
+            assert count("step_wall_ms") == wall0
+            assert count("step_interval_ms") == 0
+            assert obs.runtime.last_step_info()["step"] == 1
+            float(handles[1])       # two steps after the synced one
+            assert count("step_interval_ms") == 1
+            assert count("step_latency_ms") == 1
+            assert count("step_wall_ms") == wall0 + 1
+            assert obs.runtime.last_step_info()["step"] == 3
+            float(handles[0])       # an older step: nothing new is learnt
+            assert count("step_interval_ms") == 1
+            time.sleep(0.05)
+            float(handles[3])
+            assert count("step_interval_ms") == 2
+            # the interval is per step advanced: 50 ms and more, over two
+            # steps
+            h = obs.histogram("step_interval_ms", runner="executor")
+            assert h.to_dict()["max"] >= 25
+            assert obs.runtime.last_step_info()["step_ms"] >= 25
+
+    def test_step_intervals_are_per_executor(self):
+        """Step numbers are each executor's own: a train and an eval
+        executor whose lazy handles interleave each get the interval
+        between their own completions."""
+        main, startup, loss = _train_program()
+        slow_exe = fluid.Executor(fluid.CPUPlace())
+        fast_exe = fluid.Executor(fluid.CPUPlace())
+        h = obs.histogram("step_interval_ms", runner="executor")
+        with scope_guard(Scope()):
+            slow_exe.run(startup)
+            for _ in range(5):      # fast_exe's counter runs ahead
+                fast_exe.run(main, feed=_batch(0), fetch_list=[loss])
+            for i in range(3):
+                a = slow_exe.run(main, feed=_batch(i), fetch_list=[loss],
+                                 return_numpy=False)[0]
+                b = fast_exe.run(main, feed=_batch(i), fetch_list=[loss],
+                                 return_numpy=False)[0]
+                assert a.step_info[1] < b.step_info[1]
+                time.sleep(0.03)
+                float(b)
+                float(a)    # a lower number than b's: still a completion
+            # three each: the synced runs before (the startup program, the
+            # five steps) were each executor's first completions
+            assert h.count == 6
+            assert slow_exe._last_done[0] == 3
+            assert fast_exe._last_done[0] == 7
+            # each over its own 30 ms sleep, none divided by the other's
+            # step numbers or measured from the other's completion
+            assert h.to_dict()["min"] >= 25
+
+    def test_gc_pause_is_a_span_and_an_observation(self):
+        import gc
+
+        tr.get_tracer()                 # the hook comes with the tracer
+        junk = []
+        for _ in range(300000):
+            a, b = [], []
+            a.append(b)
+            b.append(a)
+            junk.append(a)
+        del junk
+        gc.collect()
+        spans = [r for r in tr.get_tracer().records()
+                 if r["name"] == "host.gc"]
+        assert spans, "no host.gc span for a long collection"
+        # building the graph sets off collections of its own, which
+        # find little; the forced one finds the graph
+        forced = max(spans, key=lambda r: r["attrs"]["collected"])
+        assert forced["dur_ms"] >= 1.0
+        assert forced["attrs"]["generation"] == 2
+        assert forced["attrs"]["collected"] >= 500000
+        assert obs.histogram("gc_pause_ms", generation="2").count >= 1
+        assert obs.counter("gc_collections_total",
+                           generation="2").value >= 1
+        obs.reset_telemetry()
+        assert tr._on_gc not in gc.callbacks
+
+    @pytest.mark.parametrize("sample", ["1", "0"])
+    def test_gc_pause_inside_a_step(self, sample, monkeypatch):
+        """In a kept step the pause is a child of the phase it
+        interrupted; a pause does not keep a step, and in a dropped one
+        it is a span of its own.  Observed once either way."""
+        monkeypatch.setenv("PADDLE_TPU_TRACE_SAMPLE", sample)
+        obs.reset_telemetry()
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        from paddle_tpu import pipeline
+
+        stage = pipeline._stage
+
+        def collecting_stage(*args, **kwargs):
+            _collect_cycles()
+            return stage(*args, **kwargs)
+
+        with scope_guard(Scope()):
+            exe.run(startup)
+            exe.run(main, feed=_batch(0), fetch_list=[loss])
+            monkeypatch.setattr(pipeline, "_stage", collecting_stage)
+            exe.run(main, feed=_batch(1), fetch_list=[loss])
+        records = tr.get_tracer().records()
+        pauses = [r for r in records if r["name"] == "host.gc"
+                  and r["attrs"]["collected"] >= 300000]
+        assert len(pauses) == 1 and pauses[0]["dur_ms"] >= 1.0
+        # building the graph sets off collections of its own: each pause
+        # is one span and one observation
+        assert obs.histogram("gc_pause_ms", generation="2").count == len(
+            [r for r in records if r["name"] == "host.gc"
+             and r["attrs"]["generation"] == 2])
+        steps = _steps_of(records, "executor")
+        if sample == "0":
+            assert steps == [] and pauses[0]["parent"] is None
+            return
+        stage_span, = [k for k in steps[-1][1]
+                       if k["name"] == "executor.feed_stage"]
+        assert pauses[0]["parent"] == stage_span["span"]
+        assert stage_span["t0_ns"] <= pauses[0]["t0_ns"] \
+            and _end_ns(pauses[0]) <= _end_ns(stage_span)
+
+    @pytest.mark.parametrize("where", ["tracer_lock", "flush_locked",
+                                       "id_lock", "registry_lock"])
+    def test_gc_inside_a_critical_section_takes_no_lock(
+            self, where, tmp_path, monkeypatch):
+        """The hook runs wherever a collection fires: inside the tracer's
+        lock (and its flush), the span-id lock and the metrics registry's
+        lock.  None of them is reentrant, so it may take none; what it
+        noted is recorded after, outside them."""
+        from paddle_tpu.observability import metrics as om
+
+        _trace_dir(monkeypatch, tmp_path, flush=1)
+        tracer = tr.get_tracer()
+
+        def inside():
+            if where == "flush_locked":
+                dumps = tr.json.dumps
+
+                def collecting_dumps(*args, **kwargs):
+                    _collect_cycles()
+                    return dumps(*args, **kwargs)
+
+                monkeypatch.setattr(tr.json, "dumps", collecting_dumps)
+                with tr.span("flushed"):    # ends under the lock, flushes
+                    pass
+                monkeypatch.setattr(tr.json, "dumps", dumps)
+                return
+            lock = {"tracer_lock": tracer._lock, "id_lock": tr._id_lock,
+                    "registry_lock": om.registry()._lock}[where]
+            with lock:
+                _collect_cycles()
+
+        t = threading.Thread(target=inside, daemon=True)
+        t.start()
+        t.join(60)
+        assert not t.is_alive(), "deadlock: the GC hook took a lock"
+        pauses = [r for r in tracer.records() if r["name"] == "host.gc"]
+        assert pauses and max(p["dur_ms"] for p in pauses) >= 1.0
+        assert all(p["parent"] is None for p in pauses)
+        assert obs.histogram("gc_pause_ms", generation="2").count >= 1
+
+    def test_gc_hook_with_collections_all_the_time(
+            self, tmp_path, monkeypatch):
+        """Four threads of steps, spans, counters and ring reads, writing
+        to a telemetry directory, while every few allocations set off a
+        collection and each collection counts as a pause: the hook fires
+        inside every critical section there is.  (The version that built
+        its span inside the hook hangs here within a second.)"""
+        tdir = _trace_dir(monkeypatch, tmp_path, flush=1)
+        monkeypatch.setattr(tr, "GC_SPAN_NS", 0)
+        tracer = tr.get_tracer()
+        stop = time.time() + 1.5
+
+        def work(i):
+            n = 0
+            while time.time() < stop:
+                with tr.phase("executor.step", step=n, head_sample=True):
+                    with tr.phase("executor.feed_stage"):
+                        junk = [[] for _ in range(20)]
+                        for a in junk:
+                            a.append(junk)
+                with tr.span("request", i=i):
+                    pass
+                obs.counter("stress_total", k=str(n % 50)).inc()
+                tracer.records()
+                n += 1
+
+        threads = [threading.Thread(target=work, args=(i,), daemon=True)
+                   for i in range(4)]
+        threshold = gc.get_threshold()
+        gc.set_threshold(5, 2, 2)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            gc.set_threshold(*threshold)
+        assert not any(t.is_alive() for t in threads), "deadlock"
+        tracer.flush()
+        names = {r["name"] for r in tr.read_traces(tdir)}
+        assert {"host.gc", "executor.step", "executor.feed_stage",
+                "request"} <= names
+        seen = sum(obs.counter("gc_collections_total",
+                               generation=str(g)).value for g in range(3))
+        pauses = sum(obs.histogram("gc_pause_ms",
+                                   generation=str(g)).count
+                     for g in range(3))
+        assert seen >= pauses > 100
+
+    def test_tracing_off_allocates_no_span(self, monkeypatch):
+        monkeypatch.setenv("PADDLE_TPU_TRACE_SAMPLE", "1")
+        obs.reset_telemetry()
+        tr.set_tracing_enabled(False)
+        made = []
+        init = tr.Span.__init__
+
+        def counting(self, *args, **kwargs):
+            made.append(args[0])
+            init(self, *args, **kwargs)
+
+        monkeypatch.setattr(tr.Span, "__init__", counting)
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        with scope_guard(Scope()):
+            exe.run(startup)
+            for i in range(3):
+                exe.run(main, feed=_batch(i), fetch_list=[loss])
+                float(exe.run(main, feed=_batch(i), fetch_list=[loss],
+                              return_numpy=False)[0])
+        assert made == [] and len(tr.get_tracer()) == 0
+        # the step's own clock does not hang on the tracer
+        assert obs.histogram("step_enqueue_ms",
+                             runner="executor").count == 7
+
+    def test_flight_record_shows_the_open_phase(self, monkeypatch):
+        main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        from paddle_tpu import pipeline
+
+        stage, seen = pipeline._stage, []
+
+        def peeking_stage(*args, **kwargs):
+            seen.extend(r["name"] for r in tr.get_tracer().open_spans())
+            return stage(*args, **kwargs)
+
+        monkeypatch.setattr(pipeline, "_stage", peeking_stage)
+        with scope_guard(Scope()):
+            exe.run(startup)
+            exe.run(main, feed=_batch(0), fetch_list=[loss])
+        assert seen == ["executor.step", "executor.feed_stage"]
+        assert tr.get_tracer().open_spans() == []
 
 
 # ---------------------------------------------------------------------------
