@@ -569,6 +569,11 @@ class _CompiledBlock:
                 "num_iteration_per_run cannot combine with "
                 "batch_merge_repeat: both wrap the step in a scan")
         self.trip_counts = dict(trip_counts or {})
+        # what the grad op of each Mosaic kernel site took when the step
+        # was traced (``LoweringContext.residual_sites``), and the open
+        # ``compile`` phase that reports it after the first dispatch
+        self.residual_sites = {}
+        self.compile_phase = None
         ext_reads, written, persist_written = _analyze_block(
             block, feed_names, fetch_names
         )
@@ -635,6 +640,7 @@ class _CompiledBlock:
             env.update(feeds)
             ctx = op_registry.LoweringContext(base_key=key, mode=mode)
             ctx.trip_counts = self.trip_counts
+            ctx.residual_sites = self.residual_sites
             gate = feeds.get(_FAULT_GATE_FEED)
             if gate is not None:
                 from .resilience import faults as _rfaults
@@ -870,6 +876,7 @@ class _AccumRunner:
             ctx = op_registry.LoweringContext(
                 base_key=jax.random.fold_in(key, idx), mode=self.mode)
             ctx.fault_value_hook = fault_hook
+            ctx.residual_sites = cb.residual_sites
             _run_ops_into_env(self.block, e, ctx, ops=self.head)
             return (
                 {n: e[n] for n in self.grad_reads},
@@ -1025,13 +1032,29 @@ def _run_ops_into_env(block, env, ctx, ops=None):
     can be attributed back to Program ops — the whole-block jit makes
     host-side per-op timing impossible, and this is the device-side
     equivalent of the reference's per-op profiler tables
-    (platform/profiler.h:166).  Trace-time only: zero runtime cost."""
+    (platform/profiler.h:166).  Trace-time only: zero runtime cost.
+
+    **One forward per Mosaic kernel site.**  A generic grad op re-derives
+    its forward under ``jax.vjp``; XLA merges that with the forward op
+    where both are XLA ops, never where they are a Mosaic custom call.
+    So where a forward op's site routes to such a kernel
+    (``OpDef.kernel_residuals``) and its grad twin is in THIS call's op
+    list, the forward is lowered once, through ``jax.vjp``, and kept
+    (``kept``, local to this call: no tracer crosses into another trace,
+    and a list with no backward lowers exactly as before); the grad op,
+    if it is fed the very values the forward saw, takes the kept
+    residuals.  Anything else is the generic arm.  What each kernel site
+    took is noted in ``ctx.residual_sites`` (if the caller set one)."""
     import jax
 
     from .ops import control_flow as cf_ops
 
     fault_hook = getattr(ctx, "fault_value_hook", None)
-    for i, op in enumerate(block.ops if ops is None else ops):
+    ops = block.ops if ops is None else ops
+    twin_ids = {op.attrs["__fwd_op_id__"] for op in ops
+                if "__fwd_op_id__" in op.attrs}
+    kept = {}   # forward op id -> registry.KeptForward
+    for i, op in enumerate(ops):
         if op.type in ("feed", "fetch"):
             continue
         if op.type in cf_ops.SUB_BLOCK_OPS:
@@ -1052,8 +1075,18 @@ def _run_ops_into_env(block, env, ctx, ops=None):
             ins[slot] = vals
         op_id = op.attrs.get("__fwd_op_id__", op.attrs.get("__op_id__", 0))
         with jax.named_scope("pd%d_%s" % (i, op.type)):
-            outs = op_registry.call_op(opdef, ctx, ins, op.attrs,
-                                       op_id=op_id)
+            if (op.attrs.get("__op_id__") in twin_ids
+                    and op_registry.routes_to_kernel(opdef, ctx, ins,
+                                                     op.attrs)):
+                outs, kept[op_id] = op_registry.call_op_keeping_vjp(
+                    opdef, ctx, ins, op.attrs, op_id=op_id)
+            elif opdef.fwd_def is not None \
+                    and opdef.fwd_def.kernel_residuals is not None:
+                outs = _lower_kernel_site_grad(opdef, ctx, ins, op.attrs,
+                                               op_id, kept.get(op_id))
+            else:
+                outs = op_registry.call_op(opdef, ctx, ins, op.attrs,
+                                           op_id=op_id)
         for slot, names in op.outputs.items():
             vals = outs.get(slot)
             if vals is None:
@@ -1064,6 +1097,23 @@ def _run_ops_into_env(block, env, ctx, ops=None):
                         v = fault_hook(n, v)
                     env[n] = v
     return env
+
+
+def _lower_kernel_site_grad(opdef, ctx, ins, attrs, fwd_id, kept):
+    """The generic grad of an op that can hold a Mosaic kernel: over the
+    forward twin's kept residuals where there are any and this op is fed
+    the very values the forward saw, else re-deriving the forward.  Notes
+    in ``ctx.residual_sites`` which of the two a kernel site took."""
+    if kept is not None and not kept.saw(ins):
+        kept = None
+    sites = getattr(ctx, "residual_sites", None)
+    if sites is not None and (
+            kept is not None
+            or op_registry.routes_to_kernel(opdef, ctx, ins, attrs)):
+        sites[fwd_id] = (opdef.fwd_def.type,
+                         "reused" if kept is not None else "recomputed")
+    return op_registry.call_op(opdef, ctx, ins, attrs, op_id=fwd_id,
+                               kept=kept)
 
 
 def _check_feed_shapes(program, feed_vals):
@@ -1139,6 +1189,7 @@ def _compile_step(runner, build, program, feed_vals, fetch_names):
     :class:`_CompiledBlock` (trace, lower, jit)."""
     with _tr.phase(runner + ".compile") as ph:
         compiled = build()
+    compiled.compile_phase = ph
     ph.set_attr("compile_ms", round(ph.dur_ms, 2))
     _obs.record_compile(ph.dur_ms, runner=runner)
     _register_compile_telemetry(compiled, program, feed_vals, fetch_names)
@@ -1173,6 +1224,11 @@ def _dispatch_step(runner, step_phase, compiled, program, scope, feed_vals,
     with _tr.phase(runner + ".dispatch",
                    **_obs.collective_step_shape()) as dispatch:
         fetches, new_rw, fresh = compiled.jitted(feed_vals, rw, ro, base_key)
+    if compiled.compile_phase is not None:
+        # this block's first dispatch: jax.jit traced the step just now
+        _obs.record_grad_residual_sites(
+            compiled.residual_sites.values(), compiled.compile_phase)
+        compiled.compile_phase = None
     with _tr.phase(runner + ".apply_results"):
         fetches = _apply_step_results(
             compiled, scope, fetches, new_rw, fresh, fetch_names,

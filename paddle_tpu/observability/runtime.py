@@ -7,6 +7,7 @@ uniform: every hook starts with the cached kill-switch check and
 returns immediately when telemetry is off.
 """
 
+import collections
 import os
 import threading
 import time
@@ -18,7 +19,8 @@ from .metrics import telemetry_enabled
 
 __all__ = [
     "record_step", "record_step_done", "record_jit_cache",
-    "record_compile", "record_fusion_resolve", "record_feed_cache",
+    "record_compile", "record_grad_residual_sites",
+    "record_fusion_resolve", "record_feed_cache",
     "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
     "record_prefetch", "record_guard_step", "record_guard_skip",
     "record_serving_request", "record_serving_reject",
@@ -239,6 +241,26 @@ def record_compile(ms, runner="executor"):
         return
     _m.histogram("compile_ms", runner=runner).observe(ms)
     _journal.emit("compile", runner=runner, compile_ms=round(ms, 2))
+
+
+def record_grad_residual_sites(sites, compile_phase):
+    """What the grad op of each Mosaic kernel site of a newly traced
+    block took, ``(op_type, "reused" | "recomputed")`` each: the forward
+    op's saved residuals, or a second run of the forward kernel
+    (``executor._run_ops_into_env``).  The two counts are attributes of
+    the block's ``compile`` phase."""
+    counts = collections.Counter(sites)
+    for path in ("reused", "recomputed"):
+        compile_phase.set_attr(
+            "grad_residual_sites_" + path,
+            sum(n for (_, p), n in counts.items() if p == path))
+    if not telemetry_enabled():
+        return
+    for (op_type, path), n in counts.items():
+        _m.counter("grad_residual_sites_total",
+                   "kernel sites whose grad op reused the forward op's "
+                   "residuals, or ran the forward kernel again",
+                   op_type=op_type, path=path).inc(n)
 
 
 def record_fusion_resolve(hit):

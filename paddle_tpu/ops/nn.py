@@ -16,6 +16,8 @@ Reference kernels: ``paddle/fluid/operators/softmax_op.cc`` (+cuDNN variant),
   reference's hand-written fused grad kernel.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -520,8 +522,14 @@ def prelu(ctx, attrs, X, Alpha):
     return jnp.where(X >= 0, X, a * X)
 
 
+def _flash_site(ctx, attrs, Q, K, V, BiasQK=None):
+    from .pallas.flash_attention import routes_to_kernel
+
+    return routes_to_kernel(Q, K, BiasQK)
+
+
 @register_op("fused_multihead_attention", inputs=["Q", "K", "V", "BiasQK"],
-             outputs=["Out"])
+             outputs=["Out"], kernel_residuals=_flash_site)
 def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
     """Fused scaled-dot-product attention (reference analogue: the
     fusion_* attention kernels under ``paddle/fluid/operators/fused/``).
@@ -529,7 +537,10 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
     [B,1,1,Tk].  Lowered to the Pallas FlashAttention-2 TPU kernel when
     profitable, XLA attention otherwise (ops/pallas/flash_attention.py);
     its backward is the custom-vjp flash backward, reached through the
-    registry's generic jax.vjp grad derivation."""
+    registry's generic grad derivation: over the residuals (``m``, ``l``)
+    of the forward op's own kernel call where the Executor lowers both
+    ops in one call and the site routes to the kernel (``_flash_site``),
+    else over a ``jax.vjp`` that re-derives the forward."""
     from .pallas.flash_attention import flash_attention
 
     causal = bool(attrs.get("causal", False))
@@ -552,9 +563,25 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
                            dropout_seed=seed)
 
 
+def _fused_ln_rate(ctx, attrs):
+    rate = float(attrs.get("dropout_prob", 0.0) or 0.0)
+    if attrs.get("is_test") or ctx.mode == "infer":
+        rate = 0.0
+    return rate
+
+
+def _fused_ln_site(ctx, attrs, X, Residual, Scale, Bias):
+    from .pallas.fused_ln import routes_to_kernel
+
+    shape = jnp.shape(X)
+    return routes_to_kernel(
+        jax.ShapeDtypeStruct((math.prod(shape[:-1]), shape[-1]), X.dtype),
+        _fused_ln_rate(ctx, attrs))
+
+
 @register_op("fused_dropout_add_ln", inputs=["X", "Residual", "Scale",
                                              "Bias"],
-             outputs=["Out"])
+             outputs=["Out"], kernel_residuals=_fused_ln_site)
 def fused_dropout_add_ln(ctx, attrs, X, Residual, Scale, Bias):
     """``layer_norm(residual + dropout(x))`` in one Pallas pass
     (ops/pallas/fused_ln.py; reference analogue: the fused_elemwise /
@@ -562,9 +589,7 @@ def fused_dropout_add_ln(ctx, attrs, X, Residual, Scale, Bias):
     last axis; Scale/Bias: [D]."""
     from .pallas.fused_ln import fused_dropout_add_ln as _fused
 
-    rate = float(attrs.get("dropout_prob", 0.0) or 0.0)
-    if attrs.get("is_test") or ctx.mode == "infer":
-        rate = 0.0
+    rate = _fused_ln_rate(ctx, attrs)
     eps = float(attrs.get("epsilon", 1e-5))
     seed = None
     if rate > 0.0:

@@ -80,7 +80,15 @@ _KERNEL_CALL = re.compile(
 def pallas_kernels_in(hlo_text):
     """``Counter`` of the Mosaic kernels in a compiled module's text, by
     HLO instruction name — the ``name=`` each ``pallas_call`` here is
-    given (``jvp_..._`` when traced through a ``custom_vjp`` rule)."""
+    given, or ``jvp_<name>_`` when traced through a ``custom_vjp`` rule.
+    In a training step every kernel of a fused-LN or flash site carries
+    the second form, the forward once a site (``jvp_fused_ln_fwd_``,
+    ``jvp_flash_attention_fwd_``: the forward op keeps its ``jax.vjp``
+    for the grad op, ``executor._run_ops_into_env``) beside the backward
+    kernels; a program with no backward holds the plain names.  A plain
+    AND a ``jvp_`` forward of one kernel in one step means a site's grad
+    op ran the forward again (``grad_residual_sites_total``,
+    ``path="recomputed"``)."""
     return collections.Counter(_KERNEL_CALL.findall(hlo_text))
 
 

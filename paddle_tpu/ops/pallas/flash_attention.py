@@ -636,6 +636,21 @@ def _kernel_applicable(q, k, bias):
     return True
 
 
+def routes_to_kernel(q, k, bias=None):
+    """Does :func:`flash_attention` run the Pallas kernels for these
+    shapes (q, k: [B, H, T, D]; bias as the entry point takes it;
+    anything with ``.shape``), or XLA attention?  The entry point's one
+    routing decision."""
+    b, h, tq, d = q.shape
+    tk = k.shape[2]
+    if bias is not None:
+        bias = jax.ShapeDtypeStruct((bias.shape[0], bias.shape[-1]),
+                                    jnp.float32)
+    return use_pallas()[0] and _kernel_applicable(
+        jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32),
+        jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32), bias)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
            interpret, dropout_rate, dropout_debug):
@@ -694,7 +709,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
             "upscale by 1/0)" % dropout_rate)
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
-    use, interpret = use_pallas()
+    interpret = use_pallas()[1]
     debug = _dropout_debug()
     b, h, tq, _ = q.shape
     tk = k.shape[2]
@@ -704,7 +719,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     seed = jnp.reshape(
         jnp.asarray(0 if dropout_seed is None else dropout_seed,
                     jnp.int32), (1,))
-    if not (use and _kernel_applicable(qf, kf, bias)):
+    if not routes_to_kernel(q, k, bias):
         return mha_reference(q, k, v, bias=bias, causal=causal,
                              sm_scale=sm_scale,
                              dropout_rate=dropout_rate, seed=seed,

@@ -284,7 +284,17 @@ def fused_dropout_add_ln(x, residual, gamma, beta, dropout_rate=0.0,
     rate = float(dropout_rate or 0.0)
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
-    if use_pallas()[1] and rate > 0.0 and not _debug_mask():
+    if not routes_to_kernel(x, rate):
+        return _xla_reference(x, residual, gamma, beta, rate, eps, seed,
+                              _debug_mask())
+    return _fused_core(x, residual, gamma, beta, rate, eps, seed)
+
+
+def routes_to_kernel(x, dropout_rate=0.0):
+    """Does :func:`fused_dropout_add_ln` run the Pallas kernels for an
+    ``x`` of this shape ([N, D]; anything with ``.shape``) at this rate,
+    or the XLA composite?  The entry point's one routing decision."""
+    if use_pallas()[1] and dropout_rate > 0.0 and not _debug_mask():
         # the pltpu hardware PRNG has no CPU/interpret lowering — the
         # kernel would die deep in Pallas with an opaque 'prng_seed not
         # found for platform cpu'.  Unlike the flash entry (whose caller
@@ -293,9 +303,5 @@ def fused_dropout_add_ln(x, residual, gamma, beta, dropout_rate=0.0,
         # composite instead of raising; set
         # PADDLE_TPU_FLASH_DROPOUT_DEBUG=iota to run the kernel with the
         # deterministic debug hash instead.
-        return _xla_reference(x, residual, gamma, beta, rate, eps, seed,
-                              False)
-    if not _eligible(x):
-        return _xla_reference(x, residual, gamma, beta, rate, eps, seed,
-                              _debug_mask())
-    return _fused_core(x, residual, gamma, beta, rate, eps, seed)
+        return False
+    return _eligible(x)

@@ -11,10 +11,17 @@ function per op subsumes all three:
 * **InferShape** — derived with ``jax.eval_shape`` over the lowering
   (see :func:`infer_shapes`);
 * **grad ops** — a generic ``<type>_grad`` lowering is derived with
-  ``jax.vjp`` over the forward lowering (:func:`generic_grad_fn`).  Because
-  the Executor lowers the whole block into one jaxpr, XLA CSEs the forward
-  recomputation inside the vjp against the original forward ops, so the
-  default grad costs no extra FLOPs; ops can still register a hand-written
+  ``jax.vjp`` over the forward lowering (:func:`_make_generic_grad_def`).
+  Because the Executor lowers the whole block into one jaxpr, XLA CSEs the
+  forward recomputation inside the vjp against the original forward ops
+  **where the forward is XLA ops**, so there the default grad costs no
+  extra FLOPs.  A Mosaic kernel is a custom call XLA merges with nothing:
+  re-derived, its forward runs twice a step.  An op whose site can route
+  to such a kernel says so (``register_op(kernel_residuals=...)``), and
+  where the same lowering call also holds the grad twin the Executor
+  takes that forward once, through ``jax.vjp`` (:func:`call_op_keeping_vjp`),
+  and hands the grad op the kept residuals (:class:`KeptForward`) in
+  place of a second forward.  Ops can still register a hand-written
   ``<type>_grad`` where a different formula is preferable.
 
 This mirrors the precedent the reference itself set for graph-compiler
@@ -34,6 +41,9 @@ __all__ = [
     "OpNotRegistered",
     "LoweringContext",
     "call_op",
+    "call_op_keeping_vjp",
+    "routes_to_kernel",
+    "KeptForward",
     "infer_shapes",
     "infer_output_structs",
     "EMPTY_VAR_NAME",
@@ -67,7 +77,8 @@ def _kwarg_name(slot):
 
 class OpDef:
     def __init__(self, type, fn, inputs, outputs, no_grad=False,
-                 infer_shape=None, grad_maker=None, stateful_outputs=()):
+                 infer_shape=None, grad_maker=None, stateful_outputs=(),
+                 kernel_residuals=None):
         self.type = type
         self.fn = fn
         self.inputs = _parse_slots(inputs)  # [(slot, duplicable)]
@@ -80,6 +91,12 @@ class OpDef:
         # output slots that are state (e.g. batch_norm running stats) —
         # excluded from differentiation paths
         self.stateful_outputs = set(stateful_outputs)
+        # ``fn(ctx, attrs, **slots) -> bool``: does this site route to a
+        # Mosaic kernel whose custom_vjp forward rule saves residuals for
+        # its backward kernels?  (None: the op holds no such kernel.)
+        self.kernel_residuals = kernel_residuals
+        # on a generic ``<type>_grad`` def: the forward def it derives from
+        self.fwd_def = None
 
     @property
     def input_slot_names(self):
@@ -91,12 +108,15 @@ class OpDef:
 
 
 def register_op(type, inputs, outputs, no_grad=False, infer_shape=None,
-                grad_maker=None, stateful_outputs=()):
+                grad_maker=None, stateful_outputs=(), kernel_residuals=None):
     """Decorator: register `fn(ctx, attrs, **slots)` as the lowering of `type`.
 
     Slot kwargs are arrays (or lists of arrays for duplicable slots, or None
     for absent optional slots).  Return value: a single array (one output
     slot), a tuple in declared output order, or a dict slot→array/list.
+    ``kernel_residuals``: a predicate of the lowering's own signature,
+    true where the site routes to a Mosaic kernel with saved residuals
+    (module docstring: the forward is then taken once for both ops).
     """
 
     def deco(fn):
@@ -104,6 +124,7 @@ def register_op(type, inputs, outputs, no_grad=False, infer_shape=None,
             type, fn, inputs, outputs, no_grad=no_grad,
             infer_shape=infer_shape, grad_maker=grad_maker,
             stateful_outputs=stateful_outputs,
+            kernel_residuals=kernel_residuals,
         )
         return fn
 
@@ -137,7 +158,8 @@ class LoweringContext:
     RNG: keys are derived deterministically from (step key, op id, draw index)
     so that a grad op recomputing its forward (vjp) draws identical randomness
     — which both makes dropout-style grads correct and lets XLA CSE the
-    recompute against the original forward.
+    recompute against the original forward (XLA ops only; a Mosaic kernel's
+    forward is kept instead, see the module docstring).
     """
 
     def __init__(self, base_key=None, mode="train"):
@@ -159,6 +181,10 @@ class LoweringContext:
         # applied to every op output at trace time (executor sets it when
         # a PADDLE_TPU_FAULT_SPEC names value faults; None = zero cost)
         self.fault_value_hook = None
+        # {forward op id: (op type, "reused" | "recomputed")}, filled at
+        # trace time for the grad ops of Mosaic kernel sites (executor
+        # ``_run_ops_into_env``) when the caller sets a dict; None: no note
+        self.residual_sites = None
 
     def set_op(self, op_id):
         self._op_id = op_id
@@ -193,9 +219,7 @@ def _normalize_result(opdef, res):
     return out
 
 
-def call_op(opdef, ctx, ins, attrs, op_id=0):
-    """Invoke an op lowering. `ins`: {slot: [value-or-None]}."""
-    ctx.set_op(op_id)
+def _slot_kwargs(opdef, ins):
     kwargs = {}
     for slot, dup in opdef.inputs:
         vals = ins.get(slot) or []
@@ -203,8 +227,83 @@ def call_op(opdef, ctx, ins, attrs, op_id=0):
             kwargs[_kwarg_name(slot)] = [v for v in vals]
         else:
             kwargs[_kwarg_name(slot)] = vals[0] if vals else None
+    return kwargs
+
+
+def call_op(opdef, ctx, ins, attrs, op_id=0, kept=None):
+    """Invoke an op lowering. `ins`: {slot: [value-or-None]}.  ``kept``
+    (generic grad defs only): the forward twin's :class:`KeptForward`."""
+    ctx.set_op(op_id)
+    kwargs = _slot_kwargs(opdef, ins)
+    if kept is not None:
+        kwargs["_kept"] = kept
     res = opdef.fn(ctx, dict(attrs), **kwargs)
     return _normalize_result(opdef, res)
+
+
+def routes_to_kernel(opdef, ctx, ins, attrs):
+    """Would lowering this site of ``opdef`` (or of the forward def a
+    generic grad def derives from) run a Mosaic kernel with saved
+    residuals?  The op's own ``kernel_residuals`` predicate decides."""
+    fwd_def = opdef.fwd_def or opdef
+    if fwd_def.kernel_residuals is None:
+        return False
+    return bool(fwd_def.kernel_residuals(ctx, dict(attrs),
+                                         **_slot_kwargs(fwd_def, ins)))
+
+
+class KeptForward:
+    """One forward op's ``jax.vjp``, kept for its grad twin in the same
+    lowering call: the values the forward saw (``ins``), its outputs
+    (``primal``) and the pullback over the residuals its kernel saved."""
+
+    __slots__ = ("ins", "primal", "vjp_fn")
+
+    def __init__(self, ins, primal, vjp_fn):
+        self.ins, self.primal, self.vjp_fn = ins, primal, vjp_fn
+
+    def saw(self, ins):
+        """Are the grad op's forward-slot values the very objects the
+        forward op saw?  (A name overwritten in between: not.)"""
+        for slot, vals in self.ins.items():
+            theirs = ins.get(slot) or []
+            if len(theirs) != len(vals) or any(
+                    a is not b for a, b in zip(vals, theirs)):
+                return False
+        return True
+
+
+def _fwd_slot_values(fwd_def, kwargs):
+    """The forward's ``{slot: [values]}`` as its vjp differentiates it
+    (absent optional slots left out), from slot kwargs."""
+    fwd_in = {}
+    for slot, dup in fwd_def.inputs:
+        v = kwargs.get(_kwarg_name(slot))
+        if v is None:
+            continue
+        fwd_in[slot] = list(v) if dup else [v]
+    return fwd_in
+
+
+def _forward_vjp(fwd_def, ctx, fwd_in, attrs, op_id):
+    """``jax.vjp`` of a forward lowering over its ``{slot: [values]}``:
+    ``(outs, pullback)``."""
+    import jax
+
+    def f(fin):
+        return call_op(fwd_def, ctx, fin, attrs, op_id=op_id)
+
+    return jax.vjp(f, fwd_in)
+
+
+def call_op_keeping_vjp(opdef, ctx, ins, attrs, op_id=0):
+    """:func:`call_op` of a forward op through ``jax.vjp``, exactly as its
+    generic grad twin would re-derive it: ``(outs, KeptForward)``."""
+    primal, vjp_fn = _forward_vjp(
+        opdef, ctx, _fwd_slot_values(opdef, _slot_kwargs(opdef, ins)),
+        attrs, op_id)
+    return primal, KeptForward({s: list(ins.get(s) or [])
+                                for s, _ in opdef.inputs}, primal, vjp_fn)
 
 
 # ---------------------------------------------------------------------------
@@ -225,14 +324,9 @@ def _make_generic_grad_def(fwd_def):
         slot + "@GRAD" + ("*" if dup else "") for slot, dup in fwd_def.inputs
     ]
 
-    def grad_fn(ctx, attrs, **kwargs):
+    def grad_fn(ctx, attrs, _kept=None, **kwargs):
         # reconstruct raw slot dicts from kwargs
-        fwd_in = {}
-        for slot, dup in fwd_def.inputs:
-            v = kwargs.get(_kwarg_name(slot))
-            if v is None:
-                continue
-            fwd_in[slot] = list(v) if dup else [v]
+        fwd_in = _fwd_slot_values(fwd_def, kwargs)
         out_grads = {}
         for slot, dup in fwd_def.outputs:
             g = kwargs.get(_kwarg_name(slot + "@GRAD"))
@@ -240,12 +334,14 @@ def _make_generic_grad_def(fwd_def):
                 continue
             out_grads[slot] = list(g) if dup else [g]
 
-        fwd_op_id = attrs.get("__fwd_op_id__", attrs.get("__op_id__", 0))
-
-        def f(fin):
-            return call_op(fwd_def, ctx, fin, attrs, op_id=fwd_op_id)
-
-        primal, vjp_fn = jax.vjp(f, fwd_in)
+        if _kept is not None:
+            # the forward op already ran under jax.vjp in this lowering
+            # call, on these very values: its residuals, no second forward
+            primal, vjp_fn = _kept.primal, _kept.vjp_fn
+        else:
+            primal, vjp_fn = _forward_vjp(
+                fwd_def, ctx, fwd_in, attrs,
+                attrs.get("__fwd_op_id__", attrs.get("__op_id__", 0)))
         # build cotangents matching the primal pytree exactly
         cot = {}
         for slot, vals in primal.items():
@@ -281,9 +377,11 @@ def _make_generic_grad_def(fwd_def):
             result[slot + "@GRAD"] = vals
         return result
 
-    return OpDef(
+    d = OpDef(
         fwd_def.type + "_grad", grad_fn, grad_inputs, grad_outputs, no_grad=True
     )
+    d.fwd_def = fwd_def
+    return d
 
 
 def _cotangent_dtype(p):
