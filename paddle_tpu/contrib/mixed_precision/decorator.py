@@ -18,14 +18,26 @@ __all__ = ["decorate", "OptimizerWithMixedPrecision", "rewrite_program_bf16"]
 
 def rewrite_program_bf16(program, amp_lists=None):
     """Insert bf16 casts before white-listed ops and fp32 casts before
-    black-listed ops (reference fp16_utils.py rewrite_program)."""
+    black-listed ops (reference fp16_utils.py rewrite_program), in the
+    global block and in the regions of its ``recompute_block`` ops
+    (``fluid.layers.recompute()``: a region's casts are part of what its
+    grad op re-runs, so a weight's bf16 copy lives for one region)."""
     amp_lists = amp_lists or AutoMixedPrecisionLists()
-    block = program.global_block()
+    _rewrite_block_bf16(program, program.global_block(), amp_lists)
+    program._bump_version()
+    return program
+
+
+def _rewrite_block_bf16(program, block, amp_lists):
+    from ...framework import Operator
+
     cast_cache = {}  # (var, dtype) -> cast var name
     new_ops = []
 
-    def cast_input(op, target_dtype, from_dtypes):
+    def cast_input(op, target_dtype, from_dtypes, keep=()):
         for slot, names in op.inputs.items():
+            if slot in keep:
+                continue
             new_names = []
             for n in names:
                 var = block._find_var_recursive(n)
@@ -35,12 +47,10 @@ def rewrite_program_bf16(program, amp_lists=None):
                 key = (n, target_dtype)
                 if key not in cast_cache:
                     cast_name = unique_name.generate(n + ".cast_" + target_dtype)
-                    cv = block.create_var(
+                    block.create_var(
                         name=cast_name, shape=var.shape, dtype=target_dtype,
                         persistable=False, stop_gradient=var.stop_gradient,
                     )
-                    from ...framework import Operator
-
                     cast_op = Operator(
                         block, "cast",
                         {"X": [n]}, {"Out": [cast_name]},
@@ -52,8 +62,13 @@ def rewrite_program_bf16(program, amp_lists=None):
             op.inputs[slot] = new_names
 
     for op in block.ops:
-        if op.type in amp_lists.white_list:
-            cast_input(op, "bfloat16", ("float32",))
+        if op.type == "recompute_block":
+            _rewrite_block_bf16(
+                program, program.block(int(op.attrs["sub_block"])),
+                amp_lists)
+        elif op.type in amp_lists.white_list:
+            cast_input(op, "bfloat16", ("float32",),
+                       keep=amp_lists.fp32_slots.get(op.type, ()))
             # downstream vars produced by this op are bf16 at runtime
             for name in op.output_arg_names:
                 v = block._find_var_recursive(name)
@@ -63,8 +78,6 @@ def rewrite_program_bf16(program, amp_lists=None):
             cast_input(op, "float32", ("bfloat16",))
         new_ops.append(op)
     block.ops = new_ops
-    program._bump_version()
-    return program
 
 
 class OptimizerWithMixedPrecision:

@@ -22,6 +22,7 @@ white_list = {
     "conv2d_transpose",
     "fused_dropout_add_ln",
     "fused_multihead_attention",
+    "moe_experts",
     # elementwise / activation glue
     "elementwise_add",
     "elementwise_sub",
@@ -38,6 +39,8 @@ white_list = {
     "swish",
     "leaky_relu",
     "dropout",
+    "swiglu",
+    "rotary_embedding",
     # shape glue (cast-free but keeps dtype propagation consistent)
     "reshape",
     "reshape2",
@@ -56,6 +59,7 @@ white_list = {
     # normalization / attention softmax / fused loss (f32 internals in
     # the lowerings)
     "layer_norm",
+    "rms_norm",
     "softmax",
     "softmax_with_cross_entropy",
 }
@@ -72,6 +76,15 @@ black_list = {
     "exp",
     "log",
     "squared_l2_norm",
+    # a near-tie between two experts' scores decides which one runs
+    "moe_route",
+}
+
+# inputs of white-listed ops that stay float32: the RMSNorm scale beside
+# its float32 statistics, and the gates the router computed in float32
+fp32_slots = {
+    "rms_norm": ("Scale",),
+    "moe_experts": ("Gate",),
 }
 
 # everything else follows its inputs
@@ -86,3 +99,4 @@ class AutoMixedPrecisionLists:
             self.white_list |= set(custom_white_list)
         if custom_black_list:
             self.black_list |= set(custom_black_list)
+        self.fp32_slots = dict(fp32_slots)

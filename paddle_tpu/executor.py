@@ -1033,6 +1033,8 @@ def _run_ops_into_env(block, env, ctx, ops=None):
     host-side per-op timing impossible, and this is the device-side
     equivalent of the reference's per-op profiler tables
     (platform/profiler.h:166).  Trace-time only: zero runtime cost.
+    An op built under ``framework.device_tag`` is named after its tag;
+    a lowering names its own parts through ``ctx.part_scope``.
 
     **One forward per Mosaic kernel site.**  A generic grad op re-derives
     its forward under ``jax.vjp``; XLA merges that with the forward op
@@ -1074,7 +1076,8 @@ def _run_ops_into_env(block, env, ctx, ops=None):
                     vals.append(env.get(n))
             ins[slot] = vals
         op_id = op.attrs.get("__fwd_op_id__", op.attrs.get("__op_id__", 0))
-        with jax.named_scope("pd%d_%s" % (i, op.type)):
+        ctx.op_scope = "pd%d_%s" % (i, op.attrs.get("device_tag", op.type))
+        with jax.named_scope(ctx.op_scope):
             if (op.attrs.get("__op_id__") in twin_ids
                     and op_registry.routes_to_kernel(opdef, ctx, ins,
                                                      op.attrs)):
@@ -1192,6 +1195,7 @@ def _compile_step(runner, build, program, feed_vals, fetch_names):
     compiled.compile_phase = ph
     ph.set_attr("compile_ms", round(ph.dur_ms, 2))
     _obs.record_compile(ph.dur_ms, runner=runner)
+    _obs.record_moe_layers(program, ph)
     _register_compile_telemetry(compiled, program, feed_vals, fetch_names)
     return compiled
 

@@ -38,6 +38,7 @@ __all__ = [
     "switch_startup_program",
     "program_guard",
     "name_scope",
+    "device_tag",
     "cpu_places",
     "cuda_places",
     "tpu_places",
@@ -83,6 +84,22 @@ def name_scope(prefix=None):
         yield
     finally:
         _name_scope_stack.pop()
+
+
+_device_tag_stack = []
+
+
+@contextlib.contextmanager
+def device_tag(tag):
+    """Ops built inside carry ``tag`` into the device trace in place of
+    their own type (attr ``device_tag``; the Executor names each op's
+    scope after it), so that a part of a model made of many small ops,
+    a latent-attention block say, reads as one line of a profile."""
+    _device_tag_stack.append(tag)
+    try:
+        yield
+    finally:
+        _device_tag_stack.pop()
 
 
 class Variable:
@@ -256,6 +273,8 @@ class Operator:
             self.attrs["__op_id__"] = program._next_op_id()
         if _name_scope_stack:
             self.attrs.setdefault("op_namescope", "/".join(_name_scope_stack))
+        if _device_tag_stack:
+            self.attrs.setdefault("device_tag", _device_tag_stack[-1])
 
     def input(self, slot):
         return self.inputs.get(slot, [])

@@ -6,6 +6,7 @@ import numpy as np
 from ..framework import Variable
 from ..layer_helper import LayerHelper
 from ..initializer import ConstantInitializer, NormalInitializer
+from ..param_attr import ParamAttr
 from .. import core
 
 __all__ = [
@@ -37,6 +38,12 @@ __all__ = [
     "mul",
     "fused_dropout_add_ln",
     "fused_multihead_attention",
+    "rms_norm",
+    "rotary_embedding",
+    "swiglu",
+    "moe_route",
+    "moe_experts",
+    "moe_count_rows",
     "elementwise_add",
     "elementwise_sub",
     "elementwise_mul",
@@ -1112,10 +1119,11 @@ def fused_dropout_add_ln(x, residual, dropout_prob=0.0, epsilon=1e-5,
 
 def fused_multihead_attention(q, k, v, bias=None, causal=False, scale=None,
                               dropout_rate=0.0, name=None):
-    """Fused multi-head attention over [B, H, T, Dh] tensors; on TPU this
+    """Fused multi-head attention over q, k: [B, H, T, Dh] and v: [B, H,
+    T, Dv] (Dv may differ from Dh and is the output's width); on TPU this
     is a single Pallas flash-attention kernel (O(T) memory), elsewhere XLA
-    attention.  `bias` is an additive key bias ([B, Tk] or [B,1,1,Tk],
-    e.g. a padding mask); no gradient flows to it.  dropout_rate applies
+    attention.  `scale` defaults to 1/sqrt(Dh).  `bias` is an additive
+    key bias ([B, Tk] or [B,1,1,Tk], e.g. a padding mask); no gradient flows to it.  dropout_rate applies
     attention-probability dropout INSIDE the kernel (train mode only) —
     the [B,H,T,T] mask never materializes in HBM."""
     helper = LayerHelper("fused_multihead_attention", **locals())
@@ -1135,6 +1143,158 @@ def fused_multihead_attention(q, k, v, bias=None, causal=False, scale=None,
         attrs=attrs,
     )
     return out
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * scale`` over the LAST axis, the
+    statistics in float32; creates the float32 scale [D], ones."""
+    helper = LayerHelper("rms_norm", **locals())
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[input.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+        outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, rotary_dim=None, offset=0, theta=10000.0,
+                     interleaved=True, name=None):
+    """Rotary position embedding over x: [..., T, Dh], positions 0..T-1 on
+    the last axis but one.  The ``rotary_dim`` features of a head from
+    ``offset`` on are rotated (default: from ``offset`` to the end), the
+    others pass through: a head that is part position-free, part rotary.
+    ``interleaved`` pairs features (2i, 2i+1); else (i, i + half)."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rotary_embedding", inputs={"X": [x]}, outputs={"Out": [out]},
+        attrs={"rotary_dim": int(rotary_dim or x.shape[-1] - offset),
+               "offset": int(offset), "theta": float(theta),
+               "interleaved": bool(interleaved)})
+    return out
+
+
+def swiglu(x, y, name=None):
+    """``silu(x) * y``: the gate of a SwiGLU feed-forward."""
+    helper = LayerHelper("swiglu", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="swiglu", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def moe_route(input, num_experts, top_k, scale=1.0, norm_topk_prob=True,
+              center_bias=False, keep_input=None, param_attr=None,
+              bias_attr=None, name=None):
+    """The router of a top-k expert layer, over all ``num_experts``:
+    ``s = sigmoid(x W)`` in float32, the ``top_k`` largest of ``s + b``
+    chosen, gates ``scale * s_i / sum_chosen s_j`` (the sum left out
+    unless ``norm_topk_prob``).  Creates W [D, num_experts] and the
+    correction bias b [num_experts], which takes no gradient.  Returns
+    ``(index [N, top_k] int32, gate [N, top_k] float32)``, N the rows of
+    ``input`` flattened to [N, D].
+
+    ``center_bias``: in training the choice is made not with b but with
+    minus each expert's mean score over the step's rows (what balances
+    the load of a model that is not trained yet; ``parallel/moe.py``
+    ``sigmoid_topk_route``), and a third value is returned, the bias
+    used [num_experts]: assign it to b after ``optimizer.minimize`` (b's
+    name is ``bias_attr``'s) and a test-mode program routes by the last
+    step's.
+
+    ``keep_input``: a name; the rows the router read are left in a
+    persistable variable of that name, the very numbers (behind an
+    optimization barrier), for a check that routes them again."""
+    helper = LayerHelper("moe_route", **locals())
+    w = helper.create_parameter(
+        attr=helper.param_attr, shape=[input.shape[-1], num_experts],
+        dtype="float32")
+    b = helper.create_parameter(
+        attr=helper.bias_attr, shape=[num_experts], dtype="float32",
+        is_bias=True)
+    b.stop_gradient = True
+    index = helper.create_variable_for_type_inference("int32", True)
+    gate = helper.create_variable_for_type_inference("float32")
+    used = helper.create_variable_for_type_inference("float32", True)
+    outputs = {"Index": [index], "Gate": [gate], "BiasOut": [used]}
+    if keep_input:
+        kept = helper.create_or_get_global_variable(
+            keep_input, shape=[-1, input.shape[-1]], dtype=input.dtype)
+        kept.stop_gradient = True
+        outputs["XOut"] = [kept]
+    helper.append_op(
+        type="moe_route",
+        inputs={"X": [input], "Weight": [w], "Bias": [b]},
+        outputs=outputs,
+        attrs={"top_k": int(top_k), "scale": float(scale),
+               "norm_topk_prob": bool(norm_topk_prob),
+               "center_bias": bool(center_bias),
+               "keep_input": bool(keep_input)})
+    return (index, gate, used) if center_bias else (index, gate)
+
+
+def moe_experts(input, index, gate, expert_width, experts_held,
+                first_expert=0, param_attr=None, name=None):
+    """The part of a top-k expert layer that the ``experts_held`` experts
+    from ``first_expert`` on give: ``sum_k gate[t,k] * E_index[t,k](x_t)``
+    over the choices that name a held expert, ``E(x) = W_down (silu(W_gate
+    x) * W_up x)`` of width ``expert_width``.  Dropless: every row routed
+    here is computed, whatever the imbalance, by grouped products whose
+    work follows those rows.  ``index`` and ``gate`` come from
+    :func:`moe_route` and count over all the layer's experts, held or
+    not.  Creates each held expert's own ``<name>.<e>.gate``, ``.up``
+    [D, F] and ``.down`` [F, D], e counted from 0 over the experts held,
+    ``<name>`` and the initializer from ``param_attr`` (as a checkpoint of
+    such a model stores them, an expert a tensor; the lowering stacks
+    them).  Returns ``(out, rows)``; ``rows`` [held] int32 are the rows
+    each held expert was given (see :func:`moe_count_rows`)."""
+    helper = LayerHelper("moe_experts", **locals())
+    d = input.shape[-1]
+    attr = ParamAttr._to_attr(param_attr)
+    prefix = attr.name or helper.name
+
+    def weights(part, shape):
+        return [helper.create_parameter(
+            attr=ParamAttr(name="%s.%d.%s" % (prefix, e, part),
+                           initializer=attr.initializer,
+                           learning_rate=attr.learning_rate,
+                           regularizer=attr.regularizer,
+                           trainable=attr.trainable),
+            shape=shape, dtype="float32") for e in range(experts_held)]
+
+    out = helper.create_variable_for_type_inference(input.dtype)
+    rows = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [input], "Index": [index], "Gate": [gate],
+                "WGate": weights("gate", [d, expert_width]),
+                "WUp": weights("up", [d, expert_width]),
+                "WDown": weights("down", [expert_width, d])},
+        outputs={"Out": [out], "Rows": [rows]},
+        attrs={"first_expert": int(first_expert)})
+    return out, rows
+
+
+def moe_count_rows(rows, index, layer, name=None):
+    """Keeps an expert layer's counters on the device: a persistable
+    int32 vector ``<name>`` of the rows given to each held expert so far,
+    then the rows possible (tokens * top_k) and the steps, updated inside
+    the step with no host sync.  ``observability.runtime.
+    publish_moe_counters`` reads them into the metrics registry under the
+    label ``layer``.  Call it outside any recompute region."""
+    helper = LayerHelper("moe_count_rows", **locals())
+    stats = helper.create_or_get_global_variable(
+        name or "moe_rows.layer%s" % layer, shape=[rows.shape[0] + 2],
+        dtype="int32")
+    stats.stop_gradient = True
+    helper.set_variable_initializer(stats, ConstantInitializer(0))
+    helper.append_op(
+        type="moe_count_rows",
+        inputs={"Rows": [rows], "Index": [index], "Stats": [stats]},
+        outputs={"StatsOut": [stats]}, attrs={"layer": str(layer)})
+    return stats
 
 
 # Reference parity: the reference keeps all of these names in ONE

@@ -20,6 +20,7 @@ from .metrics import telemetry_enabled
 __all__ = [
     "record_step", "record_step_done", "record_jit_cache",
     "record_compile", "record_grad_residual_sites",
+    "record_moe_layers", "publish_moe_counters",
     "record_fusion_resolve", "record_feed_cache",
     "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
     "record_prefetch", "record_guard_step", "record_guard_skip",
@@ -261,6 +262,73 @@ def record_grad_residual_sites(sites, compile_phase):
                    "kernel sites whose grad op reused the forward op's "
                    "residuals, or ran the forward kernel again",
                    op_type=op_type, path=path).inc(n)
+
+
+# stats var of each expert layer a compiled step holds -> its layer
+# label, and what ``publish_moe_counters`` last read and has summed
+_moe_stats = {}
+_moe_read = {}
+
+
+def record_moe_layers(program, compile_phase):
+    """The expert layers of a newly compiled block, as attributes of its
+    ``compile`` phase (``moe_layers``, ``experts_held``,
+    ``experts_total``; a block without any gets none), and the counters
+    their ``moe_count_rows`` ops keep on the device, noted for
+    :func:`publish_moe_counters`."""
+    ops = [op for b in getattr(program, "blocks", ()) for op in b.ops]
+    experts = [op for op in ops if op.type == "moe_experts"]
+    if not experts:
+        return
+    compile_phase.set_attr("moe_layers", len(experts))
+    compile_phase.set_attr("experts_held", len(experts[0].input("WGate")))
+    for op in ops:
+        if op.type == "moe_route":
+            weight = op.block._find_var_recursive(op.input("Weight")[0])
+            compile_phase.set_attr("experts_total", int(weight.shape[1]))
+        elif op.type == "moe_count_rows":
+            _moe_stats[op.input("Stats")[0]] = str(op.attrs.get("layer"))
+
+
+def publish_moe_counters(scope=None):
+    """Reads each noted expert layer's device counters (one host sync a
+    layer; nothing is read until this is called) and publishes what was
+    added since the last read: counters ``moe_rows_routed_here_total``
+    and ``moe_rows_possible_total`` (tokens * top_k), gauges
+    ``moe_expert_rows_max`` and ``moe_expert_rows_mean`` over the held
+    experts' totals, each with ``layer``.  Returns ``{layer: {"rows":
+    [per held expert], "possible": n, "steps": n}}``, the totals since
+    the counters were last zeroed."""
+    import numpy as np
+
+    if scope is None:
+        from ..executor import global_scope
+        scope = global_scope()
+    out = {}
+    for name, layer in sorted(_moe_stats.items()):
+        value = scope.get(name)
+        if value is None:
+            continue
+        raw = np.asarray(value).astype(np.int64) & 0xFFFFFFFF
+        last, total = _moe_read.get(name, (np.zeros_like(raw),) * 2)
+        # the device's int32 wraps; a step count that fell is a restart
+        delta = raw if raw[-1] < last[-1] else (raw - last) & 0xFFFFFFFF
+        total = delta + (0 if raw[-1] < last[-1] else total)
+        _moe_read[name] = (raw, total)
+        rows = [int(x) for x in total[:-2]]
+        out[layer] = {"rows": rows, "possible": int(total[-2]),
+                      "steps": int(total[-1])}
+        if telemetry_enabled():
+            _m.counter("moe_rows_routed_here_total",
+                       "rows the experts held here were given",
+                       layer=layer).inc(int(delta[:-2].sum()))
+            _m.counter("moe_rows_possible_total",
+                       "tokens * top_k: the rows all experts were given",
+                       layer=layer).inc(int(delta[-2]))
+            _m.gauge("moe_expert_rows_max", layer=layer).set(max(rows))
+            _m.gauge("moe_expert_rows_mean",
+                     layer=layer).set(sum(rows) / len(rows))
+    return out
 
 
 def record_fusion_resolve(hit):
@@ -759,3 +827,5 @@ def reset_runtime():
     _jit_handles.clear()
     _named_handles.clear()
     _env_cache.clear()
+    _moe_stats.clear()
+    _moe_read.clear()

@@ -525,7 +525,7 @@ def prelu(ctx, attrs, X, Alpha):
 def _flash_site(ctx, attrs, Q, K, V, BiasQK=None):
     from .pallas.flash_attention import routes_to_kernel
 
-    return routes_to_kernel(Q, K, BiasQK)
+    return routes_to_kernel(Q, K, BiasQK, V)
 
 
 @register_op("fused_multihead_attention", inputs=["Q", "K", "V", "BiasQK"],
@@ -533,8 +533,10 @@ def _flash_site(ctx, attrs, Q, K, V, BiasQK=None):
 def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
     """Fused scaled-dot-product attention (reference analogue: the
     fusion_* attention kernels under ``paddle/fluid/operators/fused/``).
-    Q,K,V: [B, H, T, Dh]; BiasQK: additive key bias [B, Tk] or
-    [B,1,1,Tk].  Lowered to the Pallas FlashAttention-2 TPU kernel when
+    Q,K: [B, H, T, Dh]; V: [B, H, Tk, Dv], where Dv may differ from Dh
+    (latent attention: 192 against 128) and is the output's width;
+    BiasQK: additive key bias [B, Tk] or [B,1,1,Tk].  Lowered to the
+    Pallas FlashAttention-2 TPU kernel when
     profitable, XLA attention otherwise (ops/pallas/flash_attention.py);
     its backward is the custom-vjp flash backward, reached through the
     registry's generic grad derivation: over the residuals (``m``, ``l``)
@@ -602,6 +604,149 @@ def fused_dropout_add_ln(ctx, attrs, X, Residual, Scale, Bias):
     out = _fused(X.reshape(-1, d), Residual.reshape(-1, d), Scale, Bias,
                  dropout_rate=rate, eps=eps, seed=seed)
     return out.reshape(shape)
+
+
+@register_op("rms_norm", inputs=["X", "Scale"], outputs=["Y"])
+def rms_norm(ctx, attrs, X, Scale):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, the
+    statistics in float32 whatever X's dtype."""
+    eps = float(attrs.get("epsilon", 1e-6))
+    x32 = X.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    if Scale is not None:
+        y = y * Scale.astype(jnp.float32)
+    return y.astype(X.dtype)
+
+
+@register_op("rotary_embedding", inputs=["X"], outputs=["Out"])
+def rotary_embedding(ctx, attrs, X):
+    """Rotary position embedding on part of a head.  X: [..., T, Dh],
+    positions 0..T-1 along the last axis but one; the ``rotary_dim``
+    features from ``offset`` on are rotated, the rest pass through.
+    ``interleaved``: pair i is features (2i, 2i+1) of the part, else
+    (i, i + rotary_dim/2); its angle is ``t * theta^(-2i/rotary_dim)``.
+    Computed in float32."""
+    t, dh = jnp.shape(X)[-2], jnp.shape(X)[-1]
+    offset = int(attrs.get("offset", 0))
+    rot = int(attrs.get("rotary_dim", 0)) or dh - offset
+    theta = float(attrs.get("theta", 10000.0))
+    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)                  # [T, rot/2]
+    part = X[..., offset:offset + rot].astype(jnp.float32)
+    if attrs.get("interleaved", True):
+        pairs = part.reshape(part.shape[:-1] + (rot // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                        axis=-1).reshape(part.shape)
+    else:
+        a, b = part[..., :rot // 2], part[..., rot // 2:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                              axis=-1)
+    return jnp.concatenate(
+        [X[..., :offset], out.astype(X.dtype), X[..., offset + rot:]],
+        axis=-1)
+
+
+@register_op("swiglu", inputs=["X", "Y"], outputs=["Out"])
+def swiglu(ctx, attrs, X, Y):
+    """``silu(x) * y``, the product in float32."""
+    return (jax.nn.silu(X.astype(jnp.float32))
+            * Y.astype(jnp.float32)).astype(X.dtype)
+
+
+def _rows_of(shape):
+    """Rows of a [..., D] var flattened to [N, D]; -1 where a dim is."""
+    lead = [int(d) for d in shape[:-1]]
+    return -1 if any(d < 0 for d in lead) else math.prod(lead)
+
+
+def _moe_route_shapes(op, block):
+    # from the metadata: the unknown batch's sentinel times tokens times
+    # top_k passes 2**31, and eval_shape of the sort would refuse it
+    x = block._find_var_recursive(op.input("X")[0])
+    for slot, dtype in (("Index", "int32"), ("Gate", "float32")):
+        v = block._find_var_recursive(op.output(slot)[0])
+        v.shape = (_rows_of(x.shape), int(op.attrs["top_k"]))
+        v.dtype = dtype
+    used = block._find_var_recursive(op.output("BiasOut")[0])
+    used.shape = tuple(block._find_var_recursive(op.input("Bias")[0]).shape)
+    used.dtype = "float32"
+    if op.output("XOut"):
+        kept = block._find_var_recursive(op.output("XOut")[0])
+        kept.shape, kept.dtype = (_rows_of(x.shape), x.shape[-1]), x.dtype
+
+
+def _moe_experts_shapes(op, block):
+    x = block._find_var_recursive(op.input("X")[0])
+    out = block._find_var_recursive(op.output("Out")[0])
+    out.shape, out.dtype = tuple(x.shape), x.dtype
+    rows = block._find_var_recursive(op.output("Rows")[0])
+    rows.shape, rows.dtype = (len(op.input("WGate")),), "int32"
+
+
+@register_op("moe_route", inputs=["X", "Weight", "Bias"],
+             outputs=["Index", "Gate", "BiasOut", "XOut"],
+             infer_shape=_moe_route_shapes)
+def moe_route(ctx, attrs, X, Weight, Bias):
+    """The router of a top-k expert layer over ALL its experts
+    (``parallel/moe.py`` ``sigmoid_topk_route``).  X: [..., D]; Weight:
+    [D, E]; Bias: [E], added to the scores for the choice only.  Index:
+    [N, top_k] int32 and Gate: [N, top_k] float32, N the rows of X;
+    BiasOut: [E], the bias the choice was made with: Bias, or with
+    ``center_bias`` outside test mode minus each expert's mean score
+    over these rows.  XOut (``keep_input``): the rows of X as the router
+    read them, behind an optimization barrier: the compiler keeps more
+    than bfloat16 between the ops it fuses, so a copy of X made by
+    another op need not hold the very numbers this one was given."""
+    from ..parallel.moe import sigmoid_topk_route
+
+    center = bool(attrs.get("center_bias")) and not (
+        attrs.get("is_test") or ctx.mode == "infer")
+    x = X.reshape(-1, jnp.shape(X)[-1])
+    if attrs.get("keep_input"):
+        x = jax.lax.optimization_barrier(x)
+    idx, gates, used = sigmoid_topk_route(
+        x, Weight, Bias, int(attrs["top_k"]),
+        float(attrs.get("scale", 1.0)),
+        bool(attrs.get("norm_topk_prob", True)), center)
+    return {"Index": idx, "Gate": gates, "BiasOut": used, "XOut": x}
+
+
+@register_op("moe_experts", inputs=["X", "Index", "Gate", "WGate*", "WUp*",
+                                    "WDown*"],
+             outputs=["Out", "Rows"], stateful_outputs=("Rows",),
+             infer_shape=_moe_experts_shapes)
+def moe_experts(ctx, attrs, X, Index, Gate, WGate, WUp, WDown):
+    """The part of a top-k expert layer that the experts held here give
+    (``parallel/moe.py`` ``held_experts_ffn``): WGate, WUp (each expert's
+    [D, F]) and WDown ([F, D]) are experts ``first_expert .. first_expert
+    + held - 1`` of those Index counts over, stacked here for the grouped
+    products.  Dropless.  Out: X's shape; Rows: [held] int32, the rows
+    each held expert was given this step."""
+    from ..parallel.moe import held_experts_ffn
+
+    shape = jnp.shape(X)
+    out, rows = held_experts_ffn(
+        X.reshape(-1, shape[-1]), Index, Gate, jnp.stack(WGate),
+        jnp.stack(WUp), jnp.stack(WDown),
+        first=int(attrs.get("first_expert", 0)), scope=ctx.part_scope)
+    return {"Out": out.reshape(shape), "Rows": rows}
+
+
+@register_op("moe_count_rows", inputs=["Rows", "Index", "Stats"],
+             outputs=["StatsOut"], no_grad=True)
+def moe_count_rows(ctx, attrs, Rows, Index, Stats):
+    """Adds one step to an expert layer's counters, on the device:
+    Stats is int32 [held + 2], the rows given to each held expert so
+    far, then the rows possible (tokens * top_k) and the steps.  int32
+    wraps; ``observability.runtime.publish_moe_counters`` reads
+    differences."""
+    step = jnp.concatenate([
+        Rows.astype(jnp.int32),
+        jnp.asarray([Index.size, 1], jnp.int32)])
+    return Stats + step
 
 
 @register_op("fused_bias_act", inputs=["X", "Bias"], outputs=["Out"])

@@ -18,6 +18,12 @@ Supported bias: an additive key-padding bias of shape [B, Tk] (the common
 treated as constant (no gradient — padding masks are data, not parameters).
 Causal masking is a flag; above-diagonal blocks are skipped entirely.
 
+Q and K share one head width ``d`` (the contraction of the scores); V has
+its own, ``dv``, which is also the output's: latent attention carries a
+rotary part on Q and K only (192 against 128).  The forward's PV product
+and accumulator, dO, dV and delta are ``dv`` wide, the scores, dQ and dK
+``d`` wide; nothing is padded to the wider of the two.
+
 Attention-probability dropout IS supported in-kernel (``dropout_rate``):
 the FA2 formulation — the softmax denominator l comes from the UNdropped
 probabilities, dropout scales the numerator entries feeding the PV matmul
@@ -139,7 +145,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, m_out_ref,
         # fix as the r04 XLA-fallback change; f32 inputs are unchanged.
         q = q_ref[0]  # [bq, d]
         k = k_ref[0]  # [bk, d]
-        v = v_ref[0]  # [bk, d]
+        v = v_ref[0]  # [bk, dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -203,14 +209,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, m_out_ref,
 def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
                interpret, dropout_rate, dropout_debug):
     bh, tq, d = q.shape
-    _, tk, _ = k.shape
+    _, tk, dv = v.shape
     nq, nk = tq // block_q, tk // block_k
     grid = (bh, nq, nk)
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
     ]
     args = [q, k, v]
     kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -237,17 +243,17 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -397,7 +403,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref, m_ref,
 def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
                block_q, block_k, interpret, dropout_rate, dropout_debug):
     bh, tq, d = q.shape
-    _, tk, _ = k.shape
+    _, tk, d_v = v.shape
     nq, nk = tq // block_q, tk // block_k
 
     delta = jnp.sum(
@@ -412,7 +418,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     dkv_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # q
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
+        pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),  # v
     ]
     dkv_args = [q, k, v]
     if bias is not None:
@@ -432,7 +438,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     dkv_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))   # seed
     dkv_args.append(seed)
     dkv_specs += [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),     # do
+        pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),    # do
         pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # m
         pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # l
         pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # delta
@@ -446,15 +452,15 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(*dkv_args)
@@ -463,7 +469,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
     ]
     dq_args = [q, k, v]
     if bias is not None:
@@ -481,7 +487,7 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     dq_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))   # seed
     dq_args.append(seed)
     dq_specs += [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),     # do
+        pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),    # do
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # m
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # l
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # delta
@@ -508,7 +514,8 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
 
 def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                   dropout_rate=0.0, seed=None, debug=False):
-    """Plain-XLA multi-head attention. q,k,v: [B,H,T,D]; bias: [B,Tk].
+    """Plain-XLA multi-head attention. q,k: [B,H,T,D]; v: [B,H,Tk,Dv];
+    bias: [B,Tk].
     With dropout: upscale-in-train on the probabilities; the mask comes
     from the debug position hash (bit-matching the kernel's debug mode)
     or jax.random (statistically matching the kernel's hardware PRNG)."""
@@ -612,10 +619,10 @@ def flash_min_t():
     return 512
 
 
-def _kernel_applicable(q, k, bias):
+def _kernel_applicable(q, k, bias, dv=None):
     bh, tq, d = q.shape
     _, tk, _ = k.shape
-    if d > 512:
+    if max(d, dv or d) > 512:
         return False
     # Perf heuristic (measured on v5e): the blocked kernel wins once the
     # score matrix per head exceeds ~256x256 (2.0-2.4x at T=2048); at
@@ -636,11 +643,12 @@ def _kernel_applicable(q, k, bias):
     return True
 
 
-def routes_to_kernel(q, k, bias=None):
+def routes_to_kernel(q, k, bias=None, v=None):
     """Does :func:`flash_attention` run the Pallas kernels for these
-    shapes (q, k: [B, H, T, D]; bias as the entry point takes it;
-    anything with ``.shape``), or XLA attention?  The entry point's one
-    routing decision."""
+    shapes (q, k: [B, H, T, D]; v: [B, H, Tk, Dv], taken to be as wide
+    as k where left out; bias as the entry point takes it; anything
+    with ``.shape``), or XLA attention?  The entry point's one routing
+    decision."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
     if bias is not None:
@@ -648,7 +656,8 @@ def routes_to_kernel(q, k, bias=None):
                                     jnp.float32)
     return use_pallas()[0] and _kernel_applicable(
         jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32),
-        jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32), bias)
+        jax.ShapeDtypeStruct((b * h, tk, d), jnp.float32), bias,
+        dv=None if v is None else v.shape[-1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
@@ -683,8 +692,9 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
                     dropout_rate=0.0, dropout_seed=None):
     """Multi-head attention: Pallas flash kernel on TPU, XLA elsewhere.
 
-    q,k,v: [B, H, T, D]; bias: additive key bias [B, Tk] or [B,1,1,Tk]
-    (no gradient flows to bias); returns [B, H, Tq, D].
+    q,k: [B, H, T, D]; v: [B, H, Tk, Dv] (Dv may differ from D); bias:
+    additive key bias [B, Tk] or [B,1,1,Tk] (no gradient flows to bias);
+    returns [B, H, Tq, Dv].  ``sm_scale`` defaults to 1/sqrt(D).
 
     dropout_rate > 0 applies attention-probability dropout IN-KERNEL
     (upscale-in-train semantics); ``dropout_seed`` is an int32 scalar or
@@ -715,11 +725,12 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     tk = k.shape[2]
     qf = q.reshape(b * h, tq, d)
     kf = k.reshape(b * h, tk, d)
-    vf = v.reshape(b * h, tk, d)
+    dv = v.shape[-1]
+    vf = v.reshape(b * h, tk, dv)
     seed = jnp.reshape(
         jnp.asarray(0 if dropout_seed is None else dropout_seed,
                     jnp.int32), (1,))
-    if not routes_to_kernel(q, k, bias):
+    if not routes_to_kernel(q, k, bias, v):
         return mha_reference(q, k, v, bias=bias, causal=causal,
                              sm_scale=sm_scale,
                              dropout_rate=dropout_rate, seed=seed,
@@ -737,4 +748,4 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     bq, bk = _pick_blocks(tq, tk)
     o = _flash(qf, kf, vf, bias, seed, causal, sm_scale, bq, bk,
                interpret, dropout_rate, debug)
-    return o.reshape(b, h, tq, d)
+    return o.reshape(b, h, tq, dv)

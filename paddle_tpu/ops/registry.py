@@ -185,6 +185,16 @@ class LoweringContext:
         # trace time for the grad ops of Mosaic kernel sites (executor
         # ``_run_ops_into_env``) when the caller sets a dict; None: no note
         self.residual_sites = None
+        # the ``jax.named_scope`` of the op being lowered, set by the
+        # Executor (``_run_ops_into_env``): ``pd<index>_<tag>``
+        self.op_scope = "pd0_op"
+
+    def part_scope(self, part):
+        """The scope that names a part of the op being lowered for a
+        device trace: ``<the op's own scope>.<part>``, inside the op's."""
+        import jax
+
+        return jax.named_scope("%s.%s" % (self.op_scope, part))
 
     def set_op(self, op_id):
         self._op_id = op_id

@@ -20,6 +20,25 @@ Entry points mirror the other parallel primitives:
 * :func:`moe_ffn` — global [B, T, D] + mesh wrapper (batch sharded over
   the ``expert`` axis, experts sharded over the same axis — the usual
   dp=ep co-located layout).
+
+**The dropless share** (:func:`sigmoid_topk_route`, :func:`plan_held_rows`,
+:func:`held_experts_ffn`) is the expert layer that expert parallelism over
+many small experts needs, and the lowering of the Program ops
+``moe_route`` and ``moe_experts``: the device is told which experts it
+holds (``first``, ``held`` of ``E``), routes over all ``E`` (sigmoid
+scores, the ``top_k`` largest of score + correction bias, gates
+normalised over the chosen and scaled) and computes the part of the
+layer's result that its own experts give.  Nothing is dropped whatever
+the imbalance: every choice has its row in a buffer of ``tokens * top_k``
+rows (every choice of every token may land here), those of the held
+experts first and sorted by expert, and the three products are
+:func:`grouped_dot` (``jax.lax.ragged_dot``) over the held experts' row
+counts, whose work follows the rows routed here and not ``rows *
+experts`` (on the TPU XLA lowers it to one grouped Mosaic
+kernel, ``ragged-dot-none``, that visits only the tiles the counts
+cover).  On one device it runs without an exchange; under expert
+parallelism the exchange moves rows between devices before and after it.
+The Switch path above is as it was.
 """
 
 import jax
@@ -27,7 +46,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "moe_ffn_local", "init_moe_params",
-           "moe_dispatch", "moe_combine", "MOE_RING_ID"]
+           "moe_dispatch", "moe_combine", "MOE_RING_ID",
+           "sigmoid_topk_route", "held_rows", "plan_held_rows",
+           "grouped_dot", "held_experts_ffn"]
 
 # ring-id convention (see parallel/pipeline.py / README "Analyzer")
 MOE_RING_ID = 2
@@ -196,3 +217,132 @@ def moe_ffn(x, params, mesh, axis_name, capacity_factor=1.25,
         out_specs=(P(axis_name), P()),
         check_vma=False,
     )(x, params)
+
+
+# ---------------------------------------------------------------------------
+# The dropless share of a top-k expert layer (module docstring)
+# ---------------------------------------------------------------------------
+
+def sigmoid_topk_route(x, w_router, bias, top_k, scale=1.0, norm=True,
+                       center=False):
+    """x: [T, D]; w_router: [D, E]; bias: [E] (the choice's correction
+    bias, no gradient).  Scores ``s = sigmoid(x W)`` in float32 at the
+    highest matmul precision (a near-tie decides which expert runs); the
+    ``top_k`` largest of ``s + bias`` are chosen, the gates are the
+    chosen ``s`` (over their sum where ``norm``) times ``scale``.
+
+    ``center``: the bias is not the one given but minus each expert's
+    mean score over these T tokens, so that an expert is chosen by how
+    much more it scores a token than it scores the tokens on average.
+    It is what balances the load of tokens that differ little among
+    themselves, as a model's do before it is trained: there a few
+    experts' mean scores lie above all the others' whatever the token,
+    and a bias kept from one batch does not fit the next.  (Under data
+    parallelism the mean would be taken over all the step's tokens.)
+
+    Returns ``(idx [T, top_k] int32, gates [T, top_k] float32, bias [E]
+    as used)``."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    bias = jax.lax.stop_gradient(
+        -jnp.mean(s, axis=0) if center else bias.astype(jnp.float32))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(s) + bias, top_k)
+    gates = jnp.take_along_axis(s, idx, axis=-1)
+    if norm:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gates * scale, bias
+
+
+def held_rows(idx, first, held):
+    """idx: [T, K] experts chosen over all E.  Returns ``key`` ([T*K]: a
+    choice's held expert counted from ``first``, or ``held`` where it
+    names an expert that is not here) and ``rows`` ([held] int32: the
+    rows each held expert is given)."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    rows = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                   dtype=jnp.int32)
+    return key, rows
+
+
+def plan_held_rows(idx, first, held):
+    """Where each choice goes: the choices of the held experts, sorted by
+    expert (stable, so by token inside an expert), come first.  Returns
+    ``order`` ([T*K]: the flat choice ``t * K + k`` that row r of the
+    buffer takes) and ``rows``."""
+    key, rows = held_rows(idx, first, held)
+    return jnp.argsort(key, stable=True).astype(jnp.int32), rows
+
+
+@jax.custom_vjp
+def grouped_dot(lhs, rhs, rows):
+    """``out[r] = lhs[r] @ rhs[g(r)]``: lhs [R, K] with its first
+    ``sum(rows)`` rows sorted by group, rhs [G, K, N], rows [G] the rows
+    of each group.  ``jax.lax.ragged_dot``, whose work follows
+    ``sum(rows)`` and not R or G (on the TPU one grouped Mosaic kernel,
+    ``ragged-dot-none``), with what it leaves unwritten made zero at the
+    source, forward and backward: the rows past ``sum(rows)`` of the
+    result and of lhs's cotangent, and the cotangent of a group with no
+    row.  Left as they come they are whatever the buffer held, and a
+    product rule downstream multiplies by them."""
+    covered = (jnp.arange(lhs.shape[0]) < jnp.sum(rows))[:, None]
+    return jnp.where(covered, jax.lax.ragged_dot(lhs, rhs, rows), 0)
+
+
+def _grouped_dot_fwd(lhs, rhs, rows):
+    return grouped_dot(lhs, rhs, rows), (lhs, rhs, rows)
+
+
+def _grouped_dot_bwd(res, ct):
+    lhs, rhs, rows = res
+    _, pullback = jax.vjp(
+        lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, rows), lhs, rhs)
+    d_lhs, d_rhs = pullback(ct)
+    covered = (jnp.arange(lhs.shape[0]) < jnp.sum(rows))[:, None]
+    return (jnp.where(covered, d_lhs, 0),
+            jnp.where((rows > 0)[:, None, None], d_rhs, 0), None)
+
+
+grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
+                     scope=jax.named_scope):
+    """The held experts' part of ``sum_k gates[t,k] * E_idx[t,k](x[t])``
+    with ``E(x) = W_down (silu(W_gate x) * W_up x)``.
+
+    x: [T, D]; idx, gates: [T, K] from :func:`sigmoid_topk_route`;
+    w_gate, w_up: [held, D, F]; w_down: [held, F, D]: the experts
+    ``first .. first + held - 1``.  Returns ``(y [T, D], rows [held]
+    int32)``; ``rows`` are the rows each held expert was given, summing
+    to the work done.
+
+    One path whatever the load: every choice of every token has its row
+    in a buffer of ``T * K`` rows, the choices of the held experts first
+    and sorted by expert; the three products run over it grouped by
+    ``rows`` (their work follows the rows routed here); the results are
+    scatter-added to their tokens, times their gates, in float32.  A row
+    past the routed ones takes a token that chose an expert elsewhere;
+    its products are zero, so it adds nothing to that token and takes no
+    gradient to a weight.  Plain differentiable ``jax.numpy`` round
+    :func:`grouped_dot`: the gather to the buffer and the scatter-add
+    back are each other's transposes, and both move ``T * K`` rows
+    however few are routed here.  ``scope(name)`` names the three parts
+    for a device trace: ``dispatch``, ``products`` and ``combine``."""
+    held, top_k = w_gate.shape[0], idx.shape[1]
+    with scope("dispatch"):
+        order, rows = plan_held_rows(idx, first, held)
+        token = order // top_k           # in [0, T): no bounds to check
+        xs = x.at[token].get(mode="promise_in_bounds")
+    with scope("products"):
+        h = grouped_dot(xs, w_gate.astype(xs.dtype), rows)
+        u = grouped_dot(xs, w_up.astype(xs.dtype), rows)
+        a = (jax.nn.silu(h.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(xs.dtype)
+        y = grouped_dot(a, w_down.astype(xs.dtype), rows)
+    with scope("combine"):
+        out = jnp.zeros(x.shape, jnp.float32).at[token].add(
+            y.astype(jnp.float32) * gates.reshape(-1)[order][:, None],
+            mode="promise_in_bounds")
+    return out.astype(x.dtype), rows

@@ -114,8 +114,8 @@ def _flash(causal, rate, bias):
     return fn
 
 
-def _flash_args(b, h, t, d, dt, rate, bias):
-    qkv = [((b, h, t, d), dt)] * 3
+def _flash_args(b, h, t, d, dt, rate, bias, dv=None):
+    qkv = [((b, h, t, d), dt)] * 2 + [((b, h, t, dv or d), dt)]
     return qkv + ([((b, t), F32)] if bias else []) \
         + ([((1,), I32)] if rate else [])
 
@@ -132,6 +132,18 @@ def test_flash_attention(chip, b, h, t, d, causal, rate, bias, grad):
         fn = _grad_sum(fn, (0, 1, 2))
     n = _kernels_in(fn, chip, *_flash_args(b, h, t, d, BF16, rate, bias))
     assert n == (3 if grad else 1)  # fwd + (dK/dV, dQ)
+
+
+@pytest.mark.parametrize("grad", [False, True])
+def test_flash_attention_at_d_qk_other_than_d_v(chip, grad):
+    """Latent attention at the published widths: Q and K 192 wide (128
+    position-free, 64 rotary), V and the output 128; causal, 4 x 4096."""
+    fn = _flash(True, 0.0, False)
+    if grad:
+        fn = _grad_sum(fn, (0, 1, 2))
+    n = _kernels_in(fn, chip, *_flash_args(4, 32, 4096, 192, BF16, 0.0,
+                                           False, dv=128))
+    assert n == (3 if grad else 1)
 
 
 # -- decode kernels (forward only) -------------------------------------------

@@ -1,8 +1,8 @@
 """Tier-1 hook for the benchmark's own checks.
 
 ``chipbench/selftest/`` holds the yardstick's tests (the manifest, the
-training loop at ``BERT_TINY`` width, the trace reductions on the trimmed
-recordings, the FLOP functions): a benchmark PR may add no file outside its
+training loop at ``BERT_TINY`` width and at a small decoder's, the trace
+reductions and the readers on the trimmed recordings, the FLOP functions): a benchmark PR may add no file outside its
 directories, so they live there, and this file imports them so that each
 counts in the driver's run on the CPU.  Nothing here measures anything.
 """
@@ -14,3 +14,4 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench.selftest.test_chipbench import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_program_spans import *  # noqa: E402,F401,F403
+from chipbench.selftest.test_kanana import *  # noqa: E402,F401,F403
