@@ -1280,13 +1280,14 @@ def moe_experts(input, index, gate, expert_width, experts_held,
 def moe_count_rows(rows, index, layer, name=None):
     """Keeps an expert layer's counters on the device: a persistable
     int32 vector ``<name>`` of the rows given to each held expert so far,
-    then the rows possible (tokens * top_k) and the steps, updated inside
-    the step with no host sync.  ``observability.runtime.
+    then the rows possible (tokens * top_k), the rows dispatch moved (a
+    whole block for each block the layer's loop ran) and the steps,
+    updated inside the step with no host sync.  ``observability.runtime.
     publish_moe_counters`` reads them into the metrics registry under the
     label ``layer``.  Call it outside any recompute region."""
     helper = LayerHelper("moe_count_rows", **locals())
     stats = helper.create_or_get_global_variable(
-        name or "moe_rows.layer%s" % layer, shape=[rows.shape[0] + 2],
+        name or "moe_rows.layer%s" % layer, shape=[rows.shape[0] + 3],
         dtype="int32")
     stats.stop_gradient = True
     helper.set_variable_initializer(stats, ConstantInitializer(0))

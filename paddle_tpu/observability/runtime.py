@@ -293,12 +293,16 @@ def record_moe_layers(program, compile_phase):
 def publish_moe_counters(scope=None):
     """Reads each noted expert layer's device counters (one host sync a
     layer; nothing is read until this is called) and publishes what was
-    added since the last read: counters ``moe_rows_routed_here_total``
-    and ``moe_rows_possible_total`` (tokens * top_k), gauges
-    ``moe_expert_rows_max`` and ``moe_expert_rows_mean`` over the held
-    experts' totals, each with ``layer``.  Returns ``{layer: {"rows":
-    [per held expert], "possible": n, "steps": n}}``, the totals since
-    the counters were last zeroed."""
+    added since the last read: counters ``moe_rows_routed_here_total``,
+    ``moe_rows_possible_total`` (tokens * top_k) and
+    ``moe_buffer_rows_moved_total`` (the rows dispatch gathered and
+    combine added back: a whole block for each block the layer's loop
+    ran), gauges ``moe_expert_rows_max`` and ``moe_expert_rows_mean``
+    over the held experts' totals, each with ``layer``.  Returns
+    ``{layer: {"rows": [per held expert], "possible": n, "moved": n,
+    "steps": n}}``, the totals since the counters were last zeroed;
+    ``moved / possible`` is the share of the choices' order that was
+    touched (1: the loop never stopped early)."""
     import numpy as np
 
     if scope is None:
@@ -315,15 +319,18 @@ def publish_moe_counters(scope=None):
         delta = raw if raw[-1] < last[-1] else (raw - last) & 0xFFFFFFFF
         total = delta + (0 if raw[-1] < last[-1] else total)
         _moe_read[name] = (raw, total)
-        rows = [int(x) for x in total[:-2]]
-        out[layer] = {"rows": rows, "possible": int(total[-2]),
-                      "steps": int(total[-1])}
+        rows = [int(x) for x in total[:-3]]
+        out[layer] = {"rows": rows, "possible": int(total[-3]),
+                      "moved": int(total[-2]), "steps": int(total[-1])}
         if telemetry_enabled():
             _m.counter("moe_rows_routed_here_total",
                        "rows the experts held here were given",
-                       layer=layer).inc(int(delta[:-2].sum()))
+                       layer=layer).inc(int(delta[:-3].sum()))
             _m.counter("moe_rows_possible_total",
                        "tokens * top_k: the rows all experts were given",
+                       layer=layer).inc(int(delta[-3]))
+            _m.counter("moe_buffer_rows_moved_total",
+                       "rows dispatch gathered and combine added back",
                        layer=layer).inc(int(delta[-2]))
             _m.gauge("moe_expert_rows_max", layer=layer).set(max(rows))
             _m.gauge("moe_expert_rows_mean",

@@ -739,13 +739,17 @@ def moe_experts(ctx, attrs, X, Index, Gate, WGate, WUp, WDown):
              outputs=["StatsOut"], no_grad=True)
 def moe_count_rows(ctx, attrs, Rows, Index, Stats):
     """Adds one step to an expert layer's counters, on the device:
-    Stats is int32 [held + 2], the rows given to each held expert so
-    far, then the rows possible (tokens * top_k) and the steps.  int32
-    wraps; ``observability.runtime.publish_moe_counters`` reads
-    differences."""
+    Stats is int32 [held + 3], the rows given to each held expert so
+    far, then the rows possible (tokens * top_k), the rows dispatch
+    moved (``parallel/moe.py``: ``BLOCK_ROWS`` times the blocks the
+    layer's loop ran) and the steps.  int32 wraps;
+    ``observability.runtime.publish_moe_counters`` reads differences."""
+    from ..parallel.moe import BLOCK_ROWS, blocks_run
+
     step = jnp.concatenate([
         Rows.astype(jnp.int32),
-        jnp.asarray([Index.size, 1], jnp.int32)])
+        jnp.stack([jnp.int32(Index.size), BLOCK_ROWS * blocks_run(Rows),
+                   jnp.int32(1)])])
     return Stats + step
 
 

@@ -29,17 +29,24 @@ holds (``first``, ``held`` of ``E``), routes over all ``E`` (sigmoid
 scores, the ``top_k`` largest of score + correction bias, gates
 normalised over the chosen and scaled) and computes the part of the
 layer's result that its own experts give.  Nothing is dropped whatever
-the imbalance: every choice has its row in a buffer of ``tokens * top_k``
-rows (every choice of every token may land here), those of the held
-experts first and sorted by expert, and the three products are
-:func:`grouped_dot` (``jax.lax.ragged_dot``) over the held experts' row
-counts, whose work follows the rows routed here and not ``rows *
-experts`` (on the TPU XLA lowers it to one grouped Mosaic
-kernel, ``ragged-dot-none``, that visits only the tiles the counts
-cover).  On one device it runs without an exchange; under expert
-parallelism the exchange moves rows between devices before and after it.
+the imbalance: every choice has its place in an order of ``tokens *
+top_k`` choices (every choice of every token may land here), those of
+the held experts first and sorted by expert, and one loop walks that
+order in blocks of ``BLOCK_ROWS`` as far as the rows routed here reach,
+its trip count read from the row counts on the device.  A block gathers
+its tokens' rows, runs the three products as :func:`grouped_dot`
+(``jax.lax.ragged_dot``) over the held experts' row counts inside the
+block (on the TPU XLA lowers it to one grouped Mosaic kernel,
+``ragged-dot-none``, that visits only the tiles the counts cover) and
+scatter-adds the results to their tokens: dispatch, products and
+combine all follow the rows routed here, not ``tokens * top_k`` and not
+``rows * experts``, and no buffer of every choice exists.  On one device
+it runs without an exchange; under expert parallelism the exchange moves
+rows between devices before and after it.
 The Switch path above is as it was.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +55,7 @@ from jax.sharding import PartitionSpec as P
 __all__ = ["moe_ffn", "moe_ffn_local", "init_moe_params",
            "moe_dispatch", "moe_combine", "MOE_RING_ID",
            "sigmoid_topk_route", "held_rows", "plan_held_rows",
-           "grouped_dot", "held_experts_ffn"]
+           "grouped_dot", "BLOCK_ROWS", "blocks_run", "held_experts_ffn"]
 
 # ring-id convention (see parallel/pipeline.py / README "Analyzer")
 MOE_RING_ID = 2
@@ -307,6 +314,101 @@ def _grouped_dot_bwd(res, ct):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+# Choices a trip of ``held_experts_ffn``'s loop takes.  A trip's cost is
+# mostly its scatter-adds, the same for every row of the block whether
+# routed here or past the count, and what it does once a block (the
+# weights' cotangents added, their layouts copied): on the v5e larger
+# blocks won (PERF.md 6, PR 30), and the layer's temporaries grow with it.
+BLOCK_ROWS = 8192
+
+
+def blocks_run(rows):
+    """Trips the loop of :func:`held_experts_ffn` makes for ``rows`` (the
+    rows given to each held expert): the blocks of ``BLOCK_ROWS`` buffer
+    rows that hold a routed row.  Read on the device."""
+    return (jnp.sum(rows) + (BLOCK_ROWS - 1)) // BLOCK_ROWS
+
+
+def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes):
+    """One block of the buffer: xs [B, D] the rows' tokens, g [B] their
+    gates, sizes [held] the rows of each held expert inside the block.
+    Returns [B, D] float32, what each row adds to its token."""
+    with scope("products"):
+        h = grouped_dot(xs, w_gate, sizes)
+        u = grouped_dot(xs, w_up, sizes)
+        a = (jax.nn.silu(h.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(xs.dtype)
+        y = grouped_dot(a, w_down, sizes)
+    with scope("combine"):
+        return y.astype(jnp.float32) * g[:, None]
+
+
+def _block_inputs(scope, i, x, gates, order, rows):
+    """Block i of the buffer: the flat choices it takes, their tokens,
+    those tokens' rows of x, the choices' gates, and the held experts'
+    cumulative row counts cut to the block's range."""
+    with scope("dispatch"):
+        lo = i * BLOCK_ROWS
+        choice = jax.lax.dynamic_slice(order, (lo,), (BLOCK_ROWS,))
+        token = choice // gates.shape[1]    # in [0, T): no bounds to check
+        xs = x.at[token].get(mode="promise_in_bounds")
+        g = gates.reshape(-1).at[choice].get(mode="promise_in_bounds")
+        ends = jnp.cumsum(rows)
+        sizes = (jnp.clip(ends - lo, 0, BLOCK_ROWS)
+                 - jnp.clip(ends - rows - lo, 0, BLOCK_ROWS))
+    return choice, token, xs, g, sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk_blocks(scope, x, gates, w_gate, w_up, w_down, order, rows):
+    """``held_experts_ffn`` behind its plan: order [a multiple of
+    BLOCK_ROWS] and rows [held] from :func:`plan_held_rows`."""
+    def block(i, out):
+        _, token, xs, g, sizes = _block_inputs(scope, i, x, gates, order,
+                                               rows)
+        y = _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes)
+        with scope("combine"):
+            return out.at[token].add(y, mode="promise_in_bounds")
+
+    out = jax.lax.fori_loop(0, blocks_run(rows), block,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype)
+
+
+def _walk_blocks_fwd(scope, *args):
+    return _walk_blocks(scope, *args), args
+
+
+def _walk_blocks_bwd(scope, args, ct):
+    """The same loop again: a block is computed anew and differentiated
+    (``jax.vjp`` of the one block function), nothing is kept between
+    blocks, and the cotangents of x (in x's dtype, as the transpose of a
+    gather from x adds them), the gates and the weights are carried."""
+    x, gates, w_gate, w_up, w_down, order, rows = args
+
+    def block(i, carry):
+        d_x, d_gates, d_weights = carry
+        choice, token, xs, g, sizes = _block_inputs(scope, i, x, gates,
+                                                    order, rows)
+        _, pullback = jax.vjp(
+            lambda *a: _block_ffn(scope, *a, sizes), xs, g, w_gate, w_up,
+            w_down)
+        d_xs, d_g, *d_w = pullback(
+            ct.at[token].get(mode="promise_in_bounds").astype(jnp.float32))
+        return (d_x.at[token].add(d_xs, mode="promise_in_bounds"),
+                d_gates.at[choice].add(d_g, mode="promise_in_bounds"),
+                [a + b for a, b in zip(d_weights, d_w)])
+
+    d_x, d_gates, d_weights = jax.lax.fori_loop(
+        0, blocks_run(rows), block,
+        (jnp.zeros_like(x), jnp.zeros(gates.size, gates.dtype),
+         [jnp.zeros_like(w) for w in (w_gate, w_up, w_down)]))
+    return d_x, d_gates.reshape(gates.shape), *d_weights, None, None
+
+
+_walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
+
+
 def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
                      scope=jax.named_scope):
     """The held experts' part of ``sum_k gates[t,k] * E_idx[t,k](x[t])``
@@ -318,31 +420,29 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
     int32)``; ``rows`` are the rows each held expert was given, summing
     to the work done.
 
-    One path whatever the load: every choice of every token has its row
-    in a buffer of ``T * K`` rows, the choices of the held experts first
-    and sorted by expert; the three products run over it grouped by
-    ``rows`` (their work follows the rows routed here); the results are
-    scatter-added to their tokens, times their gates, in float32.  A row
-    past the routed ones takes a token that chose an expert elsewhere;
-    its products are zero, so it adds nothing to that token and takes no
-    gradient to a weight.  Plain differentiable ``jax.numpy`` round
-    :func:`grouped_dot`: the gather to the buffer and the scatter-add
-    back are each other's transposes, and both move ``T * K`` rows
-    however few are routed here.  ``scope(name)`` names the three parts
-    for a device trace: ``dispatch``, ``products`` and ``combine``."""
-    held, top_k = w_gate.shape[0], idx.shape[1]
+    One path whatever the load: every choice of every token has its
+    place in an order of ``T * K`` choices, those of the held experts
+    first and sorted by expert (:func:`plan_held_rows`), and one loop
+    walks that order in blocks of ``BLOCK_ROWS`` as far as the routed
+    rows reach (:func:`blocks_run`, read from ``rows`` on the device).  A
+    block gathers its choices' tokens from x, runs the three products
+    grouped by the held experts' row counts cut to the block's range,
+    and scatter-adds the results to their tokens, times their gates, in
+    float32, into the ``[T, D]`` result the loop carries: no buffer of
+    ``T * K`` rows exists, and with every choice routed here the loop
+    runs every block.  The last block's rows past the routed ones take
+    tokens that chose an expert elsewhere; their products are zero, so
+    they add nothing and take no gradient to a weight.  A loop with a
+    traced trip count has no reverse mode, so the backward pass is the
+    same loop again (``_walk_blocks_bwd``).  ``scope(name)`` names the
+    three parts of a block for a device trace: ``dispatch``, ``products``
+    and ``combine``."""
+    held = w_gate.shape[0]
     with scope("dispatch"):
         order, rows = plan_held_rows(idx, first, held)
-        token = order // top_k           # in [0, T): no bounds to check
-        xs = x.at[token].get(mode="promise_in_bounds")
-    with scope("products"):
-        h = grouped_dot(xs, w_gate.astype(xs.dtype), rows)
-        u = grouped_dot(xs, w_up.astype(xs.dtype), rows)
-        a = (jax.nn.silu(h.astype(jnp.float32))
-             * u.astype(jnp.float32)).astype(xs.dtype)
-        y = grouped_dot(a, w_down.astype(xs.dtype), rows)
-    with scope("combine"):
-        out = jnp.zeros(x.shape, jnp.float32).at[token].add(
-            y.astype(jnp.float32) * gates.reshape(-1)[order][:, None],
-            mode="promise_in_bounds")
-    return out.astype(x.dtype), rows
+        # padded places lie past every routed row: choice 0, which adds 0
+        order = jnp.pad(order, (0, -order.size % BLOCK_ROWS))
+    out = _walk_blocks(scope, x, gates, w_gate.astype(x.dtype),
+                       w_up.astype(x.dtype), w_down.astype(x.dtype),
+                       order, rows)
+    return out, rows

@@ -212,11 +212,13 @@ def test_a_step_keeps_the_bias_it_made_for_test_mode():
 
 # -- the share and the model ---------------------------------------------------
 
-def _expert_layer_program(shares, bias=None):
+def _expert_layer_program(shares, count=False):
     """One expert layer over [B, T, 64] with the held experts of each of
     ``shares`` ((first, held) pairs) as ``moe_experts`` ops of their own,
     one router and the shared experts once.  Returns what to fetch: each
-    share's routed part, the shared part, each share's rows."""
+    share's routed part, the shared part, each share's rows.  ``count``:
+    each share keeps device counters under the layer label of its first
+    expert."""
     cfg = CFG
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
@@ -238,6 +240,8 @@ def _expert_layer_program(shares, bias=None):
                     name="l.share%d" % first, initializer=init))
             parts.append(y)
             rows.append(r)
+            if count:
+                fluid.layers.moe_count_rows(r, index, layer=first)
         shared = decoder._swiglu_mlp(
             x, 2 * cfg["moe_intermediate_size"], "l.shared",
             dict(cfg, initializer_range=0.3))
@@ -374,6 +378,181 @@ def test_what_the_grouped_kernel_leaves_unwritten_reaches_nothing(
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3)
+
+
+# -- the loop over blocks of the choices' order ---------------------------------
+
+def _steered_layer(here, empty_middle=False):
+    """A layer of 8 experts, top 2, experts 2..4 held, over ``T = B``
+    tokens (B the loop's block: 2 B choices, two blocks) whose first
+    features tell the router which two experts a token takes, so that
+    exactly ``here`` choices name a held expert, spread over the tokens
+    as evenly as whole choices allow.  ``empty_middle``: held expert 3
+    gets none."""
+    from paddle_tpu.parallel import moe
+
+    rng = np.random.default_rng(here)
+    e, k, held, first = 8, 2, 3, 2
+    t, d, f = moe.BLOCK_ROWS, 16, 24
+    theirs, elsewhere = [2, 4] if empty_middle else [2, 3, 4], [0, 1, 5, 6, 7]
+    pick = np.empty((t, k), int)
+    for i in range(t):
+        n = here // t + (i < here % t)      # held experts token i takes
+        pick[i] = np.concatenate([
+            rng.choice(theirs, size=n, replace=False),
+            rng.choice(elsewhere, size=k - n, replace=False)])
+    pick = pick[rng.permutation(t)]
+    x = rng.normal(size=(t, d))
+    x[:, :e] = -1.0 + 0.1 * rng.normal(size=(t, e))
+    np.put_along_axis(x, pick, 1.0 + 0.1 * rng.normal(size=(t, k)), axis=1)
+    w = {"l.router.w": jnp.asarray(
+            np.eye(d, e) * 2 + 0.02 * rng.normal(size=(d, e)), jnp.float32),
+         "l.router.b": jnp.zeros(e, jnp.float32)}
+    for i in range(held):
+        for name, shape in (("gate", (d, f)), ("up", (d, f)),
+                            ("down", (f, d))):
+            w["l.experts.%d.%s" % (i, name)] = jnp.asarray(
+                0.2 * rng.normal(size=shape), jnp.float32)
+    cfg = dict(CFG, num_experts_per_tok=k)
+
+    def program(x, w):
+        idx, gates, _ = moe.sigmoid_topk_route(
+            x, w["l.router.w"], w["l.router.b"], k,
+            cfg["routed_scaling_factor"])
+        stacked = [jnp.stack([w["l.experts.%d.%s" % (i, name)]
+                              for i in range(held)])
+                   for name in ("gate", "up", "down")]
+        return moe.held_experts_ffn(x, idx, gates, *stacked, first=first)
+
+    def reference(x, w):
+        return REF.routed_part(x, w, "l", cfg, first)
+
+    return jnp.asarray(x, jnp.float32), w, program, reference
+
+
+@pytest.mark.parametrize("load", [
+    "no_row", "one_row", "one_block", "one_block_and_a_row",
+    "an_empty_expert_in_the_middle", "every_choice"])
+def test_the_loop_over_blocks_matches_the_reference_at_any_load(load):
+    """Values and the gradients to x, the router's weight and the three
+    expert weights against ``REF.routed_part``, with the routed rows
+    ending before, on and after the edges of the loop's blocks."""
+    from paddle_tpu.parallel import moe
+
+    b = moe.BLOCK_ROWS
+    here = {"no_row": 0, "one_row": 1, "one_block": b,
+            "one_block_and_a_row": b + 1,
+            "an_empty_expert_in_the_middle": b // 2 + 3,
+            "every_choice": 2 * b}[load]
+    x, w, program, reference = _steered_layer(
+        here, empty_middle=load == "an_empty_expert_in_the_middle")
+    out, rows = jax.jit(program)(x, w)
+    assert int(rows.sum()) == here
+    assert int(moe.blocks_run(rows)) == -(-here // b)
+    if load == "an_empty_expert_in_the_middle":
+        assert rows[1] == 0 and rows[0] > 0 and rows[2] > 0
+    got = jax.jit(jax.grad(lambda x, w: jnp.sum(program(x, w)[0] ** 2),
+                           argnums=(0, 1)))(x, w)
+    with jax.default_matmul_precision("highest"):
+        want_out = reference(x, w)
+        want = jax.grad(lambda x, w: jnp.sum(reference(x, w) ** 2),
+                        argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(out, want_out, atol=1e-5, rtol=1e-4)
+    assert (np.abs(np.asarray(want_out)).max() > 0) == (here > 0)
+    for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        scale = np.abs(np.asarray(c)).max()
+        np.testing.assert_allclose(a, c, atol=1e-6 + 1e-4 * scale,
+                                   rtol=1e-3, err_msg=str(path))
+    assert np.abs(np.asarray(got[1]["l.router.w"])).max() > 0 or here == 0
+
+
+def test_the_counters_tell_the_rows_the_loop_moved():
+    """``moved`` is a whole block for each block the loop ran, summed
+    over the steps, beside ``rows``, ``possible`` and ``steps`` as the
+    reference's router gives them for the same feeds; with every choice
+    routed here the loop runs every block and ``moved == possible``."""
+    from paddle_tpu.observability import metrics, runtime
+    from paddle_tpu.parallel import moe
+
+    block, k, first, held = moe.BLOCK_ROWS, 2, 4, 2
+    main, startup, parts, _, rows = _expert_layer_program(
+        [(first, held)], count=True)
+    rng = np.random.default_rng(6)
+    feeds = [rng.normal(size=(block // T, T, CFG["hidden_size"])).astype(
+        "float32") for _ in range(3)]           # `block` tokens a step
+    here = np.full(8, -5.0, "float32")
+    here[first:first + held] = 5.0
+    want = {"rows": np.zeros(held, int), "possible": 0, "moved": 0,
+            "steps": 0}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        scope = fluid.global_scope()
+        for step, x in enumerate(feeds):
+            if step == 2:           # the last step: every choice here
+                scope.set("l.router.b", jnp.asarray(here))
+            exe.run(main, feed={"x": x}, fetch_list=[parts[0]])
+            w = {n: np.asarray(scope.get(n)) for n in ("l.router.w",
+                                                       "l.router.b")}
+            with jax.default_matmul_precision("highest"):
+                idx, _ = REF.route(jnp.asarray(x), w, "l", CFG)
+            given = np.bincount(np.asarray(idx).reshape(-1), minlength=8)[
+                first:first + held]
+            want["rows"] += given
+            want["possible"] += block * k
+            want["moved"] += block * -(-int(given.sum()) // block)
+            want["steps"] += 1
+            if step == 1:
+                before = runtime.publish_moe_counters()[str(first)]
+        counted = runtime.publish_moe_counters()
+        assert counted == runtime.publish_moe_counters()
+    got = counted[str(first)]
+    want["rows"] = want["rows"].tolist()
+    assert got == want
+    # a quarter of the choices name a held expert: one block of two
+    assert before["moved"] == 2 * block and before["possible"] == 4 * block
+    assert got["moved"] - before["moved"] == 2 * block \
+        == got["possible"] - before["possible"]
+    for name, key in (("moe_buffer_rows_moved_total", "moved"),
+                      ("moe_rows_possible_total", "possible")):
+        assert metrics.registry().get(
+            name, layer=str(first)).value == got[key]
+
+
+def test_no_part_of_the_layer_has_a_row_for_every_choice():
+    """The lowered layer and its gradient hold nothing of ``T * K`` rows
+    of the model's or the experts' width (a gather, a scatter, a select
+    or a product over the whole order of choices), only blocks; and the
+    compiler cannot tell how often the loop runs."""
+    import re
+
+    from paddle_tpu.parallel import moe
+
+    block = moe.BLOCK_ROWS
+    t, k, d, f, held = 2 * block, 3, 16, 24, 4
+    args = [jax.ShapeDtypeStruct(s, dt) for s, dt in (
+        ((t, d), jnp.float32), ((t, k), jnp.int32), ((t, k), jnp.float32),
+        ((held, d, f), jnp.float32), ((held, d, f), jnp.float32),
+        ((held, f, d), jnp.float32))]
+
+    def layer(x, idx, gates, *w):
+        return moe.held_experts_ffn(x, idx, gates, *w)[0]
+
+    def grad(x, idx, gates, *w):
+        return jax.grad(lambda x, gates, *w: jnp.sum(
+            layer(x, idx, gates, *w) ** 2), argnums=(0, 1, 2, 3, 4))(
+                x, gates, *w)
+
+    for fn in (layer, grad):
+        lowered = jax.jit(fn).lower(*args)
+        shapes = set(re.findall(r"tensor<(\d+)x(\d+)x[a-z]",
+                                lowered.as_text()))
+        assert {(str(block), str(d)), (str(block), str(f))} <= shapes
+        assert not {(str(t * k), str(d)), (str(t * k), str(f))} & shapes
+        compiled = lowered.compile().as_text()
+        assert re.search(r" while\(", compiled)
+        assert "known_trip_count" not in compiled
 
 
 # -- the pieces ------------------------------------------------------------------
