@@ -10,9 +10,10 @@ grad all-reduce over ICI.  The BuildStrategy knobs that survive are the ones
 XLA doesn't subsume: donation, remat, and the ``fuse_*`` family — which
 since the fusion-pipeline PR drive REAL cost-guided Program-IR rewrites
 (``static_analysis/fusion.py``: Pallas attention/LN kernels, fused
-bias+act, one-op softmax+xent, multi-tensor optimizer updates, bucketed
-gradient allreduce).  Only reduce-strategy / hierarchical-allreduce remain
-accepted-for-parity no-ops (GSPMD always emits fused ring allreduce).
+bias+act, one-op softmax+xent, bucketed gradient allreduce).
+``fuse_all_optimizer_ops`` (XLA fuses each parameter's update itself) and
+reduce-strategy / hierarchical-allreduce (GSPMD always emits fused ring
+allreduce) remain accepted-for-parity no-ops.
 """
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy"]
@@ -38,14 +39,14 @@ class BuildStrategy:
         # the fuse_* knobs drive the REAL cost-guided fusion pass
         # pipeline (static_analysis/fusion.py), the TPU realization of
         # the reference's fuse_all_reduce_op_pass /
-        # fuse_elewise_add_act_pass / fuse_optimizer_ops_pass:
+        # fuse_elewise_add_act_pass:
         #   fuse_all_reduce_ops      -> bucketed gradient allreduce
         #                               (PADDLE_TPU_ALLREDUCE_BUCKET_MB)
         #   fuse_elewise_add_act_ops -> fused_bias_act +
         #                               fused_dropout_add_ln rewrites
-        #   fuse_all_optimizer_ops   -> multi-tensor fused_adam/fused_sgd
-        #                               (cost-gated: BERT-scale groups
-        #                               are rejected, see the r04 A/B)
+        #   fuse_all_optimizer_ops   -> accepted and inert: XLA already
+        #                               fuses each parameter's update
+        #                               into one elementwise kernel
         # PADDLE_TPU_FUSION=0 kills the whole pipeline;
         # CompiledProgram.fusion_report() shows what fired and why not.
         self.fuse_all_reduce_ops = True
@@ -59,13 +60,10 @@ class BuildStrategy:
         self.fuse_softmax_xent = True
         # reference fuse_bn_act_ops, extended to ride the conv too:
         # conv2d -> batch_norm -> (act) becomes one fused_conv_bn_act
-        # (Pallas epilogue on TPU); lookup_table/embedding on device
-        # tables dispatch to the Pallas row-DMA gather kernel.  Both
-        # gates weigh predicted deltas by the autotune calibration
-        # factors (paddle_tpu.autotune) when a silicon sweep recorded
-        # them.
+        # (Pallas epilogue on TPU).  The gate weighs its predicted delta
+        # by the autotune calibration factor (paddle_tpu.autotune) when
+        # a silicon sweep recorded one.
         self.fuse_bn_act_ops = True
-        self.fuse_embedding_gather = True
         self.enable_sequential_execution = False
         self.remove_unnecessary_lock = True
         self.num_trainers = 1

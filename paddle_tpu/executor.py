@@ -287,120 +287,6 @@ _HOST_SIDE_OPS = ("feed", "fetch", "save", "load", "save_combine",
 from .resilience.faults import GATE_FEED as _FAULT_GATE_FEED
 
 
-class _FusedOp:
-    """Lowering-time stand-in for a group of coalesced ops (duck-types
-    the Operator surface _run_ops_into_env touches)."""
-
-    def __init__(self, type, inputs, outputs, attrs):
-        self.type = type
-        self.inputs = inputs
-        self.outputs = outputs
-        self.attrs = attrs
-
-    @property
-    def input_arg_names(self):
-        return [n for ns in self.inputs.values() for n in ns]
-
-    @property
-    def output_arg_names(self):
-        return [n for ns in self.outputs.values() for n in ns]
-
-
-def _fuse_adam_ops(ops, block):
-    """Coalesce per-param ``adam`` ops into ``fused_adam`` groups — the
-    TPU analogue of the reference's fuse_adam_op_pass
-    (``framework/ir/fuse_optimizer_ops_pass/``).  Grouping key: identical
-    hyperparameter attrs + the same LearningRate input, so every member's
-    bias correction and scale match.  Row-sharded (``_is_distributed``)
-    tables stay unfused: concatenating a sharded table with replicated
-    params would force XLA to re-gather it.  Enable with
-    PADDLE_TPU_FUSE_ADAM=1.
-
-    DEFAULT OFF (r04): XLA's cost model convicts the fusion — the
-    BERT-base bs64 train step reads/writes 145GB unfused vs 664GB fused
-    (concat + per-param scatter-back makes every member update touch
-    the whole flat stream), and the r04 flagship hardware capture
-    regressed MFU 0.42→0.30 with it on.  XLA already fuses each
-    per-param adam update into one elementwise kernel; the concat buys
-    fewer launches but pays O(n_params × stream) traffic.
-
-    The fused op also streams Param/Grad/moments through flat fp32
-    copies, so one group transiently holds ~4 extra fp32 model copies
-    in HBM.  PADDLE_TPU_FUSE_ADAM_MAX_ELEMS (default 2**27 elems =
-    512MB per fp32 stream) caps a group's total elements."""
-    import os
-
-    if os.environ.get("PADDLE_TPU_FUSE_ADAM", "0") != "1":
-        return list(ops)
-    max_elems = int(os.environ.get("PADDLE_TPU_FUSE_ADAM_MAX_ELEMS",
-                                   str(2 ** 27)))
-
-    def n_elems(op):
-        var = block._find_var_recursive(op.inputs["Param"][0])
-        if var is None or not var.shape:
-            return 1
-        n = 1
-        for d in var.shape:
-            n *= max(int(d), 1)
-        return n
-
-    def fusible_key(op):
-        if op.type != "adam":
-            return None
-        var = block._find_var_recursive(op.inputs["Param"][0])
-        # non-replicated params stay unfused: concatenating a row-sharded
-        # table or a tensor-parallel weight with replicated params would
-        # force a re-gather and break the param's sharding round-trip
-        if var is not None and (getattr(var, "_is_distributed", False)
-                                or getattr(var, "shard_spec", None)):
-            return None
-        return (
-            op.attrs.get("beta1", 0.9), op.attrs.get("beta2", 0.999),
-            op.attrs.get("epsilon", 1e-8),
-            tuple(op.inputs.get("LearningRate", [])),
-        )
-
-    def emit(run, out):
-        if len(run) == 1:
-            out.append(run[0])
-            return
-        ins = {"LearningRate": list(run[0].inputs["LearningRate"])}
-        outs = {}
-        for slot in ("Param", "Grad", "Moment1", "Moment2",
-                     "Beta1Pow", "Beta2Pow"):
-            ins[slot] = [m.inputs[slot][0] for m in run]
-        for slot in ("ParamOut", "Moment1Out", "Moment2Out",
-                     "Beta1PowOut", "Beta2PowOut"):
-            outs[slot] = [m.outputs[slot][0] for m in run]
-        out.append(_FusedOp("fused_adam", ins, outs, dict(run[0].attrs)))
-
-    # only CONSECUTIVE same-key adam ops fuse: an op interleaved between
-    # members (per-param grad clip, a scale) may write a member's Grad
-    # or read a ParamOut, and hoisting across it would reorder those
-    # dependencies.  Our own optimizer emits the run contiguously, so
-    # the common case fuses fully; odd deserialized layouts degrade to
-    # smaller groups, never to wrong code.
-    out = []
-    run, run_key, run_elems = [], None, 0
-    for op in ops:
-        key = fusible_key(op)
-        if (key is not None and key == run_key
-                and run_elems + n_elems(op) <= max_elems):
-            run.append(op)
-            run_elems += n_elems(op)
-            continue
-        if run:
-            emit(run, out)
-        if key is None:
-            out.append(op)
-            run, run_key, run_elems = [], None, 0
-        else:
-            run, run_key, run_elems = [op], key, n_elems(op)
-    if run:
-        emit(run, out)
-    return out
-
-
 def _probe_trip_counts(block, feed_vals, scope, fetch_names):
     """Pass 1 of unbounded-while gradients (while_op.cc:189 parity):
     eagerly run the block's forward prefix on the concrete feed/scope
@@ -626,10 +512,6 @@ class _CompiledBlock:
         # the op list here is safe.)
         _top_ops = [op for op in block.ops
                     if op.type not in _HOST_SIDE_OPS]
-        if not self.shard_opt_state:
-            # (concatenating data-axis-sharded moments would force XLA
-            # to re-gather them, defeating the ZeRO-1 partition)
-            _top_ops = _fuse_adam_ops(_top_ops, block)
 
         def step_once(feeds, rw, ro, key):
             """One whole train/infer step — shared by the plain path and
@@ -829,10 +711,6 @@ class _AccumRunner:
         self.mode = mode
         (self.head, self.tail, self.head_written, self.grad_reads,
          self.other_reads) = _accum_partition(block)
-        if not cb.shard_opt_state:
-            # same guard as the non-accum path: fusing would concatenate
-            # (re-gather) ZeRO-1-sharded moments every step
-            self.tail = _fuse_adam_ops(self.tail, block)
         # head-written values the caller needs: fetches + persistables
         carry_out = list(self.other_reads)
         for n in cb.fetch_names + cb.rw_names + cb.fresh_persist:

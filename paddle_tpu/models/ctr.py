@@ -82,11 +82,9 @@ def deepfm(slots, label, vocab=100000, embed_dim=16, hidden=(400, 400),
     with their own sparse-SGD lr, like the reference pserver's separate
     optimizer blocks).  When the tables FIT device memory, leave
     ``use_host_table=False``: the lookups are then in-graph
-    ``lookup_table`` ops the fusion pipeline dispatches to the Pallas
-    row-DMA gather kernel (``fused_embedding_gather``, lane-aligned
-    dims), and ``is_distributed=True`` row-shards each table over the
-    mesh — the device-side migration of the reference's distributed
-    lookup_table (see MIGRATION.md)."""
+    ``lookup_table`` ops (XLA's gather), and ``is_distributed=True``
+    row-shards each table over the mesh — the device-side migration of
+    the reference's distributed lookup_table (see MIGRATION.md)."""
     embs = []     # [B, dim] per slot (slot-summed)
     firsts = []   # [B, 1] per slot
     for i, s in enumerate(slots):
@@ -170,40 +168,6 @@ def build(model="wide_deep", num_slots=8, slot_len=4, dense_dim=13,
                                 host_lr=host_lr)
         fluid.optimizer.Adam(learning_rate=lr).minimize(loss)
     return main, startup, feeds, loss, prob
-
-
-def run_deepfm_device_table_steps(steps=5, num_slots=4, slot_len=3,
-                                  vocab=100000, batch=16, embed_dim=128,
-                                  seed=8):
-    """Device-table twin of :func:`run_deepfm_host_table_steps`: the
-    same DeepFM with the embedding tables as in-graph device parameters
-    (lane-aligned dim so the fusion pipeline dispatches the lookups to
-    the Pallas gather kernel).  Returns (per-step losses, FusionReport)
-    so tests/benches can assert the ``fused_embedding_gather`` sites
-    actually fired on the path that ran."""
-    import numpy as np
-
-    from ..executor import Scope, scope_guard
-    from ..static_analysis import fusion
-
-    fluid.unique_name.switch()
-    main, startup, feeds, loss, prob = build(
-        model="deepfm", num_slots=num_slots, slot_len=slot_len,
-        vocab=vocab, embed_dim=embed_dim, use_host_table=False)
-    _, report = fusion.resolve_fused_program(main, targets=[loss.name])
-    rng = np.random.RandomState(seed)
-    feed = {"slot_%d" % i:
-            rng.randint(0, vocab, (batch, slot_len)).astype("int64")
-            for i in range(num_slots)}
-    feed["label"] = rng.randint(0, 2, (batch, 1)).astype("int64")
-    exe = fluid.Executor(fluid.TPUPlace())
-    losses = []
-    with scope_guard(Scope()):
-        exe.run(startup)
-        for _ in range(steps):
-            (lv,) = exe.run(main, feed=feed, fetch_list=[loss])
-            losses.append(float(np.asarray(lv).reshape(())))
-    return losses, report
 
 
 def run_deepfm_host_table_steps(steps=5, data_parallel=False, places=None,

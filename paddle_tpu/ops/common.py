@@ -49,7 +49,7 @@ def normalize_axis(axis, ndim):
 
 
 def flatten_concat(xs, dtype=None):
-    """Pack a list of arrays into one flat stream (the multi-tensor /
+    """Pack a list of arrays into one flat stream (the
     bucketed-collective layout), optionally casting each segment."""
     return jnp.concatenate([
         x.reshape(-1).astype(dtype) if dtype is not None else x.reshape(-1)

@@ -845,19 +845,6 @@ def fused_conv_bn_act(ctx, attrs, Input, Filter, Scale, Bias, Mean,
     }
 
 
-@register_op("fused_embedding_gather", inputs=["W", "Ids"],
-             outputs=["Out"])
-def fused_embedding_gather(ctx, attrs, W, Ids):
-    """Embedding lookup dispatched to the Pallas row-DMA gather kernel
-    on TPU (ops/pallas/embedding.py; XLA take elsewhere) — the device-
-    side form of the reference's distributed lookup_table prefetch.
-    Semantics (clamping, padding_idx, scatter-add grad) are identical
-    to ``lookup_table``, so the fusion rewrite is value-preserving."""
-    from .pallas.embedding import embedding_gather
-
-    return embedding_gather(W, Ids, attrs.get("padding_idx", -1))
-
-
 @register_op("selu", inputs=["X"], outputs=["Out"])
 def selu(ctx, attrs, X):
     """scale * (max(0,x) + min(0, alpha*(exp(x)-1))) (selu_op.cc)."""

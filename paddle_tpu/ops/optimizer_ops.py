@@ -10,7 +10,6 @@ graph passes (``ir/fuse_optimizer_ops_pass/``), which XLA fusion subsumes.
 """
 
 import jax.numpy as jnp
-import numpy as np
 
 from .registry import register_op
 
@@ -342,94 +341,3 @@ def proximal_adagrad(ctx, attrs, Param, Moment, Grad, LearningRate):
     prox = Param - (lr / jnp.sqrt(m)) * Grad
     shrink = jnp.maximum(jnp.abs(prox) - lr * l1, 0.0)
     return jnp.sign(prox) * shrink / (1.0 + lr * l2), m
-
-
-@register_op(
-    "fused_sgd",
-    inputs=["Param*", "Grad*", "LearningRate"],
-    outputs=["ParamOut*"],
-    no_grad=True,
-)
-def fused_sgd(ctx, attrs, Param, Grad, LearningRate):
-    """Multi-tensor SGD: all same-(dtype, lr) param updates of a step as
-    one flat stream (the sgd face of Fluid's fuse_optimizer_ops_pass;
-    the fusion pipeline groups per dtype so the stream stays uniform).
-    Bit-exact vs the per-param op: concatenation does not change the
-    elementwise ``p - lr*g`` each segment computes."""
-    from .common import flatten_concat, split_like
-
-    dtype = Param[0].dtype
-    lr = _lr(LearningRate, dtype)
-    new = flatten_concat(Param) - lr * flatten_concat(Grad, dtype)
-    return {"ParamOut": split_like(new, Param, cast=False)}
-
-
-@register_op(
-    "fused_adam",
-    inputs=["Param*", "Grad*", "LearningRate", "Moment1*", "Moment2*",
-            "Beta1Pow*", "Beta2Pow*"],
-    outputs=["ParamOut*", "Moment1Out*", "Moment2Out*", "Beta1PowOut*",
-             "Beta2PowOut*"],
-    no_grad=True,
-)
-def fused_adam(ctx, attrs, Param, Grad, LearningRate, Moment1, Moment2,
-               Beta1Pow, Beta2Pow):
-    """All per-param Adam updates of a step in ONE streamed kernel.
-
-    The executor rewrites groups of same-hyperparameter ``adam`` ops into
-    this op (reference precedent: the
-    ``fuse_optimizer_ops_pass`` ir pass,
-    ``framework/ir/fuse_optimizer_ops_pass/fuse_adam_op_pass.cc``, which
-    coalesces per-param Adam kernels into one).  On TPU the win is
-    bandwidth scheduling: N small elementwise fusions (~185 for
-    BERT-base, each paying ramp-up on a few-KB..few-MB tensor) become a
-    single flat ~7-bytes/param stream that runs at HBM line rate.
-
-    Math is bit-identical to the per-param op: everything is flattened
-    and concatenated in fp32, updated once, and split back; the beta-pow
-    scalars stay per-param (cheap) so each param's bias correction reads
-    ITS OWN accumulator exactly as before — though the rewrite only
-    groups params whose beta pows are in lockstep anyway."""
-    from .common import flatten_concat, split_like
-
-    beta1 = attrs.get("beta1", 0.9)
-    beta2 = attrs.get("beta2", 0.999)
-    eps = attrs.get("epsilon", 1e-8)
-    lr = _lr(LearningRate, jnp.float32)
-    shapes = [p.shape for p in Param]
-    sizes = [int(np.prod(s)) if s else 1 for s in shapes]
-
-    p, g, m1, m2 = (flatten_concat(xs, jnp.float32)
-                    for xs in (Param, Grad, Moment1, Moment2))
-    # bias correction stays PER PARAM: each member's own beta-pow drives
-    # its lr_t (a checkpoint-resumed model can hold accumulators at
-    # different steps, e.g. a freshly added layer), broadcast to its
-    # segment of the flat stream
-    lr_ts = jnp.stack([
-        lr * jnp.sqrt(1 - b2.reshape(()).astype(jnp.float32))
-        / (1 - b1.reshape(()).astype(jnp.float32))
-        for b1, b2 in zip(Beta1Pow, Beta2Pow)
-    ])
-    # the segment map is STATIC — concat of scalar broadcasts instead of
-    # jnp.repeat: repeat's cumsum lowering XLA constant-folds for seconds
-    # on every compile (flat-stream-sized scan), and an index-gather
-    # alternative would bake a stream-sized int32 constant into HBM;
-    # broadcasts fuse to nothing
-    lr_t = jnp.concatenate([
-        jnp.broadcast_to(lr_ts[i], (n,)) for i, n in enumerate(sizes)
-    ])
-    m1n = beta1 * m1 + (1 - beta1) * g
-    m2n = beta2 * m2 + (1 - beta2) * jnp.square(g)
-    pn = p - lr_t * m1n / (jnp.sqrt(m2n) + eps)
-
-    return {
-        "ParamOut": split_like(pn, Param),
-        "Moment1Out": split_like(m1n, Moment1),
-        "Moment2Out": split_like(m2n, Moment2),
-        "Beta1PowOut": [
-            (b.reshape(()).astype(jnp.float32) * beta1)
-            .reshape(b.shape).astype(b.dtype) for b in Beta1Pow],
-        "Beta2PowOut": [
-            (b.reshape(()).astype(jnp.float32) * beta2)
-            .reshape(b.shape).astype(b.dtype) for b in Beta2Pow],
-    }

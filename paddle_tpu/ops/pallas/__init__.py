@@ -102,4 +102,3 @@ def pallas_kernels_in(hlo_text):
 from . import flash_attention  # noqa: E402,F401
 from . import flash_decode  # noqa: E402,F401
 from . import conv_bn_act  # noqa: E402,F401
-from . import embedding  # noqa: E402,F401

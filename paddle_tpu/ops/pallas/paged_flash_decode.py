@@ -4,8 +4,8 @@ block-table-indirect KV pool.
 The paged cache is ``[num_blocks, H, block_len, Dh]`` — a request's K/V
 rows live in the (non-contiguous) blocks its table names, so the ring
 kernel's contiguous ``[BH, Tmax, D]`` streaming BlockSpec cannot see
-them.  The indirection is the embedding kernel's scalar-prefetch row-DMA
-idiom (``ops/pallas/embedding.py``): the flattened per-(sequence, head)
+them.  The indirection is Pallas's scalar-prefetch row-DMA idiom
+(``pltpu.PrefetchScalarGridSpec``): the flattened per-(sequence, head)
 block table rides in as a scalar-prefetch argument, the grid is
 ``(rows, max_blocks)``, and the K/V BlockSpec index maps read
 ``table[row, j]`` to DMA exactly the j-th OWNED block HBM→VMEM — blocks

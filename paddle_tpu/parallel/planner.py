@@ -1210,8 +1210,7 @@ def _overlap_windows(worker, cand, cluster, nranks, targets,
         tkey = tuple(targets or ())
         cfg = FusionConfig(enabled=True, fuse_attention=False,
                            fuse_elewise=False, fuse_softmax_xent=False,
-                           fuse_optimizer=False, fuse_conv_bn_act=False,
-                           fuse_embedding_gather=False)
+                           fuse_conv_bn_act=False)
         apply_fusion_passes(clone, cfg, targets=tkey)
         if getattr(cand, "hier", False):
             # a hier+overlap twin's windows come from the DECOMPOSED
@@ -1443,8 +1442,7 @@ def _hier_proof_twin(worker, cand, cluster):
                                                      cand.degree)
         cfg = FusionConfig(enabled=True, fuse_attention=False,
                            fuse_elewise=False, fuse_softmax_xent=False,
-                           fuse_optimizer=False, fuse_conv_bn_act=False,
-                           fuse_embedding_gather=False)
+                           fuse_conv_bn_act=False)
         apply_fusion_passes(clone, cfg, targets=())
         if not apply_hierarchy_pass(clone, nranks=cand.degree):
             return None

@@ -19,7 +19,7 @@ in-flight step's write/donate is a hazard.  Three analyses fall out:
     A persistable scope var that is both *written* by the step and
     *fetched* races under ``max_in_flight>1``: step N donates the very
     buffer step N-1's un-materialized handle still reads.  When the
-    writer is an in-place/aliasing op (a fused multi-tensor optimizer's
+    writer is an in-place/aliasing op (an optimizer op's
     ``Param -> ParamOut``, an in-place collective), the fetched handle
     aliases the donated buffer directly — ``donated-buffer-live-read``.
     A program that overwrites one of its own fed data vars is the

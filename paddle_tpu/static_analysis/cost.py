@@ -257,21 +257,6 @@ def _fused_conv_bn_act_flops(op, ins, outs):
     return conv + 8 * epilogue
 
 
-@register_flops("fused_embedding_gather")
-def _fused_embedding_gather_flops(op, ins, outs):
-    return _out_numel(outs)  # a gather moves bytes, not FLOPs
-
-
-@register_flops("fused_adam")
-def _fused_adam_flops(op, ins, outs):
-    return 4 * _out_numel(outs)  # ~12 FLOPs per param over 3 out streams
-
-
-@register_flops("fused_sgd")
-def _fused_sgd_flops(op, ins, outs):
-    return 2 * _out_numel(outs)
-
-
 for _t in ("mean", "reduce_mean", "reduce_sum", "reduce_max",
            "reduce_min", "reduce_prod", "sum"):
     register_flops(_t)(

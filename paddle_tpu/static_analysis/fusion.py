@@ -1,8 +1,9 @@
 """Cost-guided Program-IR fusion pass pipeline — the TPU-native
 realization of Fluid's ``BuildStrategy.fuse_*`` graph passes
-(``fuse_elewise_add_act_pass``, ``framework/ir/fuse_optimizer_ops_pass``,
-``fuse_all_reduce_op_pass``) plus the attention/softmax-xent fusions the
-reference keeps as hand-written ``operators/fused/`` kernels.
+(``fuse_elewise_add_act_pass``, ``fuse_all_reduce_op_pass``; XLA's own
+per-parameter fusion stands in for ``fuse_optimizer_ops_pass``) plus the
+attention/softmax-xent fusions the reference keeps as hand-written
+``operators/fused/`` kernels.
 
 XLA fuses instruction-level chains on its own, but it demonstrably
 leaves two classes of rewrite on the table (Operator Fusion in XLA,
@@ -41,19 +42,6 @@ family                    rewrite
                           VMEM pass — the ResNet-50 MFU 0.250-vs-0.381
                           gap); gated by predicted HBM savings x the
                           autotune calibration factor
-``embedding_gather``      ``lookup_table``/``embedding`` on a device-
-                          resident table ⇒ ``fused_embedding_gather``
-                          (Pallas scalar-prefetch row-DMA gather;
-                          scatter-add backward) — value-preserving
-                          kernel dispatch, gated on lane alignment +
-                          slab size x calibration
-``optimizer``             N per-param ``adam``/``sgd`` ops ⇒ one
-                          ``fused_adam``/``fused_sgd`` multi-tensor update
-                          per (hyperparams, lr, dtype) group — gated by a
-                          flat-stream traffic model (the r04 hardware A/B:
-                          concat+split costs ~3x the update's own bytes,
-                          so BERT-scale groups are *rejected* while
-                          many-small-param models fuse)
 ``allreduce``             per-grad ``c_allreduce_sum`` ⇒ size-capped
                           ``c_fused_allreduce_sum`` buckets
                           (``PADDLE_TPU_ALLREDUCE_BUCKET_MB``), keeping
@@ -90,7 +78,7 @@ __all__ = [
     "FusionConfig", "FusionRewrite", "FusionSkip", "FusionReport",
     "fusion_enabled", "allreduce_bucket_mb", "apply_fusion_passes",
     "resolve_fused_program", "scan_fusible_patterns",
-    "conv_bn_min_bytes", "embed_fuse_min_bytes",
+    "conv_bn_min_bytes",
     "FUSED_FORWARD_OP_TYPES",
 ]
 
@@ -99,7 +87,7 @@ __all__ = [
 FUSED_FORWARD_OP_TYPES = frozenset((
     "fused_multihead_attention", "fused_dropout_add_ln",
     "fused_bias_act", "softmax_with_cross_entropy",
-    "fused_conv_bn_act", "fused_embedding_gather",
+    "fused_conv_bn_act",
     # decode family: emitted by layers.decode_loop/flash_decode, never
     # by a rewrite here — listed so the matchers and the
     # fused-op-missing-grad lint treat it as an already-fused kernel
@@ -157,26 +145,6 @@ def conv_bn_min_bytes():
         return 4096
 
 
-# No slab is big enough by default.  On a TPU v5e (chip run, PR 22) the
-# Pallas gather took 2.2-5.7x XLA's own gather at every table measured
-# (30522x768, 512x768, 2x768 in f32 and bf16; 1000003x128 f32) and cost the
-# BERT-base seq128 step 1.0%: it moves whole 8-row tiles per id and pays a
-# grid step per 8 ids.  The env gate (or a sweep's calibration factor)
-# re-decides this for a kernel that earns it.
-EMBED_FUSE_NEVER = 1 << 62
-
-
-def embed_fuse_min_bytes():
-    """Minimum gathered-slab bytes for the embedding-gather rewrite
-    (``PADDLE_TPU_EMBED_FUSE_MIN_BYTES``, default: never — see
-    ``EMBED_FUSE_NEVER``)."""
-    try:
-        return int(os.environ.get("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", "")
-                   or EMBED_FUSE_NEVER)
-    except ValueError:
-        return EMBED_FUSE_NEVER
-
-
 def _autotune_state():
     """The autotune-cache state token — part of the fusion signature so
     an in-process sweep invalidates resolved program clones whose gates
@@ -225,56 +193,22 @@ def allreduce_bucket_mb(program=None):
         return 32.0
 
 
-def optimizer_fuse_overhead_bytes():
-    """Per-op overhead the multi-tensor optimizer fusion is credited
-    with removing, expressed as HBM-bytes-equivalent (a separate small
-    elementwise kernel pays launch + ramp that the cost model prices at
-    this many streamed bytes).  ``PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES``
-    overrides; the default is backend-aware — 8 MiB (~8 µs at v5e HBM
-    rate) on TPU, 256 KiB on CPU where XLA has no per-kernel ramp to
-    amortize (a CPU A/B of the mnist MLP measured the concat/split
-    rewrite 1.7x SLOWER, the same shape as the r04 BERT-base hardware
-    regression the gate exists to prevent)."""
-    val = os.environ.get("PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES", "").strip()
-    if val:
-        try:
-            return int(val)
-        except ValueError:
-            pass
-    global _BACKEND_DEFAULT_OVERHEAD
-    if _BACKEND_DEFAULT_OVERHEAD is None:
-        # backend identity is fixed for the process; signature() calls
-        # this on the dispatch hot path
-        from ..ops.pallas import device_platform
-
-        _BACKEND_DEFAULT_OVERHEAD = (
-            (8 << 20) if device_platform() == "tpu" else (256 << 10))
-    return _BACKEND_DEFAULT_OVERHEAD
-
-
-_BACKEND_DEFAULT_OVERHEAD = None
-
-
 class FusionConfig:
     """Which families run — resolved from ``BuildStrategy`` flags (the
     reference's knobs) + the env kill switch."""
 
     __slots__ = ("enabled", "fuse_attention", "fuse_elewise",
-                 "fuse_softmax_xent", "fuse_optimizer", "fuse_allreduce",
-                 "fuse_conv_bn_act", "fuse_embedding_gather")
+                 "fuse_softmax_xent", "fuse_allreduce", "fuse_conv_bn_act")
 
     def __init__(self, enabled=None, fuse_attention=True, fuse_elewise=True,
-                 fuse_softmax_xent=True, fuse_optimizer=True,
-                 fuse_allreduce=True, fuse_conv_bn_act=True,
-                 fuse_embedding_gather=True):
+                 fuse_softmax_xent=True, fuse_allreduce=True,
+                 fuse_conv_bn_act=True):
         self.enabled = fusion_enabled() if enabled is None else bool(enabled)
         self.fuse_attention = bool(fuse_attention)
         self.fuse_elewise = bool(fuse_elewise)
         self.fuse_softmax_xent = bool(fuse_softmax_xent)
-        self.fuse_optimizer = bool(fuse_optimizer)
         self.fuse_allreduce = bool(fuse_allreduce)
         self.fuse_conv_bn_act = bool(fuse_conv_bn_act)
-        self.fuse_embedding_gather = bool(fuse_embedding_gather)
 
     @classmethod
     def default(cls):
@@ -286,17 +220,10 @@ class FusionConfig:
         if bs is None:
             return c
         c.fuse_elewise = bool(getattr(bs, "fuse_elewise_add_act_ops", True))
-        # ZeRO-1 shards the moments over the data axis: the flat-stream
-        # concat would re-gather them every step, defeating the partition
-        c.fuse_optimizer = (
-            bool(getattr(bs, "fuse_all_optimizer_ops", True))
-            and not getattr(bs, "shard_optimizer_state", False))
         c.fuse_allreduce = bool(getattr(bs, "fuse_all_reduce_ops", True))
         c.fuse_attention = bool(getattr(bs, "fuse_attention", True))
         c.fuse_softmax_xent = bool(getattr(bs, "fuse_softmax_xent", True))
         c.fuse_conv_bn_act = bool(getattr(bs, "fuse_bn_act_ops", True))
-        c.fuse_embedding_gather = bool(
-            getattr(bs, "fuse_embedding_gather", True))
         return c
 
     def signature(self, program=None):
@@ -317,11 +244,9 @@ class FusionConfig:
         from .overlap import overlap_enabled as _ov
 
         return (self.enabled, self.fuse_attention, self.fuse_elewise,
-                self.fuse_softmax_xent, self.fuse_optimizer,
-                self.fuse_allreduce, self.fuse_conv_bn_act,
-                self.fuse_embedding_gather, allreduce_bucket_mb(program),
-                optimizer_fuse_overhead_bytes(), _flash_min_t(),
-                conv_bn_min_bytes(), embed_fuse_min_bytes(),
+                self.fuse_softmax_xent, self.fuse_allreduce,
+                self.fuse_conv_bn_act, allreduce_bucket_mb(program),
+                _flash_min_t(), conv_bn_min_bytes(),
                 _qmb(program), _qb(), _ov(program), _hier(program),
                 _autotune_state())
 
@@ -577,20 +502,21 @@ def _grad_attrs(fwd_op, extra=None):
     return attrs
 
 
-def _numel(shape, batch=1):
+def _numel(shape):
     if shape is None:
         return None
     n = 1
     for d in shape:
-        n *= batch if (d is None or int(d) < 0) else max(int(d), 1)
+        if d is not None and int(d) > 0:    # a dynamic dim counts as 1
+            n *= int(d)
     return n
 
 
-def _var_bytes(view, name, batch=1):
+def _var_bytes(view, name):
     v = view.var(name)
     if v is None or v.shape is None:
         return 0
-    return (_numel(v.shape, batch) or 0) * dtype_bytes(v.dtype)
+    return (_numel(v.shape) or 0) * dtype_bytes(v.dtype)
 
 
 def _flash_min_t():
@@ -1424,221 +1350,6 @@ def _find_conv_bn_act(view, report, dry_run=False):
 
 
 # ---------------------------------------------------------------------------
-# family: embedding gather  (device-side lookup_table)
-# ---------------------------------------------------------------------------
-
-_LOOKUP_OP_TYPES = ("lookup_table", "lookup_table_v2", "embedding",
-                    "lookup_sparse_table")
-
-
-def _find_embedding_gather(view, report, dry_run=False):
-    """lookup_table/embedding on a device-resident table ⇒
-    ``fused_embedding_gather`` (the Pallas row-DMA gather kernel on
-    TPU).  A 1:1 op-identity rewrite — semantics are value-preserving
-    (ops/pallas/embedding.py) — so the gate is purely about whether the
-    kernel can win: lane-aligned dim, slab big enough, calibration."""
-    block = view.block
-    for i, op in enumerate(block.ops):
-        if op.type not in _LOOKUP_OP_TYPES or _is_grad_op(op):
-            continue
-        w = op.inputs.get("W", [None])[0]
-        wv = view.var(w) if w else None
-        if wv is None or not wv.persistable or wv.shape is None \
-                or len(wv.shape) != 2:
-            continue
-        rows, dim = wv.shape
-        if not all(isinstance(d, int) and d > 0 for d in (rows, dim)):
-            continue
-        out = op.outputs["Out"][0]
-        t = view.twin(op, op.type + "_grad")
-        if t is False:
-            continue
-        if dim % 128:
-            report.skip(
-                "embedding_gather", i, op.type,
-                "table dim %d is not lane-aligned (128) — the Pallas "
-                "row-DMA gather is ineligible and XLA's take is already "
-                "optimal for this shape" % dim,
-                key=op.attrs.get("__op_id__"))
-            continue
-        # the slab scales with the batch: resolve the dynamic batch dim
-        # at a nominal 8 (batch=1 would gate out every per-example slab
-        # whose real deployment batch is in the thousands)
-        slab_bytes = _var_bytes(view, out, batch=8)
-        factor, sig, calibrated = _calibration(
-            "embedding_gather", rows=rows, dim=dim,
-            dtype=str(wv.dtype))
-        threshold = embed_fuse_min_bytes()
-        if slab_bytes * factor < threshold:
-            report.skip(
-                "embedding_gather", i, op.type,
-                "cost model: gathered slab is ~%d B, below the %d B "
-                "gate (calibration x%.2f%s)" % (
-                    int(slab_bytes * factor), threshold, factor,
-                    "" if calibrated else
-                    " — uncalibrated: no autotune cache entry for %r "
-                    "yet; a silicon sweep (paddle_tpu.autotune.sweep) "
-                    "re-decides this gate" % sig),
-                key=op.attrs.get("__op_id__"))
-            continue
-        fattrs = {k: v for k, v in op.attrs.items()
-                  if not k.startswith("__") and k != "op_namescope"}
-        fused = _new_op(None if dry_run else block,
-                        "fused_embedding_gather",
-                        {"W": list(op.inputs["W"]),
-                         "Ids": list(op.inputs["Ids"])},
-                        {"Out": [out]}, fattrs)
-        replacements = {i: fused}
-        removals = set()
-        op_idxs = [i]
-        if t is not None:
-            g_ins = {"W": list(op.inputs["W"]),
-                     "Ids": list(op.inputs["Ids"]),
-                     "Out": [out],
-                     "Out@GRAD": list(t[1].inputs.get(
-                         "Out@GRAD", [EMPTY_VAR_NAME]))}
-            g_outs = {"W@GRAD": [_grad_out(t[1], "W@GRAD")]}
-            gfused = _new_op(None if dry_run else block,
-                             "fused_embedding_gather_grad", g_ins,
-                             g_outs, _grad_attrs(fused))
-            replacements[t[0]] = gfused
-            op_idxs.append(t[0])
-        predicted = {
-            "device_gather_bytes": slab_bytes,
-            "calibration": factor,
-            "ops_removed": 0,
-        }
-        rewrite = FusionRewrite(
-            "embedding_gather", "fused_embedding_gather", block.idx,
-            sorted(op_idxs), vars=(w,), predicted=predicted,
-            note="value-preserving kernel dispatch (V=%d, D=%d)%s"
-                 % (rows, dim,
-                    "" if calibrated else " (uncalibrated gate)"),
-            inserted=len(replacements))
-        match = {"replacements": replacements, "removals": removals,
-                 "rewrite": rewrite}
-        if dry_run:
-            report.record(rewrite)
-            continue
-        return match
-    return None
-
-
-# ---------------------------------------------------------------------------
-# family: multi-tensor optimizer update  (fuse_all_optimizer_ops)
-# ---------------------------------------------------------------------------
-
-_OPT_SLOTS = {
-    "adam": (("Param", "Grad", "Moment1", "Moment2", "Beta1Pow",
-              "Beta2Pow"),
-             ("ParamOut", "Moment1Out", "Moment2Out", "Beta1PowOut",
-              "Beta2PowOut")),
-    "sgd": (("Param", "Grad"), ("ParamOut",)),
-}
-
-
-def _opt_key(view, op):
-    if op.type not in _OPT_SLOTS or _is_grad_op(op):
-        return None
-    pname = op.inputs.get("Param", [None])[0]
-    pv = view.var(pname) if pname else None
-    if pv is None or pv.shape is None:
-        return None
-    # row-sharded tables / TP-sharded weights stay unfused: the concat
-    # would force XLA to re-gather them (same guard as _fuse_adam_ops)
-    if getattr(pv, "_is_distributed", False) \
-            or getattr(pv, "shard_spec", None):
-        return None
-    gname = op.inputs.get("Grad", [None])[0]
-    gv = view.var(gname) if gname else None
-    key = (op.type, str(pv.dtype),
-           str(gv.dtype) if gv is not None else str(pv.dtype),
-           tuple(op.inputs.get("LearningRate", [])))
-    if op.type == "adam":
-        key += (op.attrs.get("beta1", 0.9), op.attrs.get("beta2", 0.999),
-                op.attrs.get("epsilon", 1e-8))
-    return key
-
-
-def _find_optimizer(view, report, dry_run=False):
-    block = view.block
-    runs = []
-    cur, cur_key = [], None
-    for i, op in enumerate(block.ops):
-        key = _opt_key(view, op)
-        if key is not None and key == cur_key:
-            cur.append((i, op))
-            continue
-        if len(cur) >= 2:
-            runs.append((cur_key, cur))
-        cur, cur_key = ([(i, op)], key) if key is not None else ([], None)
-    if len(cur) >= 2:
-        runs.append((cur_key, cur))
-
-    matches = []
-    for key, members in runs:
-        if any(view.idx_of(o) != i for i, o in members):
-            continue
-        op_type = key[0]
-        dt_bytes = dtype_bytes(key[1])
-        total = sum(
-            (_numel(view.var(o.inputs["Param"][0]).shape) or 0)
-            for _, o in members)
-        # cost gate (the r04 hardware A/B, BENCH_r04): the flat-stream
-        # concat+split reads and writes every member through fp32
-        # copies, so the fused op pays ~(n_in + n_out) extra stream
-        # round-trips on top of the update's own bytes.  Benefit: each
-        # member no longer pays a separate kernel launch/ramp, priced
-        # at PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES of HBM-equivalent.
-        n_streams = 7 if op_type == "adam" else 3
-        extra_bytes = n_streams * total * max(dt_bytes, 4)
-        benefit = (len(members) - 1) * optimizer_fuse_overhead_bytes()
-        first_idx = members[0][0]
-        if benefit <= extra_bytes:
-            report.skip(
-                "optimizer", first_idx, op_type,
-                "cost model: flat-stream concat/split would add ~%d MB "
-                "of HBM traffic vs ~%d MB of launch savings for %d "
-                "params (the r04 A/B regressed MFU 0.42->0.30 fusing "
-                "BERT-scale groups)" % (
-                    extra_bytes >> 20, benefit >> 20, len(members)),
-                key=members[0][1].attrs.get("__op_id__"))
-            continue
-        in_slots, out_slots = _OPT_SLOTS[op_type]
-        ins = {"LearningRate": list(
-            members[0][1].inputs.get("LearningRate", []))}
-        for s in in_slots:
-            ins[s] = [o.inputs[s][0] for _, o in members]
-        outs = {s: [o.outputs[s][0] for _, o in members]
-                for s in out_slots}
-        attrs = {k: v for k, v in members[0][1].attrs.items()
-                 if not k.startswith("__")}
-        attrs["op_role"] = "optimize"
-        fused = _new_op(None if dry_run else block, "fused_" + op_type, ins, outs, attrs)
-        predicted = {
-            "ops_removed": len(members) - 1,
-            "hbm_bytes_added": extra_bytes,
-            "launch_bytes_saved": benefit,
-        }
-        rewrite = FusionRewrite(
-            "optimizer", "fused_" + op_type, block.idx,
-            [i for i, _ in members],
-            vars=tuple(ins["Param"]), predicted=predicted,
-            note="bit-exact multi-tensor update (%d params, %d elems)"
-                 % (len(members), total))
-        matches.append({
-            "replacements": {first_idx: fused},
-            "removals": {i for i, _ in members[1:]},
-            "rewrite": rewrite,
-        })
-    if dry_run:
-        for m in matches:
-            report.record(m["rewrite"])
-        return None
-    return matches[0] if matches else None
-
-
-# ---------------------------------------------------------------------------
 # family: bucketed gradient allreduce  (fuse_all_reduce_ops)
 # ---------------------------------------------------------------------------
 
@@ -1793,8 +1504,6 @@ _FAMILIES = (
     ("softmax_xent", "fuse_softmax_xent", _find_softmax_xent),
     ("dropout_add_ln", "fuse_elewise", _find_dropout_add_ln),
     ("bias_act", "fuse_elewise", _find_bias_act),
-    ("embedding_gather", "fuse_embedding_gather", _find_embedding_gather),
-    ("optimizer", "fuse_optimizer", _find_optimizer),
     ("allreduce", "fuse_allreduce", _find_allreduce),
 )
 
@@ -1877,8 +1586,9 @@ def _finding_signature(d):
     """Baseline-diff key for one ERROR finding.  Op indices are
     deliberately excluded so removing ops ahead of a pre-existing
     finding does not make it look new; race findings also drop the
-    message, which names the writing op's TYPE — rewriting ``sgd`` into
-    ``fused_sgd`` must not make a pre-existing race look introduced."""
+    message, which names the writing op's TYPE — rewriting ``batch_norm``
+    into ``fused_conv_bn_act`` must not make a pre-existing race look
+    introduced."""
     from .concurrency import RACE_CHECK_IDS
 
     if d.check in RACE_CHECK_IDS:

@@ -280,16 +280,6 @@ def _fused_conv_bn_transfer(op, in_vals, out_val):
     return _default_transfer(op, in_vals, out_val)
 
 
-@register_transfer("fused_embedding_gather")
-def _fused_embedding_transfer(op, in_vals, out_val):
-    # the gathered slab follows the ID stream's (batch) sharding; the
-    # table's row sharding does not shard the output (each worker
-    # resolves its batch's rows — GSPMD inserts the halo exchange)
-    if len(in_vals) > 1 and in_vals[1].sharding.is_sharded:
-        return in_vals[1].sharding
-    return Sharding.replicated()
-
-
 @register_transfer("c_reducescatter")
 def _reducescatter_transfer(op, in_vals, out_val):
     parts = max((v.sharding.parts for v in in_vals

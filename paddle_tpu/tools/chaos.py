@@ -177,29 +177,42 @@ def _run_worker(args):
     return 0
 
 
-def _oracle_digest(steps, skip_steps):
-    """Fault-free replay in-process, not applying the skipped steps —
-    the trajectory the recovered run must land on exactly."""
+def _oracle_digest(steps, skip_steps, spec):
+    """Fault-free replay in-process of the step the worker compiled, not
+    applying the skipped steps — the trajectory the recovered run must
+    land on exactly.  The guard's select and the value faults' gate feed
+    are part of that step, so the replay keeps both and lets nothing
+    fire: XLA's CPU backend does not round two different programs alike
+    to the last bit, and what the drill holds to the bit is recovery."""
     import warnings
+    from unittest import mock
 
     _force_cpu()
     import paddle_tpu as fluid
     from paddle_tpu.executor import Scope, scope_guard
     from paddle_tpu.resilience import faults
 
-    faults.set_fault_spec("")
-    main, startup, loss = _build_model()
-    exe = fluid.Executor(fluid.CPUPlace())
-    with scope_guard(Scope()):
-        exe.run(startup)
-        for k, (xb, yb) in enumerate(_batches(steps)):
-            if k in skip_steps:
-                continue
-            faults.set_step(k)
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")
-                exe.run(main, feed={"x": xb, "y": yb}, fetch_list=[loss])
-        return _param_digest(fluid.global_scope(), main)
+    inj = faults.set_fault_spec(spec)
+    inj.faults = inj.trace_faults
+    for f in inj.faults:
+        f.p = 0.0                       # its gate stays cold
+    try:
+        with mock.patch.dict(os.environ, {"PADDLE_TPU_NAN_GUARD": "1"}), \
+                scope_guard(Scope()):               # guarded, as the worker
+            main, startup, loss = _build_model()
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            for k, (xb, yb) in enumerate(_batches(steps)):
+                if k in skip_steps:
+                    continue
+                faults.set_step(k)
+                with warnings.catch_warnings():
+                    warnings.simplefilter("ignore")
+                    exe.run(main, feed={"x": xb, "y": yb},
+                            fetch_list=[loss])
+            return _param_digest(fluid.global_scope(), main)
+    finally:
+        faults.set_fault_spec("")
 
 
 # elastic drill: a constant GLOBAL batch sliced by membership index —
@@ -878,7 +891,7 @@ def _run_driver(args):
 
     _metrics.set_telemetry_enabled(False)
     try:
-        oracle = _oracle_digest(args.steps, skipped)
+        oracle = _oracle_digest(args.steps, skipped, args.spec)
     finally:
         _metrics.set_telemetry_enabled(None)
     if oracle != final_sha:

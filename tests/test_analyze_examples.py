@@ -80,7 +80,22 @@ def test_every_example_program_analyzes_clean(builder):
         assert report.ok, "\n".join(str(d) for d in report.errors)
 
 
-def test_example_cost_baselines_are_nonzero():
+@pytest.fixture
+def empty_autotune_cache(monkeypatch, tmp_path):
+    """A cache file of this test's own: the suite's per-process file
+    (conftest.py) keeps the calibration entries other test files record
+    (test_observability's drift factor, test_planner's planner factor),
+    and ``bench_json`` prints a line for them."""
+    from paddle_tpu import autotune
+
+    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
+                       str(tmp_path / "autotune.json"))
+    autotune.reset()
+    yield
+    autotune.reset()
+
+
+def test_example_cost_baselines_are_nonzero(empty_autotune_cache):
     """The BENCH-style static baseline a perf PR would cite: the mnist
     training program has real FLOP/byte totals and a peak estimate."""
     import mnist_train
@@ -171,17 +186,13 @@ def test_dist_worker_sets_concurrency_clean():
                 str(d) for d in report.concurrency.races)
 
 
-def test_fusion_families_fire_across_example_corpus(monkeypatch):
-    """The rewrite families all fire somewhere in the examples: mnist
-    carries bias_act + softmax_xent + optimizer, bert carries the
-    dropout_add_ln sites (and attention once T reaches the flash
-    threshold — exercised in test_fusion.py with the env override).
-    The optimizer gate gets the TPU-scale launch credit — the CPU
-    default refuses mnist-scale groups (measured slower there)."""
+def test_fusion_families_fire_across_example_corpus():
+    """The rewrite families fire somewhere in the examples: mnist
+    carries bias_act + softmax_xent, bert carries the dropout_add_ln
+    sites (and attention once T reaches the flash threshold —
+    exercised in test_fusion.py)."""
     from paddle_tpu.static_analysis import fusion
 
-    monkeypatch.setenv("PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES",
-                       str(8 << 20))
     seen = {}
     fluid.unique_name.switch()
     for build in (_mnist, _bert_tiny):
@@ -192,5 +203,4 @@ def test_fusion_families_fire_across_example_corpus(monkeypatch):
                 seen[fam] = seen.get(fam, 0) + n
     assert seen.get("bias_act", 0) >= 2
     assert seen.get("softmax_xent", 0) >= 1
-    assert seen.get("optimizer", 0) >= 1
     assert seen.get("dropout_add_ln", 0) >= 5

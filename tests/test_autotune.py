@@ -291,7 +291,8 @@ class TestCostModelExposure:
         from paddle_tpu.proto import save_program
 
         autotune.record(
-            autotune.signature("embedding_gather", rows=10, dim=128,
+            autotune.signature("conv_bn_act", shape=(1, 8, 16, 16),
+                               dtype="float32", act="relu",
                                backend="cpu"),
             {"params": {}, "calibration": 2.5})
         fluid.unique_name.switch()
@@ -318,4 +319,5 @@ class TestCostModelExposure:
         line = next(json.loads(l) for l in body.splitlines()
                     if "autotune_calibration_factors" in l)
         assert line["factors"][autotune.signature(
-            "embedding_gather", rows=10, dim=128, backend="cpu")] == 2.5
+            "conv_bn_act", shape=(1, 8, 16, 16), dtype="float32",
+            act="relu", backend="cpu")] == 2.5

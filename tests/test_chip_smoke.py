@@ -185,21 +185,6 @@ def test_autotune_cache_defaults_into_the_checkout(monkeypatch):
         os.path.join(REPO, ".autotune_cache") + os.sep)
 
 
-@pytest.mark.parametrize("rows,dim,dtype,want", [
-    (30522, 768, "float32", True),
-    (2, 768, "bfloat16", True),      # a table smaller than one tile
-    (1000003, 128, "float32", True),
-    (30522, 100, "float32", False),  # rows off the 128 lanes
-    (30522, 768, "int32", False),    # the row select is written for floats
-    (0, 768, "float32", False),
-])
-def test_gather_kernel_eligibility(monkeypatch, rows, dim, dtype, want):
-    from paddle_tpu.ops.pallas.embedding import gather_eligible
-
-    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
-    assert gather_eligible(rows, dim, dtype) is want
-
-
 @pytest.mark.parametrize("env,platform,impl", [
     (None, "cpu", "threefry2x32"),
     (None, "tpu", "rbg"),            # ran on the chip: chip_smoke's dropout
@@ -269,11 +254,11 @@ def test_kernel_names_read_from_compiled_hlo():
   %fused_ln_fwd.3 = (bf16[8,128]{1,0}) custom-call(%a), custom_call_target="tpu_custom_call", metadata={}
   %jvp_fused_ln_fwd_.7 = bf16[8,128]{1,0} custom-call(%b), custom_call_target="tpu_custom_call"
   %jvp_fused_ln_fwd_.9 = bf16[8,128]{1,0} custom-call(%c), custom_call_target="tpu_custom_call"
-  %embedding_gather = f32[8,128]{1,0} custom-call(%d), custom_call_target="tpu_custom_call"
+  %conv_bn_act_fwd = f32[8,128]{1,0} custom-call(%d), custom_call_target="tpu_custom_call"
   %other.1 = f32[8]{0} custom-call(%e), custom_call_target="Sharding"
 """
     assert pallas.pallas_kernels_in(text) == {
-        "fused_ln_fwd": 1, "jvp_fused_ln_fwd_": 2, "embedding_gather": 1}
+        "fused_ln_fwd": 1, "jvp_fused_ln_fwd_": 2, "conv_bn_act_fwd": 1}
 
 
 @pytest.fixture

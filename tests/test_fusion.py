@@ -6,6 +6,7 @@ switch + fusion_report introspection, and the two new lint checks."""
 
 import copy
 import os
+import sys
 
 import numpy as np
 import pytest
@@ -21,7 +22,7 @@ from paddle_tpu.transpiler.collective import GradAllReduce
 def build_mnist_mlp(act="relu", train=True, lr=1e-3, optimizer="adam",
                     width=24, in_dim=32):
     """fc(relu) x2 -> fc(softmax) -> cross_entropy: exercises the
-    bias_act, softmax_xent, and optimizer families."""
+    bias_act and softmax_xent families."""
     fluid.unique_name.switch()
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
@@ -49,6 +50,7 @@ def build_bert_tiny(seq_len=32, train=True, dropout=None):
 
     cfg = copy.copy(bert.BERT_TINY)
     cfg.fuse_attn = False
+    cfg.max_seq = max(cfg.max_seq, seq_len)
     if dropout is not None:
         cfg.dropout = dropout
         cfg.attn_dropout = dropout
@@ -81,35 +83,27 @@ def op_types(program):
 # pattern-match / rewrite goldens
 # ---------------------------------------------------------------------------
 
-OPT_FUSE_ON = ("PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES", str(8 << 20))
-
-
 class TestRewriteGoldens:
-    def test_mnist_mlp_families(self, monkeypatch):
-        # credit the TPU launch overhead so the optimizer gate passes
-        # (the CPU default refuses — see test_optimizer_gate_*)
-        monkeypatch.setenv(*OPT_FUSE_ON)
+    def test_mnist_mlp_families(self):
         main, startup, loss, acc, pred = build_mnist_mlp()
         fused, report = fusion.resolve_fused_program(
             main, targets=[loss.name, acc.name])
         counts = report.counts()
         assert counts.get("bias_act") == 2          # two relu fcs
         assert counts.get("softmax_xent") == 1
-        assert counts.get("optimizer") == 1         # one adam group
         types = op_types(fused)
         assert types.count("fused_bias_act") == 2
         assert types.count("fused_bias_act_grad") == 2
         assert types.count("softmax_with_cross_entropy") == 1
         assert types.count("softmax_with_cross_entropy_grad") == 1
-        assert types.count("fused_adam") == 1
-        assert types.count("adam") == 0
+        # each parameter keeps its own update op (XLA fuses each one)
+        assert types.count("adam") == op_types(main).count("adam") == 6
         # the rewritten program is strictly smaller and still verifies
         assert len(types) < len(op_types(main))
         verify_program(fused, targets=[loss.name, acc.name])
 
     def test_bert_tiny_all_families_fire(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_T", "16")
-        monkeypatch.setenv(*OPT_FUSE_ON)
         main, startup, feeds, loss, cfg = build_bert_tiny()
         fused, report = fusion.resolve_fused_program(
             main, targets=[loss.name])
@@ -118,7 +112,6 @@ class TestRewriteGoldens:
         # 2 sublayer closes per layer + the embedding add+LN
         assert counts.get("dropout_add_ln") == 2 * cfg.layers + 1
         assert counts.get("bias_act") == cfg.layers  # gelu ffn1 per layer
-        assert counts.get("optimizer") == 1
         types = op_types(fused)
         assert types.count("fused_multihead_attention") == 2
         assert types.count("fused_multihead_attention_grad") == 2
@@ -269,37 +262,6 @@ class TestCostGates:
         seen = {(s.family, s.block_idx, s.op_idx) for s in report.skipped}
         assert len(seen) == len(report.skipped)
 
-    def test_optimizer_gate_rejects_large_groups(self, monkeypatch):
-        """The r04 lesson encoded: a BERT-scale flat stream costs more
-        in concat/split traffic than it saves in launches."""
-        monkeypatch.setenv("PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES", "1024")
-        main, startup, loss, acc, pred = build_mnist_mlp()
-        fused, report = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report.counts().get("optimizer") is None
-        skips = [s for s in report.skipped if s.family == "optimizer"]
-        assert skips and "cost model" in skips[0].reason
-
-    def test_optimizer_gate_default_is_backend_aware(self, monkeypatch):
-        """On the CPU backend the default launch-overhead credit is
-        small enough that the real mnist-scale group (784->200->200->10,
-        ~200k params) is refused — the fused arm measured 1.7x SLOWER
-        there — while tiny groups still pass.  The TPU-scale credit
-        (env override here; automatic on a tpu backend) flips it."""
-        monkeypatch.delenv("PADDLE_TPU_FUSE_OPT_OVERHEAD_BYTES",
-                           raising=False)
-        main, startup, loss, acc, pred = build_mnist_mlp(
-            width=200, in_dim=784)
-        fused, report = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report.counts().get("optimizer") is None
-        skips = [s for s in report.skipped if s.family == "optimizer"]
-        assert skips and "cost model" in skips[0].reason
-        monkeypatch.setenv(*OPT_FUSE_ON)
-        fused2, report2 = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report2.counts().get("optimizer") == 1
-
     def test_attention_rank2_per_row_bias_stays_unfused(self, monkeypatch):
         """Regression: a rank-2 bias trailing-aligns to the (Tq,Tk)
         score dims under the unfused elementwise_add — a per-QUERY-ROW
@@ -381,11 +343,11 @@ class TestCostGates:
 # ---------------------------------------------------------------------------
 
 class TestNumerics:
-    def test_bias_act_and_optimizer_train_bit_exact(self, monkeypatch):
-        """Families documented bit-exact (bias_act composite, fused_sgd
-        multi-tensor): identical losses and identical final params.  The
-        model avoids the softmax-xent family so the whole program is in
-        the bit-exact class."""
+    def test_bias_act_train_bit_exact(self, monkeypatch):
+        """The family documented bit-exact (bias_act composite):
+        identical losses and identical final params.  The model avoids
+        the softmax-xent family so the whole program is in the bit-exact
+        class."""
         def build():
             fluid.unique_name.switch()
             main, startup = fluid.Program(), fluid.Program()
@@ -405,7 +367,6 @@ class TestNumerics:
         rng = np.random.RandomState(3)
         feed = {"img": rng.rand(8, 32).astype("float32"),
                 "label": rng.rand(8, 1).astype("float32")}
-        monkeypatch.setenv(*OPT_FUSE_ON)
         monkeypatch.setenv("PADDLE_TPU_FUSION", "0")
         m0, s0, loss0 = build()
         off, sc_off = run_steps(m0, s0, feed, [loss0.name])
@@ -414,7 +375,6 @@ class TestNumerics:
         # prove the rewrites actually fired on the fusion-on arm
         rep = fusion.resolve_fused_program(m1, targets=[loss1.name])[1]
         assert rep.counts().get("bias_act") == 2
-        assert rep.counts().get("optimizer") == 1
         on, sc_on = run_steps(m1, s1, feed, [loss1.name])
         np.testing.assert_array_equal(off, on)
         w_off = np.asarray(sc_off.get("fc_0.w_0"))
@@ -599,15 +559,28 @@ class TestIntrospectionAndCaching:
     def test_build_strategy_flags_gate_families(self):
         main, startup, loss, acc, pred = build_mnist_mlp()
         bs = fluid.BuildStrategy()
-        bs.fuse_all_optimizer_ops = False
         bs.fuse_elewise_add_act_ops = False
         config = FusionConfig.from_build_strategy(bs)
         fused, report = fusion.resolve_fused_program(
             main, config=config, targets=[loss.name])
         counts = report.counts()
-        assert counts.get("optimizer") is None
         assert counts.get("bias_act") is None
         assert counts.get("softmax_xent") == 1  # its own flag, still on
+
+    def test_fuse_all_optimizer_ops_is_accepted_and_inert(self):
+        """Fluid's own flag stays settable and gates nothing: XLA fuses
+        each parameter's update, so the op list is the same either way."""
+        main, startup, loss, acc, pred = build_mnist_mlp()
+        resolved = []
+        for flag in (True, False):
+            bs = fluid.BuildStrategy()
+            bs.fuse_all_optimizer_ops = flag
+            fused, _ = fusion.resolve_fused_program(
+                main, config=FusionConfig.from_build_strategy(bs),
+                targets=[loss.name])
+            resolved.append(op_types(fused))
+        assert resolved[0] == resolved[1]
+        assert resolved[0].count("adam") == 6
 
     def test_plain_compiled_program_honors_disabled_flags(self):
         """Regression: with a BuildStrategy that disables a family, the
@@ -951,6 +924,25 @@ class TestConvBnActFamily:
         assert "conv_bn_act|" in skips[0].reason  # the signature to sweep
         autotune.reset()
 
+    def test_lint_advisory_covers_new_families(self, monkeypatch,
+                                               tmp_path):
+        """Satellite: fusible-pattern-not-fused surfaces the gated-out
+        conv+bn+act sites with the autotune cost-gate reason."""
+        from paddle_tpu import autotune
+
+        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
+                           str(tmp_path / "at.json"))
+        monkeypatch.setenv("PADDLE_TPU_CONV_BN_MIN_BYTES", "1000000000000")
+        autotune.reset()
+        main, startup, loss = build_conv_bn()
+        diags = verify_program(main, targets=[loss.name])
+        hits = [d for d in diags
+                if d.check == "fusible-pattern-not-fused"
+                and "conv_bn_act" in d.message]
+        assert hits
+        assert any("uncalibrated" in d.message for d in hits)
+        autotune.reset()
+
     def test_calibration_flips_the_gate(self, monkeypatch, tmp_path):
         """The measure-and-learn loop closed: a recorded calibration
         factor scales the predicted delta past the gate."""
@@ -1010,145 +1002,6 @@ class TestConvBnActFamily:
                                    rtol=2e-5, atol=2e-5)
 
 
-# ---------------------------------------------------------------------------
-# embedding gather family (ISSUE 6)
-# ---------------------------------------------------------------------------
-
-def build_embedding(dim=128, vocab=100, slot_len=16, train=True):
-    fluid.unique_name.switch()
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        ids = fluid.layers.data(name="ids", shape=[slot_len],
-                                dtype="int64")
-        label = fluid.layers.data(name="label", shape=[1], dtype="int64")
-        emb = fluid.layers.embedding(
-            ids, size=[vocab, dim], padding_idx=0,
-            param_attr=fluid.ParamAttr(name="fused_emb_tab"))
-        s = fluid.layers.reduce_sum(emb, dim=1)
-        pred = fluid.layers.fc(s, size=10, act="softmax")
-        loss = fluid.layers.reduce_mean(
-            fluid.layers.cross_entropy(input=pred, label=label))
-        if train:
-            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
-    return main, startup, loss
-
-
-class TestEmbeddingGatherFamily:
-    @pytest.fixture(autouse=True)
-    def gate_open(self, monkeypatch):
-        """The family is gated off by default (the kernel measured slower
-        than XLA's gather on the chip); these tests open the gate."""
-        monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", "4096")
-
-    def test_gated_off_by_default(self, monkeypatch):
-        monkeypatch.delenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES")
-        main, startup, loss = build_embedding()
-        fused, report = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report.counts().get("embedding_gather") is None
-        assert "lookup_table" in op_types(fused)
-        skips = [s for s in report.skipped
-                 if s.family == "embedding_gather"]
-        assert skips and "cost model" in skips[0].reason
-
-    def test_rewrite_golden(self):
-        main, startup, loss = build_embedding()
-        fused, report = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report.counts().get("embedding_gather") == 1
-        types = op_types(fused)
-        assert types.count("fused_embedding_gather") == 1
-        assert types.count("fused_embedding_gather_grad") == 1
-        assert "lookup_table" not in types
-        assert "lookup_table_grad" not in types
-        verify_program(fused, targets=[loss.name])
-
-    def test_train_bit_exact_family_isolated(self, monkeypatch):
-        rng = np.random.RandomState(0)
-        feed = {"ids": rng.randint(0, 100, (4, 16)).astype("int64"),
-                "label": rng.randint(0, 10, (4, 1)).astype("int64")}
-
-        def arm(gate):
-            monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES", gate)
-            main, startup, loss = build_embedding()
-            out, _ = run_steps(main, startup, feed, [loss.name], steps=4)
-            return out
-
-        on = arm("4096")
-        off = arm("1000000000000")
-        assert np.array_equal(on, off)
-
-    @pytest.mark.parametrize("rows,n,dtype", [
-        (30522, 16, "float32"),   # table rows not a multiple of the tile
-        (30522, 16, "bfloat16"),  # packed dtype: masked row select
-        (2, 13, "float32"),       # table smaller than a tile, ragged ids
-        (2, 13, "bfloat16"),
-    ])
-    def test_kernel_matches_take(self, monkeypatch, rows, n, dtype):
-        """The Pallas gather (interpret mode) returns exactly the rows
-        ``jnp.take`` does, padding_idx row zeroed."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.ops.pallas.embedding import embedding_gather
-
-        rng = np.random.RandomState(0)
-        table = jnp.asarray(rng.randn(rows, 128), dtype)
-        ids = rng.randint(0, rows, (n, 1)).astype("int64")
-        ids[0] = rows - 1
-        ids[1] = 0
-        monkeypatch.setenv("PADDLE_TPU_PALLAS", "off")
-        want = embedding_gather(table, jnp.asarray(ids), padding_idx=0)
-        monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
-        got = embedding_gather(table, jnp.asarray(ids), padding_idx=0)
-        assert got.dtype == want.dtype and got.shape == (n, 128)
-        assert np.array_equal(np.asarray(got.astype("float32")),
-                              np.asarray(want.astype("float32")))
-        assert not np.asarray(got[1].astype("float32")).any()
-
-    def test_unaligned_dim_skips_with_reason(self):
-        main, startup, loss = build_embedding(dim=48)
-        fused, report = fusion.resolve_fused_program(
-            main, targets=[loss.name])
-        assert report.counts().get("embedding_gather") is None
-        skips = [s for s in report.skipped
-                 if s.family == "embedding_gather"]
-        assert skips and "lane-aligned" in skips[0].reason
-
-    def test_deepfm_device_table_path_fuses(self):
-        """The DeepFM device-table migration: lane-aligned tables fuse,
-        the dim-1 first-order tables are correctly refused, and the
-        model trains to finite losses through the fused gather."""
-        from paddle_tpu.models import ctr
-
-        losses, report = ctr.run_deepfm_device_table_steps(
-            steps=3, num_slots=2, slot_len=3, vocab=200, batch=8,
-            embed_dim=128)
-        assert report.counts().get("embedding_gather") == 2
-        assert all(np.isfinite(l) for l in losses)
-        assert losses[0] != losses[-1]  # it actually trains
-
-    def test_lint_advisory_covers_new_families(self, monkeypatch,
-                                               tmp_path):
-        """Satellite: fusible-pattern-not-fused surfaces the gated-out
-        conv+bn+act and embedding-gather sites with the autotune
-        cost-gate reason."""
-        from paddle_tpu import autotune
-
-        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
-                           str(tmp_path / "at.json"))
-        monkeypatch.setenv("PADDLE_TPU_EMBED_FUSE_MIN_BYTES",
-                           "1000000000000")
-        autotune.reset()
-        main, startup, loss = build_embedding()
-        diags = verify_program(main, targets=[loss.name])
-        hits = [d for d in diags
-                if d.check == "fusible-pattern-not-fused"
-                and "embedding_gather" in d.message]
-        assert hits
-        assert any("uncalibrated" in d.message for d in hits)
-        autotune.reset()
-
-
 class TestConvBnActAmp:
     def test_amp_cast_sandwich_is_absorbed(self):
         """The bf16 AMP rewrite cast-sandwiches BN (conv -> cast f32 ->
@@ -1204,3 +1057,140 @@ class TestConvBnActAmp:
         off = arm("1000000000000")
         assert np.isfinite(on).all() and np.isfinite(off).all()
         np.testing.assert_allclose(on, off, rtol=2e-2, atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# the hand-kept lists agree: flags, rows, signature
+# ---------------------------------------------------------------------------
+
+def _main_and_loss(build, loss_at=2, **kwargs):
+    built = build(**kwargs)
+    return built[0], built[loss_at]
+
+
+_FLAG_PROGRAMS = {
+    "fuse_attention": lambda: _main_and_loss(build_bert_tiny, 3,
+                                             seq_len=512),
+    "fuse_elewise": lambda: _main_and_loss(build_mnist_mlp),
+    "fuse_softmax_xent": lambda: _main_and_loss(build_mnist_mlp),
+    "fuse_conv_bn_act": lambda: _main_and_loss(build_conv_bn),
+    "fuse_allreduce": lambda: _main_and_loss(build_dp_mlp),
+}
+
+
+@pytest.mark.parametrize(
+    "flag", [f for f in FusionConfig.__slots__ if f != "enabled"])
+def test_each_family_flag_is_in_the_signature_and_gates_its_rows(flag):
+    """``FusionConfig``'s flags, ``_FAMILIES``' rows and ``signature()``
+    are three lists kept by hand; a flag missing from the signature is a
+    stale fused clone (fixed three times, ROADMAP Queue 3).  Turning one
+    flag off changes the signature, takes out exactly that flag's
+    families and leaves the others' counts alone."""
+    rows = [family for family, f, _ in fusion._FAMILIES if f == flag]
+    assert rows, "no family reads %s" % flag
+    main, loss = _FLAG_PROGRAMS[flag]()
+    off = FusionConfig()
+    setattr(off, flag, False)
+    assert off.signature(main) != FusionConfig().signature(main)
+    on_counts = fusion.resolve_fused_program(
+        main, targets=[loss.name])[1].counts()
+    off_counts = fusion.resolve_fused_program(
+        main, config=off, targets=[loss.name])[1].counts()
+    assert any(on_counts.get(family) for family in rows)
+    assert off_counts == {family: n for family, n in on_counts.items()
+                          if family not in rows}
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+def test_each_parameter_keeps_its_own_update_op(optimizer):
+    """No rewrite concatenates the parameters: the resolved program has
+    one update op a parameter, the op XLA fuses on its own."""
+    main, _, loss, _, _ = build_mnist_mlp(optimizer=optimizer)
+    fused, _ = fusion.resolve_fused_program(main, targets=[loss.name])
+    params = main.global_block().all_parameters()
+    assert op_types(fused).count(optimizer) == len(params) == 6
+
+
+# ---------------------------------------------------------------------------
+# no dead forks: every family is reached by a default configuration
+# ---------------------------------------------------------------------------
+
+def _cell_programs():
+    """The benchmark cells' training programs from their own builders and
+    files, cut in depth alone (one layer; ResNet's 50 is a table row)."""
+    from chipbench import manifest as mf
+
+    manifest = mf.load_manifest()
+    for entry in manifest["workloads"]:
+        cell = mf.load_cell(entry["name"], manifest)
+        config = dict(cell["config"])
+        if "num_hidden_layers" in config:
+            # kanana's layer 0 is dense: two layers reach an expert layer
+            config["num_hidden_layers"] = \
+                1 + config.get("first_k_dense_replace", 0)
+        builder = mf.load_by_name("builders", config["builder"])
+        _, _, loss, main = builder.build(
+            config, cell["workload"]["program"], cell["traffic"], 0)
+        yield main, [loss.name]
+
+
+def _example_programs():
+    examples = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "examples")
+    if examples not in sys.path:
+        sys.path.insert(0, examples)
+    import bert_pretrain
+    import mnist_train
+
+    main, _, _, loss, acc = mnist_train.build_program()
+    yield main, [loss.name, acc.name]
+    main, _, _, loss = bert_pretrain.build_program(tiny=True, seq_len=32)
+    yield main, [loss.name]
+
+
+def _this_files_programs():
+    main, _, loss, acc, _ = build_mnist_mlp()
+    yield main, [loss.name, acc.name]
+    # attention at the flash threshold's default, the smallest T it admits
+    main, _, _, loss, _ = build_bert_tiny(seq_len=512)
+    yield main, [loss.name]
+    main, _, loss = build_conv_bn()
+    yield main, [loss.name]
+    main, _, loss = build_dp_mlp()     # a data-parallel program
+    yield main, [loss.name]
+
+
+@pytest.fixture(scope="module")
+def default_config_counts(tmp_path_factory):
+    """Rewrites applied per family over the corpus: every gate at its
+    default (no env override, an empty autotune cache), nothing run, only
+    ``resolve_fused_program``."""
+    from paddle_tpu import autotune
+
+    seen = {}
+    with pytest.MonkeyPatch.context() as mp:
+        for name in [n for n in os.environ if n.startswith("PADDLE_TPU_")
+                     and n != "PADDLE_TPU_VERIFY_PASSES"]:
+            mp.delenv(name)     # conftest.py's, or what another file left
+        mp.setenv("PADDLE_TPU_AUTOTUNE_CACHE",
+                  str(tmp_path_factory.mktemp("autotune") / "empty.json"))
+        autotune.reset()
+        for programs in (_example_programs, _cell_programs,
+                         _this_files_programs):
+            for program, targets in programs():
+                _, report = fusion.resolve_fused_program(
+                    program, targets=targets)
+                for family, n in report.counts().items():
+                    seen[family] = seen.get(family, 0) + n
+    autotune.reset()
+    return seen
+
+
+@pytest.mark.parametrize("family", [row[0] for row in fusion._FAMILIES])
+def test_every_family_fires_under_the_default_config(
+        default_config_counts, family):
+    """A family that no default configuration reaches is a dead fork
+    (as the embedding-gather and optimizer families were): matched in every
+    resolve, keyed into every jit key, and kept alive by tests that open
+    its gate.  It has to apply somewhere with every gate at its default."""
+    assert default_config_counts.get(family, 0) >= 1

@@ -422,9 +422,10 @@ class TestNanGuard:
                                      monkeypatch=monkeypatch)
         assert stats["skipped_steps"] == 1
         assert stats["last_skipped_step"] == 2
-        # trajectory == fault-free run that never applied step 2
-        _, oracle, _ = self._run(batches, skip={2},
-                                 monkeypatch=monkeypatch)
+        # trajectory == the same step (its gate feed cold: step 2, the
+        # fault's, never comes) in a run that never applied step 2
+        _, oracle, _ = self._run(batches, spec="nan_grad@step=2",
+                                 skip={2}, monkeypatch=monkeypatch)
         for name in params:
             np.testing.assert_array_equal(params[name], oracle[name])
 

@@ -2,17 +2,18 @@
 
 Compiles the flagship (bert) or resnet train step through the real
 Executor lowering on the CPU backend and prints XLA's own accounting:
-FLOPs, bytes accessed, temp/output/alias sizes.  This is how the r04
-fused-Adam regression was convicted without a chip (145GB unfused vs
-664GB fused bytes accessed on the BERT-base bs64 step, matching the
-hardware MFU drop 0.42->0.30), and how the framework was shown to be
-~2x cheaper than the hand-written pure-jax control (291GB).
+FLOPs, bytes accessed, temp/output/alias sizes.  This is how a
+flat-stream optimizer fusion (since deleted) was convicted without a chip
+(145GB per-parameter vs 664GB concatenated bytes accessed on the BERT-base
+bs64 step, matching the hardware MFU drop 0.42->0.30), and how the
+framework was shown to be ~2x cheaper than the hand-written pure-jax
+control (291GB).
 
 Absolute numbers are CPU-backend artifacts; the value is in A/B deltas
-under env knobs (PADDLE_TPU_FUSE_ADAM, PADDLE_TPU_PALLAS, model edits).
+under env knobs (PADDLE_TPU_FUSION, PADDLE_TPU_PALLAS) and model edits.
 
 Usage:  python tools/step_cost.py [bert|resnet] [batch]
-        PADDLE_TPU_FUSE_ADAM=1 python tools/step_cost.py bert 64
+        PADDLE_TPU_FUSION=0 python tools/step_cost.py bert 64
 """
 
 import sys
