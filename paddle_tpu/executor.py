@@ -21,6 +21,7 @@ writing into scope slots (``executor.cc:254-325``); `feed`/`fetch` ops that
 exist in serialized programs are recognized and skipped.
 """
 
+import collections
 import contextlib
 import threading
 import time as _time
@@ -33,6 +34,7 @@ from .observability import runtime as _obs
 from .observability import tracing as _tr
 from .framework import Program, default_main_program, Variable
 from .ops import registry as op_registry
+from .ops.pallas.flash_attention import noting_blocks
 from .ops.registry import EMPTY_VAR_NAME
 from .pipeline import FetchHandle
 
@@ -459,6 +461,9 @@ class _CompiledBlock:
         # was traced (``LoweringContext.residual_sites``), and the open
         # ``compile`` phase that reports it after the first dispatch
         self.residual_sites = {}
+        # the grid blocks of the flash kernels the step's last trace held
+        # (``flash_attention.noting_blocks``), reported the same way
+        self.flash_blocks = {}
         self.compile_phase = None
         ext_reads, written, persist_written = _analyze_block(
             block, feed_names, fetch_names
@@ -530,7 +535,8 @@ class _CompiledBlock:
                 ctx.fault_value_hook = _rfaults.get_injector() \
                     .make_value_hook(gate, loss_name=getattr(
                         program, "_guard_loss_name", None))
-            _run_ops_into_env(block, env, ctx, ops=_top_ops)
+            with noting_blocks(collections.Counter()) as self.flash_blocks:
+                _run_ops_into_env(block, env, ctx, ops=_top_ops)
             fetches = [env[n] for n in self.fetch_names]
             new_rw = {n: env[n] for n in self.rw_names}
             fresh = {n: env[n] for n in self.fresh_persist if n in env}
@@ -755,7 +761,8 @@ class _AccumRunner:
                 base_key=jax.random.fold_in(key, idx), mode=self.mode)
             ctx.fault_value_hook = fault_hook
             ctx.residual_sites = cb.residual_sites
-            _run_ops_into_env(self.block, e, ctx, ops=self.head)
+            with noting_blocks(collections.Counter()) as cb.flash_blocks:
+                _run_ops_into_env(self.block, e, ctx, ops=self.head)
             return (
                 {n: e[n] for n in self.grad_reads},
                 {n: e[n] for n in self.carry_out if n in e},
@@ -1110,6 +1117,8 @@ def _dispatch_step(runner, step_phase, compiled, program, scope, feed_vals,
         # this block's first dispatch: jax.jit traced the step just now
         _obs.record_grad_residual_sites(
             compiled.residual_sites.values(), compiled.compile_phase)
+        _obs.record_flash_blocks(compiled.flash_blocks,
+                                 compiled.compile_phase)
         compiled.compile_phase = None
     with _tr.phase(runner + ".apply_results"):
         fetches = _apply_step_results(

@@ -20,6 +20,7 @@ from .metrics import telemetry_enabled
 __all__ = [
     "record_step", "record_step_done", "record_jit_cache",
     "record_compile", "record_grad_residual_sites",
+    "record_flash_blocks",
     "record_moe_layers", "publish_moe_counters",
     "record_fusion_resolve", "record_feed_cache",
     "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
@@ -262,6 +263,29 @@ def record_grad_residual_sites(sites, compile_phase):
                    "kernel sites whose grad op reused the forward op's "
                    "residuals, or ran the forward kernel again",
                    op_type=op_type, path=path).inc(n)
+
+
+def record_flash_blocks(blocks, compile_phase):
+    """The grid blocks of the flash kernels a newly traced step holds,
+    ``blocks[(kernel, kind)]`` as ``flash_attention.noting_blocks``
+    counted them (kernel ``fwd`` | ``dkv`` | ``dq``; kind ``possible``:
+    the whole rectangle, ``visited``: those a sweep computes, ``masked``:
+    visited blocks the causal diagonal crosses), summed over the sites: three
+    attributes of the block's ``compile`` phase, a step without a flash
+    kernel gets none."""
+    if not blocks:
+        return
+    for kind in ("possible", "visited", "masked"):
+        compile_phase.set_attr(
+            "flash_blocks_" + kind,
+            sum(n for (_, k), n in blocks.items() if k == kind))
+    if not telemetry_enabled():
+        return
+    for (kernel, kind), n in blocks.items():
+        _m.counter("flash_blocks_total",
+                   "grid blocks of the flash kernels in traced steps: "
+                   "possible, visited, and masked among the visited",
+                   kernel=kernel, kind=kind).inc(n)
 
 
 # stats var of each expert layer a compiled step holds -> its layer

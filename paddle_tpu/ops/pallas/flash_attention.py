@@ -6,17 +6,32 @@ for the hot path.  On TPU the hot path is attention; this module implements
 the FlashAttention-2 blocked online-softmax algorithm so the [B,H,T,T]
 score matrix never touches HBM:
 
-* forward: grid (B*H, Tq/bq, Tk/bk), KV innermost; running (m, l, acc) live
-  in VMEM scratch across the KV sweep; output + logsumexp written on the
-  last KV block.
-* backward: two kernels — dK/dV (grid over KV blocks, sweeping Q) and dQ
-  (grid over Q blocks, sweeping KV) — using the saved logsumexp and the
-  precomputed delta = rowsum(dO * O), the standard FA2 recomputation split.
+* forward: grid (B*H, Tq/bq, spans of K); a grid step holds one Q block and
+  a span of K and V (the whole sequence up to 4 MiB: ``_span``) and sweeps
+  the span's K chunks of ``bk`` rows in a loop inside the kernel; running
+  (m, l, acc) live in VMEM scratch across the sweep; output + m and l
+  written when the last span ends.
+* backward: two kernels — dK/dV (a K block a grid step, sweeping the Q
+  chunks of a span of Q and dO) and dQ (a Q block, sweeping K chunks) —
+  using the saved m and l and the precomputed delta = rowsum(dO * O), the
+  standard FA2 recomputation split.  Each is ONE ``pallas_call`` a site
+  and one small body (a loop, never a body unrolled over blocks): every
+  site's kernels are traced when a Program is built and traced and lowered
+  again at its first step, so a kernel's trace cost is paid twice a site in
+  set-up (``tests/test_flash_attention.py::test_one_small_kernel_a_site``).
 
 Supported bias: an additive key-padding bias of shape [B, Tk] (the common
 [B,1,1,Tk] mask squeezed), broadcast over heads and query positions; it is
 treated as constant (no gradient — padding masks are data, not parameters).
-Causal masking is a flag; above-diagonal blocks are skipped entirely.
+Causal masking is a flag.  A sweep's loop bounds come from where the
+diagonal lies: chunks wholly under it run without a mask, chunks it
+crosses run the same step with the mask (two loops over one body; the
+dK/dV kernel computes a square block in two parts that leave out the
+quarter above the diagonal: ``_tiles``), chunks above it are not visited
+at all; where a sequence needs several spans, a
+grid step whose span lies wholly above the diagonal runs no chunk and its
+block index is clamped to the last one fetched, so nothing is fetched for
+it either.
 
 Q and K share one head width ``d`` (the contraction of the scores); V has
 its own, ``dv``, which is also the output's: latent attention carries a
@@ -37,9 +52,14 @@ come from a position hash (same formula exposed as
 against the XLA reference; the hardware PRNG path is validated on-chip.
 
 Per-row stats (m, l) live in (block_q, 128) VMEM scratch with the value
-replicated across lanes; rows are recovered with a lanes-reduce and moved
-between row/column orientation with 2-D reshapes (both verified supported
-by Mosaic on v5e).
+replicated across lanes, and the arithmetic on them stays in that form
+(``_lanes`` widens it to a score block by repeating whole vregs): the only
+reductions of a chunk are the row max and row sum of its scores.  The
+backward kernels take the saved rows as they are: dK/dV keeps the keys
+down the rows of its score blocks, so m, l and delta are lane vectors and
+P^T dO, dS^T Q are plain products (no transpose of a score block); dQ turns
+the three rows of its Q block to columns once a sweep.  1/l is an exact
+division a row, and ``sm_scale`` meets dK and dQ once, at the end.
 
 Off-TPU, and for shapes the kernel does not cover, the entry point routes
 to a pure-XLA implementation; set ``PADDLE_TPU_PALLAS=interpret`` to force
@@ -47,9 +67,12 @@ the Pallas kernels in interpreter mode (CPU correctness tests), or ``=off``
 to force the XLA path (see :func:`paddle_tpu.ops.pallas.use_pallas`).
 """
 
+import contextlib
 import functools
 import math
+import operator
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +85,19 @@ from . import use_pallas
 NEG_INF = -1e30
 
 
-def _row(x2d):
-    """(1, n) row from a (n, 1) column value."""
-    return x2d.reshape(1, -1)
+
+def _rows(x, y):
+    """The ``(1, n)`` rows of two ``(n, 128)`` statistics that hold a
+    row's number in every lane.  One transpose on the XLU where Mosaic
+    has it (whole 128 x 128 tiles), x in the lower lanes and y in the
+    upper: a transposed vreg costs two issue slots, a column turned by
+    rotations ten."""
+    n = x.shape[0]
+    if n % 128:
+        return x[:, :1].reshape(1, n), y[:, :1].reshape(1, n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    both = jax.lax.select(lane < 64, x, y).T
+    return both[:1], both[64:65]
 
 
 def _dropout_debug():
@@ -85,15 +118,20 @@ def _hash_bits(b, r, c, seed):
     return h * jnp.uint32(2246822519)
 
 
-def _keep_mask(shape, rate, seed_ref, bh, qi, kj, block_q, block_k, debug):
-    """In-kernel Bernoulli keep mask for the (qi, kj) block of
-    batch·head bh.  Hardware path: per-block counter seeding of the TPU
+def _keep_mask(shape, rate, seed_ref, bh, block, debug, keys_down=False):
+    """In-kernel Bernoulli keep mask of batch·head bh for the block
+    ``block`` = (Q block, K block, its first query, its first key),
+    ``shape`` = (block_q, block_k); transposed, the same draws, if
+    ``keys_down``.  Hardware path: per-block counter seeding of the TPU
     PRNG (fwd and bwd seed identically, so the draw reproduces)."""
+    qi, kj, q_pos, k_pos = block
     if debug:
-        r = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
-             + (qi * block_q).astype(jnp.uint32))
-        c = (jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-             + (kj * block_k).astype(jnp.uint32))
+        if keys_down:
+            shape = shape[::-1]
+        r = (jax.lax.broadcasted_iota(jnp.uint32, shape, int(keys_down))
+             + jnp.asarray(q_pos, jnp.uint32))
+        c = (jax.lax.broadcasted_iota(jnp.uint32, shape, int(not keys_down))
+             + jnp.asarray(k_pos, jnp.uint32))
         bits = _hash_bits(bh.astype(jnp.uint32), r, c, seed_ref[0])
     else:
         # v5e Mosaic caps prng_seed at 2 words ("Setting seed with more
@@ -104,7 +142,8 @@ def _keep_mask(shape, rate, seed_ref, bh, qi, kj, block_q, block_k, debug):
         # int32 wraparound is well-defined in Mosaic.
         mix = qi * jnp.int32(7919) + kj * jnp.int32(104729)
         pltpu.prng_seed(seed_ref[0] ^ bh, mix)
-        bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+        bits = pltpu.prng_random_bits(shape)
+        bits = pltpu.bitcast(bits.T if keys_down else bits, jnp.uint32)
     return bits >= _rate_threshold(rate)
 
 
@@ -120,90 +159,291 @@ def debug_keep_mask(bh, tq, tk, rate, seed):
 
 
 # ---------------------------------------------------------------------------
+# What a kernel call visits: chunks of a grid step's span, up to the diagonal
+# ---------------------------------------------------------------------------
+
+_noting = threading.local()
+
+
+@contextlib.contextmanager
+def noting_blocks(counts):
+    """While active on this thread, every flash kernel call that is
+    traced adds its (block_q, block_k) blocks to ``counts[(kernel,
+    kind)]``, kind ``possible`` (the whole rectangle), ``visited`` (those
+    a sweep computes) and ``masked`` (visited blocks the diagonal
+    crosses), each times batch·head.  The Executor holds it open while
+    it traces a step (``observability.runtime.record_flash_blocks``)."""
+    was = getattr(_noting, "counts", None)
+    _noting.counts = counts
+    try:
+        yield counts
+    finally:
+        _noting.counts = was
+
+
+def _note_blocks(kernel, bh, nq, nk, block_q, block_k, causal):
+    counts = getattr(_noting, "counts", None)
+    if counts is None:
+        return
+    seen = [j * block_k + block_k - 1 > i * block_q      # crossed?
+            for i in range(nq) for j in range(nk)
+            if not causal or j * block_k <= i * block_q + block_q - 1]
+    for kind, n in (("possible", nq * nk), ("visited", len(seen)),
+                    ("masked", sum(seen) if causal else 0)):
+        counts[kernel, kind] += bh * n
+
+
+def _span(n, rows, row_bytes):
+    """How many of the ``n`` blocks of ``rows`` rows that a sweep walks
+    one grid step holds in VMEM (a divisor of ``n``): as many as 3 MiB
+    take (twice that with the pipeline's second buffer, beside 4 to 6 MiB
+    of score blocks, under Mosaic's 16 MiB), so that a sweep is a loop
+    inside the kernel and not a grid step a block (0.13-0.23 us each on
+    a v5e, the blocks above the diagonal among them)."""
+    span = n
+    while span > 1 and (span * rows * row_bytes > 3 << 20 or n % span):
+        span -= 1
+    return span
+
+
+def _sweep(ranges, step):
+    """``step(c, masked)`` for the chunks ``lo <= c < hi`` of each
+    ``(masked, lo, hi)``: a loop in the kernel, its bounds static (not
+    causal) or computed from where the diagonal lies."""
+    for masked, lo, hi in ranges:
+        if isinstance(hi, int) and isinstance(lo, int) and hi - lo == 1:
+            step(lo, masked)  # not causal, one block: no loop
+        else:
+            jax.lax.fori_loop(
+                lo, hi, lambda c, _, masked=masked: step(c, masked), None)
+
+
+def _ints(python, lax):
+    """Index arithmetic: Python's own on two ints, else one lax call (a
+    jax.numpy operator on a traced scalar costs five to trace)."""
+    def op(x, y):
+        both = isinstance(x, int) and isinstance(y, int)
+        return python(x, y) if both else lax(x, y)
+    return op
+
+
+_iadd = _ints(operator.add, jax.lax.add)
+_isub = _ints(operator.sub, jax.lax.sub)
+_imul = _ints(operator.mul, jax.lax.mul)
+
+
+def _at(first, n):
+    """``n`` rows (or lanes) from ``first``, a multiple of ``n``."""
+    return pl.ds(first if isinstance(first, int)
+                 else pl.multiple_of(first, n), n)
+
+
+def _tiles(masked, block_q, block_k):
+    """The parts of a ``block_q x block_k`` block that the dK/dV kernel
+    computes, each ``(q0, queries, k0, keys)``: the whole block, or, of a
+    square block the diagonal crosses (corner to corner then), the three
+    quarters at and under it in two parts: the early queries against the
+    early keys, the late queries against all.  The quarter above the
+    diagonal costs no product.  (Only dK/dV, whose step holds four
+    products: its second copy of the step is traced once a site, the
+    forward's would be twice, for a third less to save.)"""
+    half = block_q // 2
+    if not masked or block_q != block_k or half % 128:
+        return [(0, block_q, 0, block_k)]
+    return [(0, half, 0, half), (half, half, 0, block_k)]
+
+
+def _div(x, n):
+    """``x // n`` for a traced ``x >= 0``: one equation, where the
+    floor division of jax.numpy lowers a dozen."""
+    return jax.lax.div(x, jnp.int32(n))
+
+
+def _within(x, span):
+    return jax.lax.min(jax.lax.max(x, 0), span)
+
+
+# The arithmetic of the kernel bodies is written with jax.lax functions,
+# operands of one shape: a jax.numpy operator on a tracer costs five times
+# a lax call to trace, and set-up traces every site's kernels twice
+_add, _sub, _mul, _exp = jax.lax.add, jax.lax.sub, jax.lax.mul, jax.lax.exp
+
+
+def _select(keep, x, other):
+    """``jnp.where(keep, x, other)`` for a scalar ``other``, inline."""
+    return jax.lax.select(keep, x, jax.lax.full_like(x, other))
+
+
+def _cast(x, like):
+    return jax.lax.convert_element_type(x, like.dtype)
+
+
+def _over_keys(reduce, s):
+    """A row reduction of the scores ``(rows, keys)`` as ``(rows, 128)``,
+    every lane the row's number."""
+    return jax.lax.broadcast_in_dim(reduce(s, (1,)), (s.shape[0], 128), (0,))
+
+
+def _down(row, n):
+    """A ``(1, q)`` row of statistics against ``(n, q)`` scores."""
+    return jax.lax.broadcast_in_dim(row, (n, row.shape[1]), (0, 1))
+
+
+def _major(axis, n):
+    """The grid's index along the swept axis, a Python 0 where one step
+    holds the whole sweep (then ``pl.when`` sees plain booleans)."""
+    return pl.program_id(axis) if n > 1 else 0
+
+
+def _lanes(x, n):
+    """``(rows, n)`` from ``(rows, 128)`` whose lanes all hold the row's
+    number: whole vregs repeated, or a prefix of the lanes, so that no
+    value crosses lanes."""
+    if n % 128 == 0:
+        return x if n == 128 else pltpu.repeat(x, n // 128, axis=1)
+    if n < 128:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _column(row):
+    """A ``(1, n)`` row of statistics as ``(n, 128)``, its number in
+    every lane: the inverse of :func:`_row`."""
+    n = row.shape[-1]
+    if n % 128 == 0:
+        return jnp.broadcast_to(row, (128, n)).T
+    return jnp.broadcast_to(row.reshape(n, 1), (n, 128))
+
+
+def _scores(x, y, sm_scale, bias, offset, keys_down):
+    """``x @ y.T * sm_scale (+ bias)`` in float32 from input-dtype
+    operands.  ``offset`` (None: no mask) is the first key's position
+    less the first query's: the score of query r and key c of the block
+    is visible iff ``r - c >= offset``; ``keys_down`` says the keys run
+    down the rows (the dK/dV kernel's orientation)."""
+    # matmuls run at the INPUT dtype with f32 accumulation: under bf16
+    # AMP the MXU's bf16 rate is ~4x its f32 rate, and bf16xbf16->f32 is
+    # bit-identical to upcast-then-f32 (bf16 casts are exact;
+    # 8-bit-mantissa products fit f32's 24); f32 inputs are unchanged
+    s = _mul(jax.lax.dot_general(
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ), sm_scale)
+    if bias is not None:
+        s = s + bias  # a row or a column against the block
+    if offset is not None:
+        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = jax.lax.ge(_sub(c, r) if keys_down else _sub(r, c), offset)
+        s = _select(seen, s, NEG_INF)
+    return s
+
+
+def _k_sweep(causal, i, J, span, block_q, block_k):
+    """The K chunks of grid step ``J``'s span that Q block ``i`` sees:
+    those wholly under the diagonal unmasked, then those it crosses."""
+    if not causal:
+        return [(False, 0, span)]
+    first, base = _imul(i, block_q), _imul(J, span)
+    whole = _within(_isub(_div(_iadd(first, 1), block_k), base), span)
+    seen = _within(_isub(_iadd(_div(_iadd(first, block_q - 1), block_k), 1),
+                         base), span)
+    return [(False, 0, whole), (True, whole, seen)]
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, o_ref, m_out_ref,
-                l_out_ref, acc_ref, m_ref, l_ref, *, sm_scale, causal,
-                block_q, block_k, dropout_rate, dropout_debug):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
+                has_bias, block_q, block_k, dropout_rate, dropout_debug):
+    bias_ref = rest[0] if has_bias else None
+    seed_ref, o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = \
+        rest[has_bias:]
+    b, i, J = pl.program_id(0), pl.program_id(1), _major(2, n_major)
+    span, dv = k_ref.shape[1] // block_k, acc_ref.shape[-1]
 
-    @pl.when(j == 0)
+    @pl.when(J == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        # matmuls run at the INPUT dtype with f32 accumulation: under
-        # bf16 AMP the MXU's bf16 rate is ~4x its f32 rate, and
-        # bf16xbf16->f32 QK^T is bit-identical to upcast-then-f32 (bf16
-        # casts are exact; 8-bit-mantissa products fit f32's 24).  Same
-        # fix as the r04 XLA-fallback change; f32 inputs are unchanged.
-        q = q_ref[0]  # [bq, d]
-        k = k_ref[0]  # [bk, d]
-        v = v_ref[0]  # [bk, dv]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # [bq, bk] f32
-        if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)  # (1, bk) broadcasts
-        if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(rows >= cols, s, NEG_INF)
-
-        # lanes of m_ref/l_ref all hold the same value; a lanes-max recovers
-        # the (bq, 1) column without lane slicing
-        m_prev = jnp.max(m_ref[:], axis=1, keepdims=True)
-        l_prev = jnp.max(l_ref[:], axis=1, keepdims=True)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+    def _step(c, masked):
+        j = _iadd(_imul(J, span), c)  # this K chunk among the sequence's
+        q_pos, k_pos = _imul(i, block_q), _imul(j, block_k)
+        keys = _at(_imul(c, block_k), block_k)
+        v = v_ref[0, keys, :]  # [bk, dv]
+        s = _scores(
+            q_ref[0], k_ref[0, keys, :], sm_scale,
+            bias_ref[0, :, keys].astype(jnp.float32) if has_bias else None,
+            _isub(k_pos, q_pos) if masked else None, False)
+        # m and l stay in their (bq, 128) form, every lane the row's
+        # number: the only reductions of a step are the scores' own
+        m_prev = m_ref[:]
+        m_new = jax.lax.max(m_prev, _over_keys(jax.lax.reduce_max, s))
+        alpha = _exp(_sub(m_prev, m_new))
+        p = _exp(_sub(s, _lanes(m_new, block_k)))
         # FA2 dropout: l accumulates the UNdropped p (true softmax
         # denominator); only the numerator entries feeding PV are masked
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[:] = _add(_mul(alpha, l_ref[:]),
+                        _over_keys(jax.lax.reduce_sum, p))
+        m_ref[:] = m_new
         if dropout_rate > 0.0:
-            keep = _keep_mask(p.shape, dropout_rate, seed_ref, b, i, j,
-                              block_q, block_k, dropout_debug)
-            p = jnp.where(keep, p, 0.0) / (1.0 - dropout_rate)
+            keep = _keep_mask(p.shape, dropout_rate, seed_ref, b,
+                              (i, j, q_pos, k_pos), dropout_debug)
+            p = _select(keep, _mul(p, 1.0 / (1.0 - dropout_rate)), 0.0)
         # PV at input dtype (p downcast under AMP): the MXU-rate
         # tradeoff mha_reference makes identically; acc stays f32
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[:] = _add(
+            _mul(acc_ref[:], _lanes(alpha, dv)),
+            jax.lax.dot_general(
+                _cast(p, v), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
 
-    if causal:
-        @pl.when(j * block_k <= i * block_q + (block_q - 1))
-        def _():
-            _compute()
-    else:
-        _compute()
+    _sweep(_k_sweep(causal, i, J, span, block_q, block_k), _step)
 
-    @pl.when(j == nk - 1)
+    @pl.when(J == n_major - 1)
     def _finalize():
-        m = jnp.max(m_ref[:], axis=1, keepdims=True)
-        l = jnp.max(l_ref[:], axis=1, keepdims=True)
-        l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows → zeros, not NaN
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = l_ref[:]
+        l = _select(l != 0.0, l, 1.0)  # a row no chunk reached: zeros
+        o_ref[0] = (acc_ref[:] / _lanes(l, dv)).astype(o_ref.dtype)
         # m and l are saved SEPARATELY (not lse = m + log l): when |m| is
         # large (e.g. -1e4 padding bias on every visible key) the f32 sum
-        # m + log(l) loses all bits of log(l); exp(s - m)/l in the backward
-        # reproduces the forward's p bit-for-bit instead
-        m_out_ref[0] = _row(m)
-        l_out_ref[0] = _row(l)
+        # m + log(l) loses all bits of log(l); exp(s - m) * (1/l) in the
+        # backward reproduces the forward's p instead
+        m_out_ref[0], l_out_ref[0] = _rows(m_ref[:], l)
+
+
+def _operands(q, k, v, bias, q_rows, k_rows, blocks_of):
+    """``spec`` and the specs and arguments every kernel starts with, q,
+    k, v (, bias), in blocks of ``q_rows`` and ``k_rows`` rows.
+    ``blocks_of(*grid indices) -> (b, i, j)`` says which blocks a grid
+    step holds; ``spec(shape, index)`` is the BlockSpec at
+    ``index(b, i, j)``."""
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda *g: index(*blocks_of(*g)))
+
+    specs = [spec((1, q_rows, q.shape[2]), lambda b, i, j: (b, i, 0)),
+             spec((1, k_rows, k.shape[2]), lambda b, i, j: (b, j, 0)),
+             spec((1, k_rows, v.shape[2]), lambda b, i, j: (b, j, 0))]
+    args = [q, k, v]
+    if bias is not None:
+        nheads = k.shape[0] // bias.shape[0]
+        specs.append(spec((1, 1, k_rows),
+                          lambda b, i, j: (b // nheads, 0, j)))
+        args.append(bias.reshape(bias.shape[0], 1, k.shape[1]))
+    return spec, specs, args
+
+
+def _q_major(causal, block_q, k_rows, n_major):
+    """``blocks_of`` for a grid (b, Q block i, span J of K): a causal
+    step whose span lies wholly above the diagonal is given the last
+    span under it, and a block index that repeats is not fetched
+    again."""
+    if not causal or n_major == 1:
+        return lambda b, i, J: (b, i, J)
+    return lambda b, i, J: (b, i, jnp.minimum(
+        J, _div(i * block_q + block_q - 1, k_rows)))
 
 
 def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
@@ -211,41 +451,25 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
     bh, tq, d = q.shape
     _, tk, dv = v.shape
     nq, nk = tq // block_q, tk // block_k
-    grid = (bh, nq, nk)
-
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
-    ]
-    args = [q, k, v]
-    kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-              block_k=block_k, dropout_rate=dropout_rate,
-              dropout_debug=dropout_debug)
-    if bias is not None:
-        nheads = bh // bias.shape[0]
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, i, j: (b // nheads, 0, j))
-        )
-        args.append(bias.reshape(bias.shape[0], 1, tk))
-        kernel = functools.partial(_fwd_kernel, **kw)
-    else:
-        def kernel(qr, kr, vr, sr, o, mo, lo, acc, m, l):
-            return _fwd_kernel(qr, kr, vr, None, sr, o, mo, lo, acc, m, l,
-                               **kw)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    args.append(seed)
+    _note_blocks("fwd", bh, nq, nk, block_q, block_k, causal)
+    span = _span(nk, block_k, (d + dv) * k.dtype.itemsize)
+    k_rows = span * block_k
+    _, in_specs, args = _operands(
+        q, k, v, bias, block_q, k_rows,
+        _q_major(causal, block_q, k_rows, nk // span))
+    stat = pl.BlockSpec((1, 1, block_q), lambda b, i, J: (b, 0, i))
 
     o, m_out, l_out = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _fwd_kernel, n_major=nk // span, sm_scale=sm_scale, causal=causal,
+            has_bias=bias is not None, block_q=block_q, block_k=block_k,
+            dropout_rate=dropout_rate, dropout_debug=dropout_debug),
         name="flash_attention_fwd",
-        grid=grid,
-        in_specs=in_specs,
+        grid=(bh, nq, nk // span),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, J: (b, i, 0)),
+            stat, stat,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
@@ -258,7 +482,7 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(*args)
+    )(*args, seed)
     return o, m_out, l_out
 
 
@@ -266,138 +490,135 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q, k, bias_ref, m_col, l_col, sm_scale, causal, i, j,
-                 block_q, block_k):
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if bias_ref is not None:
-        s = s + bias_ref[0].astype(jnp.float32)
-    if causal:
-        rows = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(rows >= cols, s, NEG_INF)
-    return jnp.exp(s - m_col) / l_col
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref, m_ref,
-                    l_ref, dl_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    sm_scale, causal, block_q, block_k, dropout_rate,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
+                    has_bias, block_q, block_k, dropout_rate,
                     dropout_debug):
-    b = pl.program_id(0)
-    j = pl.program_id(1)  # kv block
-    i = pl.program_id(2)  # q block (innermost sweep)
-    nq = pl.num_programs(2)
+    """dK and dV of one K block over its sweep of Q chunks, with the keys
+    down the rows of every score-shaped value (the transpose of the
+    forward's): the rows' statistics m, l, delta are then lane vectors
+    used as saved, and P^T dO and dS^T Q are plain products."""
+    bias_ref = rest[0] if has_bias else None
+    seed_ref, do_ref, m_ref, l_ref, dl_ref, dk_ref, dv_ref, dk_acc, \
+        dv_acc, *bias_col = rest[has_bias:]
+    b, j, I = pl.program_id(0), pl.program_id(1), _major(2, n_major)
+    span = q_ref.shape[1] // block_q
 
-    @pl.when(i == 0)
+    @pl.when(I == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if has_bias:  # the K block's bias, turned to a column once
+            bias_col[0][:] = _column(bias_ref[0].astype(jnp.float32))
 
-    def _compute():
-        # input-dtype matmuls, f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        m_col = m_ref[0].reshape(block_q, 1)
-        l_col = l_ref[0].reshape(block_q, 1)
-        delta_col = dl_ref[0].reshape(block_q, 1)
-        p = _recompute_p(q, k, bias_ref, m_col, l_col, sm_scale, causal,
-                         i, j, block_q, block_k)
-        # dP = dO @ V^T
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def _step(c, masked):
+        i = _iadd(_imul(I, span), c)  # this Q chunk among the sequence's
+        q_pos, k_pos = _imul(i, block_q), _imul(j, block_k)
         if dropout_rate > 0.0:
-            # the SAME (b, i, j) seeding as the forward reproduces the
-            # mask; O = P_drop V, so dV uses P_drop and the softmax-
-            # jacobian input is the mask-scaled dP (sum P·dP = delta
-            # still holds because delta = rowsum(dO·O))
-            keep = _keep_mask(p.shape, dropout_rate, seed_ref, b, i, j,
-                              block_q, block_k, dropout_debug)
-            p_v = jnp.where(keep, p, 0.0) / (1.0 - dropout_rate)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_rate)
-        else:
-            p_v = p
-        # dV += P_drop^T @ dO
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # dS = P * (dP_masked - delta)
-        ds = p * (dp - delta_col)
-        # dK += dS^T @ Q * scale
-        dk_acc[:] = dk_acc[:] + sm_scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            # the SAME seeding as the forward reproduces the mask;
+            # O = P_drop V, so dV uses P_drop and the softmax-jacobian
+            # input is the mask-scaled dP (sum P·dP = delta still holds
+            # because delta = rowsum(dO·O))
+            keep = _keep_mask((block_q, block_k), dropout_rate, seed_ref,
+                              b, (i, j, q_pos, k_pos), dropout_debug,
+                              keys_down=True)
+            inv_keep = 1.0 / (1.0 - dropout_rate)
+        for q0, qn, k0, kn in _tiles(masked, block_q, block_k):
+            rows = _at(_iadd(_imul(c, block_q), q0), qn)
+            keys = slice(k0, k0 + kn)
+            q = q_ref[0, rows, :]
+            do = do_ref[0, rows, :]
+            s = _scores(
+                k_ref[0, keys, :], q, sm_scale,
+                _lanes(bias_col[0][keys, :], qn) if has_bias else None,
+                _isub(_iadd(k_pos, k0), _iadd(q_pos, q0)) if masked
+                else None, True)
+            p = _mul(_exp(_sub(s, _down(m_ref[0, :, rows], kn))),
+                     _down(jax.lax.div(1.0, l_ref[0, :, rows]), kn))
+            # dP^T = V @ dO^T
+            dp = jax.lax.dot_general(
+                v_ref[0, keys, :], do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if dropout_rate > 0.0:
+                kept = keep[keys, q0:q0 + qn]
+                p_v = _select(kept, _mul(p, inv_keep), 0.0)
+                dp = _select(kept, _mul(dp, inv_keep), 0.0)
+            else:
+                p_v = p
+            # dV += P_drop^T @ dO
+            dv_acc[keys, :] = _add(dv_acc[keys, :], jax.lax.dot_general(
+                _cast(p_v, do), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))
+            # dS = P * (dP_masked - delta); dK += dS^T @ Q, scaled at
+            # the end
+            ds = _mul(p, _sub(dp, _down(dl_ref[0, :, rows], kn)))
+            dk_acc[keys, :] = _add(dk_acc[keys, :], jax.lax.dot_general(
+                _cast(ds, q), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))
 
-    if causal:
-        @pl.when(i * block_q + (block_q - 1) >= j * block_k)
-        def _():
-            _compute()
+    if causal:  # the Q chunks the diagonal crosses, then those under it
+        first, base = _imul(j, block_k), _imul(I, span)
+        seen = _within(_isub(_div(first, block_q), base), span)
+        whole = _within(_isub(
+            _div(_iadd(first, block_k + block_q - 2), block_q), base), span)
+        _sweep([(True, seen, whole), (False, whole, span)], _step)
     else:
-        _compute()
+        _sweep([(False, 0, span)], _step)
 
-    @pl.when(i == nq - 1)
+    @pl.when(I == n_major - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, seed_ref, do_ref, m_ref,
-                   l_ref, dl_ref, dq_ref, dq_acc, *, sm_scale, causal,
-                   block_q, block_k, dropout_rate, dropout_debug):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
+                   has_bias, block_q, block_k, dropout_rate, dropout_debug):
+    bias_ref = rest[0] if has_bias else None
+    seed_ref, do_ref, m_ref, l_ref, dl_ref, dq_ref, dq_acc, m_col, \
+        linv_col, dl_col = rest[has_bias:]
+    b, i, J = pl.program_id(0), pl.program_id(1), _major(2, n_major)
+    span = k_ref.shape[1] // block_k
 
-    @pl.when(j == 0)
+    @pl.when(J == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        # the Q block's statistics, turned to columns once a sweep
+        m_col[:] = _column(m_ref[0])
+        linv_col[:] = _column(1.0 / l_ref[0])
+        dl_col[:] = _column(dl_ref[0])
 
-    def _compute():
-        # input-dtype matmuls, f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        m_col = m_ref[0].reshape(block_q, 1)
-        l_col = l_ref[0].reshape(block_q, 1)
-        delta_col = dl_ref[0].reshape(block_q, 1)
-        p = _recompute_p(q, k, bias_ref, m_col, l_col, sm_scale, causal,
-                         i, j, block_q, block_k)
+    def _step(c, masked):
+        j = _iadd(_imul(J, span), c)
+        q_pos, k_pos = _imul(i, block_q), _imul(j, block_k)
+        keys = _at(_imul(c, block_k), block_k)
+        k = k_ref[0, keys, :]
+        s = _scores(
+            q_ref[0], k, sm_scale,
+            bias_ref[0, :, keys].astype(jnp.float32) if has_bias else None,
+            _isub(k_pos, q_pos) if masked else None, False)
+        p = _mul(_exp(_sub(s, _lanes(m_col[:], block_k))),
+                 _lanes(linv_col[:], block_k))
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            do_ref[0], v_ref[0, keys, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if dropout_rate > 0.0:
-            keep = _keep_mask(p.shape, dropout_rate, seed_ref, b, i, j,
-                              block_q, block_k, dropout_debug)
-            dp = jnp.where(keep, dp, 0.0) / (1.0 - dropout_rate)
-        ds = p * (dp - delta_col)
-        dq_acc[:] = dq_acc[:] + sm_scale * jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            keep = _keep_mask(p.shape, dropout_rate, seed_ref, b,
+                              (i, j, q_pos, k_pos), dropout_debug)
+            dp = _select(keep, _mul(dp, 1.0 / (1.0 - dropout_rate)), 0.0)
+        ds = _mul(p, _sub(dp, _lanes(dl_col[:], block_k)))
+        dq_acc[:] = _add(dq_acc[:], jax.lax.dot_general(
+            _cast(ds, k), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        ))
 
-    if causal:
-        @pl.when(j * block_k <= i * block_q + (block_q - 1))
-        def _():
-            _compute()
-    else:
-        _compute()
+    _sweep(_k_sweep(causal, i, J, span, block_q, block_k), _step)
 
-    @pl.when(j == nk - 1)
+    @pl.when(J == n_major - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
@@ -405,54 +626,44 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     bh, tq, d = q.shape
     _, tk, d_v = v.shape
     nq, nk = tq // block_q, tk // block_k
+    item = q.dtype.itemsize
 
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, None, :]  # [bh, 1, tq], matching the saved m/l row layout
-    bias3 = None if bias is None else bias.reshape(bias.shape[0], 1, tk)
-    kw = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-              block_k=block_k, dropout_rate=dropout_rate,
+    kw = dict(sm_scale=sm_scale, causal=causal, has_bias=bias is not None,
+              block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
               dropout_debug=dropout_debug)
 
-    # --- dK/dV: grid (bh, kv-block, q-sweep) ---
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),  # v
-    ]
-    dkv_args = [q, k, v]
-    if bias is not None:
-        nheads = bh // bias.shape[0]
-        dkv_specs.append(
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, j, i: (b // nheads, 0, j))
-        )
-        dkv_args.append(bias3)
-        dkv_kernel = functools.partial(_bwd_dkv_kernel, **kw)
-    else:
-        def dkv_kernel(qr, kr, vr, sr, dor, mr, lr, dlr, dkr, dvr, dka,
-                       dva):
-            return _bwd_dkv_kernel(
-                qr, kr, vr, None, sr, dor, mr, lr, dlr, dkr, dvr, dka,
-                dva, **kw)
-    dkv_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))   # seed
-    dkv_args.append(seed)
-    dkv_specs += [
-        pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),    # do
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # m
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # l
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),     # delta
-    ]
-    dkv_args += [do, m, l, delta]
+    def operands(q_rows, k_rows, blocks_of):
+        """... then seed, dO, m, l, delta: the rest of both kernels'."""
+        spec, specs, args = _operands(q, k, v, bias, q_rows, k_rows,
+                                      blocks_of)
+        stat = spec((1, 1, q_rows), lambda b, i, j: (b, 0, i))
+        specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
+                  spec((1, q_rows, d_v), lambda b, i, j: (b, i, 0)),
+                  stat, stat, stat]
+        return specs, args + [seed, do, m, l, delta]
 
+    # --- dK/dV: a K block's sweep of Q chunks ---
+    _note_blocks("dkv", bh, nq, nk, block_q, block_k, causal)
+    span = _span(nq, block_q, (d + d_v) * item + 12)
+    q_rows = span * block_q
+    if causal and nq > span:  # a span above the diagonal: see _q_major
+        def blocks_of(b, j, I):
+            return b, jnp.maximum(I, _div(j * block_k, q_rows)), j
+    else:
+        def blocks_of(b, j, I):
+            return b, I, j
+    specs, args = operands(q_rows, block_k, blocks_of)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_bwd_dkv_kernel, n_major=nq // span, **kw),
         name="flash_attention_dkv",
-        grid=(bh, nk, nq),
-        in_specs=dkv_specs,
+        grid=(bh, nk, nq // span),
+        in_specs=specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, I: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, I: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
@@ -461,49 +672,28 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d_v), jnp.float32),
-        ],
+        ] + ([pltpu.VMEM((block_k, 128), jnp.float32)]
+             if bias is not None else []),
         interpret=interpret,
-    )(*dkv_args)
+    )(*args)
 
-    # --- dQ: grid (bh, q-block, kv-sweep) ---
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
-    ]
-    dq_args = [q, k, v]
-    if bias is not None:
-        nheads = bh // bias.shape[0]
-        dq_specs.append(
-            pl.BlockSpec((1, 1, block_k),
-                         lambda b, i, j: (b // nheads, 0, j))
-        )
-        dq_args.append(bias3)
-        dq_kernel = functools.partial(_bwd_dq_kernel, **kw)
-    else:
-        def dq_kernel(qr, kr, vr, sr, dor, mr, lr, dlr, dqr, dqa):
-            return _bwd_dq_kernel(
-                qr, kr, vr, None, sr, dor, mr, lr, dlr, dqr, dqa, **kw)
-    dq_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))   # seed
-    dq_args.append(seed)
-    dq_specs += [
-        pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),    # do
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # m
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # l
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),     # delta
-    ]
-    dq_args += [do, m, l, delta]
-
+    # --- dQ: a Q block's sweep of K chunks ---
+    _note_blocks("dq", bh, nq, nk, block_q, block_k, causal)
+    span = _span(nk, block_k, (d + d_v) * item)
+    k_rows = span * block_k
+    specs, args = operands(block_q, k_rows,
+                           _q_major(causal, block_q, k_rows, nk // span))
     dq = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_bwd_dq_kernel, n_major=nk // span, **kw),
         name="flash_attention_dq",
-        grid=(bh, nq, nk),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        grid=(bh, nq, nk // span),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, J: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
+        + [pltpu.VMEM((block_q, 128), jnp.float32)] * 3,
         interpret=interpret,
-    )(*dq_args)
+    )(*args)
 
     return dq, dk, dv
 

@@ -3,6 +3,7 @@ fused_multihead_attention op/layer, mirroring the reference OpTest pattern
 (`python/paddle/fluid/tests/unittests/op_test.py`): kernel vs XLA-reference
 oracle for forward and grads."""
 
+import collections
 import os
 
 import numpy as np
@@ -311,3 +312,237 @@ class TestInKernelDropout:
         q = _rand(rng, 1, 1, 128, 64)
         with pytest.raises(ValueError, match="dropout_rate"):
             FA.flash_attention(q, q, q, dropout_rate=1.0, dropout_seed=1)
+
+
+# -- the grid stops at the diagonal; masks only where it crosses -------------
+
+def _fwd_and_grads(fn, q, k, v, w, **kw):
+    out = fn(q, k, v, **kw)
+    grads = jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v, **kw) * w),
+                     argnums=(0, 1, 2))(q, k, v)
+    return (out,) + grads
+
+
+def _against_reference(q, k, v, **kw):
+    """Forward and the three gradients of the kernels against
+    ``mha_reference``, at the tolerances of the tests above."""
+    w = jnp.asarray(np.random.RandomState(9).randn(
+        *q.shape[:3], v.shape[-1]).astype("float32"))
+    assert FA.routes_to_kernel(q, k, kw.get("bias"), v)
+    got = _fwd_and_grads(FA.flash_attention, q, k, v, w, **kw)
+    want = _fwd_and_grads(FA.mha_reference, q, k, v, w, **kw)
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=2e-5)
+    for a, b, nm in zip(got[1:], want[1:], "qkv"):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3,
+                                   err_msg="d%s" % nm)
+    return got
+
+
+@pytest.mark.parametrize("t,dqk,dv,bq,bk", [
+    (512, 192, 128, 128, 128),   # latent attention's widths, 4 x 4 blocks
+    (512, 64, 64, 256, 128),     # the diagonal crosses two K blocks a row
+    (512, 64, 64, 128, 256),     # ... two Q blocks a K block
+    (384, 64, 64, 64, 128),
+    (256, 64, 64, 256, 256),     # tq == bq: one block, masked
+    (768, 192, 128, 256, 256),   # square blocks: the diagonal ones in
+])                               # two parts, their upper quarter left out
+def test_causal_blocks(t, dqk, dv, bq, bk, monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", str(bq))
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", str(bk))
+    assert FA._pick_blocks(t, t)[:2] == (bq, bk)
+    rng = np.random.RandomState(3)
+    q, k = (_rand(rng, 1, 2, t, dqk) for _ in range(2))
+    _against_reference(q, k, _rand(rng, 1, 2, t, dv), causal=True)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bq,bk,span", [(128, 128, 2), (64, 128, 1),
+                                        (128, 256, 1)])
+def test_a_sweep_of_several_grid_steps(bq, bk, span, causal, monkeypatch):
+    """A sequence too long for one grid step to hold its K and V (or Q
+    and dO): the sweep goes on over the grid's last axis, the state in
+    scratch, and a causal step wholly above the diagonal runs no chunk
+    and fetches nothing new."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", str(bq))
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", str(bk))
+    monkeypatch.setattr(FA, "_span", lambda n, rows, row_bytes: span)
+    rng = np.random.RandomState(6)
+    q, k, v = (_rand(rng, 1, 2, 512, 64) for _ in range(3))
+    bias = jnp.asarray(np.where(rng.rand(1, 512) < 0.2, -1e4, 0)
+                       .astype("float32"))
+    _against_reference(q, k, v, causal=causal, bias=bias)
+
+
+@pytest.mark.parametrize("bk", [128, 256])
+def test_statistics_carry_across_k_blocks_with_bias_and_dropout(
+        bk, monkeypatch):
+    """Not causal, a key bias, debug-hash dropout, 4 or 2 K blocks a Q
+    block: m and l ride the sweep in their lane-replicated form."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_DROPOUT_DEBUG", "iota")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", str(bk))
+    rng = np.random.RandomState(4)
+    b, h, t, d = 2, 2, 512, 64
+    q, k, v = (_rand(rng, b, h, t, d) for _ in range(3))
+    bias = jnp.asarray(np.where(rng.rand(b, t) < 0.2, -1e4, 0)
+                       .astype("float32"))
+    w = jnp.asarray(rng.randn(b, h, t, d).astype("float32"))
+    seed = jnp.asarray([77], jnp.int32)
+    got = _fwd_and_grads(FA.flash_attention, q, k, v, w, bias=bias,
+                         dropout_rate=0.1, dropout_seed=seed)
+    want = _fwd_and_grads(FA.mha_reference, q, k, v, w, bias=bias,
+                          dropout_rate=0.1, seed=seed, debug=True)
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=2e-5)
+    for a, b_, nm in zip(got[1:], want[1:], "qkv"):
+        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4,
+                                   err_msg="d%s" % nm)
+
+
+def test_causal_with_bias_and_dropout_on_square_blocks(monkeypatch):
+    """The diagonal blocks' two parts take their slices of the block's
+    one dropout draw and of the key bias."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_DROPOUT_DEBUG", "iota")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "256")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "256")
+    rng = np.random.RandomState(8)
+    b, h, t, d = 2, 2, 512, 64
+    q, k, v = (_rand(rng, b, h, t, d) for _ in range(3))
+    bias = jnp.asarray(np.where(rng.rand(b, t) < 0.2, -1e4, 0)
+                       .astype("float32"))
+    w = jnp.asarray(rng.randn(b, h, t, d).astype("float32"))
+    seed = jnp.asarray([5], jnp.int32)
+    got = _fwd_and_grads(FA.flash_attention, q, k, v, w, bias=bias,
+                         causal=True, dropout_rate=0.1, dropout_seed=seed)
+    want = _fwd_and_grads(FA.mha_reference, q, k, v, w, bias=bias,
+                          causal=True, dropout_rate=0.1, seed=seed,
+                          debug=True)
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=2e-5)
+    for a, b_, nm in zip(got[1:], want[1:], "qkv"):
+        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4,
+                                   err_msg="d%s" % nm)
+
+
+def test_fully_masked_rows_are_finite(monkeypatch):
+    """A bias of -1e30 on every key leaves a row nothing to prefer: the
+    kernels give what the reference gives (the mean of V), no NaN, in
+    the output and in all three gradients."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "128")
+    rng = np.random.RandomState(5)
+    q, k, v = (_rand(rng, 2, 2, 256, 64) for _ in range(3))
+    bias = jnp.asarray(np.stack([np.full(256, -1e30), np.zeros(256)])
+                       .astype("float32"))
+    out = _against_reference(q, k, v, bias=bias)[0]
+    np.testing.assert_allclose(out[0], jnp.broadcast_to(
+        jnp.mean(v[0], axis=1, keepdims=True), out[0].shape), atol=2e-5)
+
+
+# -- the set-up budget, without a clock --------------------------------------
+
+def _count_equations(jaxpr):
+    """Equations of a jaxpr, those of nested jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_equations(sub)
+    return n
+
+
+# about three times the 83 / 65 / 55 of the kernels before PR 33
+BODY_LIMIT = {"flash_attention_fwd": 250, "flash_attention_dkv": 200,
+              "flash_attention_dq": 170}
+
+
+@pytest.mark.parametrize("bh,t,dqk,dv,causal,bias,rate", [
+    (128, 4096, 192, 128, True, False, 0.0),    # the kanana cell's site
+    (384, 512, 64, 64, False, True, 0.1),       # BERT seq512's
+])
+def test_one_small_kernel_a_site(bh, t, dqk, dv, causal, bias, rate):
+    """``_flash_fwd`` holds ONE ``pallas_call``, ``_flash_bwd`` one for
+    dK/dV and one for dQ, whatever the number of blocks, and each body
+    stays small: every attention site's kernels are traced when the
+    Program is built and traced and lowered again at the first step, so
+    a body unrolled over blocks, or a call a row of blocks, is paid
+    twice a site in ``setup_s`` (PR 32's refusal)."""
+    bq, bk = FA._pick_blocks(t, t)[:2]
+    assert t // bq == (8 if causal else 1)
+    sd = jax.ShapeDtypeStruct
+    q, v = sd((bh, t, dqk), jnp.bfloat16), sd((bh, t, dv), jnp.bfloat16)
+    b = sd((bh // 12, t), jnp.float32) if bias else None
+    seed, stat = sd((1,), jnp.int32), sd((bh, 1, t), jnp.float32)
+    static = (causal, 0.125, bq, bk, False, rate, False)
+    fwd = jax.make_jaxpr(lambda q, k, v, b, s: FA._flash_fwd(
+        q, k, v, b, s, *static))(q, q, v, b, seed)
+    bwd = jax.make_jaxpr(lambda q, k, v, b, s, o, m, l, do: FA._flash_bwd(
+        q, k, v, b, s, o, m, l, do, *static))(q, q, v, b, seed, v, stat,
+                                              stat, v)
+    calls = [(e.params["name"], _count_equations(e.params["jaxpr"]))
+             for jp in (fwd, bwd) for e in jp.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert [name for name, _ in calls] == list(BODY_LIMIT)
+    for name, n in calls:
+        assert n < BODY_LIMIT[name], (name, n)
+
+
+# -- how often the mechanism engages -----------------------------------------
+
+def test_blocks_noted_at_the_kanana_site():
+    """One head of 4096 at 512 x 512 blocks, causal: each kernel's grid
+    visits 36 of the 64 blocks and masks 8 of the 36."""
+    sd = jax.ShapeDtypeStruct
+    q, v = sd((1, 1, 4096, 192), jnp.bfloat16), sd((1, 1, 4096, 128),
+                                                   jnp.bfloat16)
+    assert FA._pick_blocks(4096, 4096)[:2] == (512, 512)
+    with FA.noting_blocks(collections.Counter()) as noted:
+        jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(FA.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)),
+            q, q, v)
+    assert noted == {(kernel, kind): n for kernel in ("fwd", "dkv", "dq")
+                     for kind, n in (("possible", 64), ("visited", 36),
+                                     ("masked", 8))}
+    with FA.noting_blocks(collections.Counter()) as noted:
+        jax.eval_shape(FA.flash_attention, q, q, v)     # not causal
+    assert noted == {("fwd", "possible"): 64, ("fwd", "visited"): 64,
+                     ("fwd", "masked"): 0}
+
+
+def test_compile_phase_carries_the_blocks(monkeypatch):
+    """A training step with one causal site of 2 x 2 blocks over 6
+    batch-heads: the ``compile`` phase holds the three kernels' blocks,
+    and the counter has them by kernel."""
+    import paddle_tpu as fluid
+    from paddle_tpu.observability import metrics, tracing
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "128")
+    metrics.registry().reset()
+    b, h, t, d = 2, 3, 256, 16
+    fluid.unique_name.switch()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", shape=[h, t, d])
+        x.stop_gradient = False
+        q = fluid.layers.scale(x, scale=0.5)
+        out = fluid.layers.fused_multihead_attention(q, q, q, causal=True)
+        loss = fluid.layers.reduce_sum(out)
+        fluid.backward.gradients([loss], [x])
+    exe = fluid.Executor(fluid.CPUPlace())
+    with tracing.span("test.root"):     # inside a trace every step records
+        exe.run(main, feed={"x": np.ones((b, h, t, d), "float32")},
+                fetch_list=[loss])
+    attrs = [r["attrs"] for r in tracing.get_tracer().records()
+             if r["name"] == "executor.compile"
+             and "flash_blocks_possible" in r["attrs"]][-1]
+    heads, kernels = b * h, 3
+    assert (attrs["flash_blocks_possible"], attrs["flash_blocks_visited"],
+            attrs["flash_blocks_masked"]) == (
+        kernels * heads * 4, kernels * heads * 3, kernels * heads * 2)
+    got = {(dict(m.labels)["kernel"], dict(m.labels)["kind"]): m.value
+           for m in metrics.registry().collect()
+           if m.name == "flash_blocks_total"}
+    assert got[("dkv", "visited")] == heads * 3 and len(got) == 9

@@ -18,7 +18,7 @@ import numpy as np
 
 
 def bench_case(T, dropout, use_kernel, B=16, H=12, D=64, steps=30,
-               block_q=None, block_k=None):
+               block_q=None, block_k=None, DV=None, causal=False):
     """use_kernel: False = XLA fallback, True = our Pallas kernel,
     "jax" = the upstream jax.experimental TPU flash kernel (no-dropout
     comparator: how far is our kernel from the stock tuned one?)."""
@@ -52,7 +52,7 @@ def bench_case(T, dropout, use_kernel, B=16, H=12, D=64, steps=30,
                     dtype=jnp.bfloat16)
     k = jnp.asarray(rng.randn(B, H, T, D).astype(np.float32),
                     dtype=jnp.bfloat16)
-    v = jnp.asarray(rng.randn(B, H, T, D).astype(np.float32),
+    v = jnp.asarray(rng.randn(B, H, T, DV or D).astype(np.float32),
                     dtype=jnp.bfloat16)
     seed = jnp.asarray([3], jnp.int32)
 
@@ -68,7 +68,7 @@ def bench_case(T, dropout, use_kernel, B=16, H=12, D=64, steps=30,
     else:
         def loss(q, k, v):
             o = FA.flash_attention(
-                q, k, v, dropout_rate=dropout,
+                q, k, v, causal=causal, dropout_rate=dropout,
                 dropout_seed=(seed if dropout else None))
             return jnp.sum(o.astype(jnp.float32) ** 2)
 
@@ -80,26 +80,32 @@ def bench_case(T, dropout, use_kernel, B=16, H=12, D=64, steps=30,
         l, g = step(q, k, v)
     jax.block_until_ready((l, g))
     dt = (time.perf_counter() - t0) / steps
-    # attention fwd+bwd FLOPs: fwd 2*2*B*H*T^2*D (scores + PV), bwd ~2.5x
-    flops = 3.5 * 2 * 2 * B * H * T * T * D
+    # attention fwd+bwd FLOPs: fwd 2*B*H*T^2*(D + DV) (scores + PV), bwd
+    # ~2.5x; causal needs half of them
+    flops = 3.5 * 2 * B * H * T * T * (D + (DV or D)) / (2 if causal else 1)
     mfu = flops / dt / 197e12
     return dt * 1e3, mfu
 
 
 def block_sweep():
     """Block-shape sweep at the kernel's own regime (VERDICT r4 #4):
-    (block_q, block_k) combos at T=512/1024 with dropout on, kernel
-    path only.  Prints per-T winners and BLOCK-DECISION lines the
-    watcher artifact records (parsed by tools/decide_flash_min_t.py)."""
+    (block_q, block_k) combos at T=512/1024 with dropout on and at the
+    causal 192/128 site of T=4096, kernel path only.  Prints per-T
+    winners and BLOCK-DECISION lines the watcher artifact records
+    (parsed by tools/decide_flash_min_t.py)."""
     best = {}
-    for T in (512, 1024):
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512):
+    # BERT's heads at T 512 / 1024 with dropout; latent attention's site
+    # of the kanana cell (4 x 32 heads, 192 / 128 wide, causal) at 4096
+    latent = dict(B=4, H=32, D=192, DV=128, causal=True, steps=10)
+    for T, dropout, site in ((512, 0.1, {}), (1024, 0.1, {}),
+                             (4096, 0.0, latent)):
+        for bq in (128, 256, 512, 1024):
+            for bk in (128, 256, 512, 1024):
                 if bq > T or bk > T:
                     continue
                 try:
-                    ms, mfu = bench_case(T, 0.1, True, block_q=bq,
-                                         block_k=bk)
+                    ms, mfu = bench_case(T, dropout, True, block_q=bq,
+                                         block_k=bk, **site)
                 except Exception as e:  # noqa: BLE001
                     print("# T=%d bq=%d bk=%d FAILED: %s"
                           % (T, bq, bk, str(e)[-160:]), flush=True)
