@@ -1,10 +1,10 @@
 """Operations and bytes of the BERT training step, from shapes.
 
-``train_flops_per_example`` is ``bench.py``'s ``model_train_flops_per_token``
-times the sequence length, copied so that an edit of ``bench.py`` cannot
-move ``mfu``: forward matmuls of the encoder and of the MLM head over the
-gathered positions, backward counted as twice the forward, nothing
-recomputed counted.
+``train_flops_per_example``: forward matmuls of the encoder and of the MLM
+head over the gathered positions, backward counted as twice the forward,
+nothing recomputed counted (the arithmetic ``bench.py`` has as
+``model_train_flops_per_token``, kept here so that no edit there can move
+``mfu``, and held to numbers worked by hand in ``selftest/``).
 """
 
 
@@ -46,8 +46,8 @@ def fused_ln_bytes_per_step(config, traffic, elt_bytes=2):
 def flash_flops_per_step(config, traffic):
     """Matmul operations attention needs for one forward and one backward
     per layer: QK^T and PV forward (2 matmuls), dV, dP, dK, dQ backward (4),
-    each 2*T*T*dh per head.  What the kernels recompute (the scores in both
-    backward kernels, the whole second forward) is not counted."""
+    each 2*T*T*dh per head.  What the kernels compute again (the scores,
+    in both backward kernels) is not counted."""
     t = traffic["seq_len"]
     per_head = 6 * 2 * t * t * (config["hidden_size"]
                                 // config["num_attention_heads"])

@@ -49,22 +49,41 @@ def expected_rows_per_token(config):
 
 
 def counted_rows(config):
-    """``{layer: {"rows": [..], "possible": n, "steps": n}}`` from the
-    program's device counters (``observability.runtime.
-    publish_moe_counters``), or {} where the program keeps none."""
+    """``{layer: {"rows": [..], "possible": n, "moved": n, "steps": n}}``
+    from the program's device counters (``observability.runtime.
+    publish_moe_counters``: one host sync a layer), the totals of the run
+    so far; {} where the program keeps none.  The loop reads them where
+    its windows open and close (``traffic/train_loop.py`` ``measure``)."""
     try:
         from paddle_tpu.observability.runtime import publish_moe_counters
     except ImportError:
         return {}
-    return {k: v for k, v in publish_moe_counters().items() if v["steps"]}
+    return publish_moe_counters()
 
 
-def rows_per_token(config):
-    """Rows a token sent to the experts held here, a layer: what the
-    counters show over the run so far.  Without counters there is nothing
-    to count the routed experts' work by, and that is an error: the even
-    spread is the caller's to pass where it is meant."""
-    layers = counted_rows(config).values()
+def counted_between(before, after):
+    """What two readings of ``counted_rows`` lie apart, layer by layer:
+    the rows, choices and steps of the window between them.  A layer that
+    ``before`` lacks counts from nought; one without a step in between is
+    left out."""
+    out = {}
+    for layer, now in after.items():
+        then = before.get(layer)
+        if then is not None:
+            now = {"rows": [a - b for a, b in zip(now["rows"],
+                                                  then["rows"])],
+                   **{k: now[k] - then[k] for k in now if k != "rows"}}
+        if now["steps"]:
+            out[layer] = now
+    return out
+
+
+def rows_per_token(config, counted):
+    """Rows a token sent to the experts held here, a layer, over the steps
+    that ``counted`` holds (``counted_between``).  Without counters there
+    is nothing to count the routed experts' work by, and that is an error:
+    the even spread is the caller's to pass where it is meant."""
+    layers = counted.values()
     if not layers:
         raise RuntimeError(
             "the program keeps no moe_count_rows counters: the routed "
@@ -101,10 +120,11 @@ def forward_flops_per_token(config, seq_len, routed_rows=None):
     return parts
 
 
-def train_flops_per_example(config, traffic, routed_rows=None):
+def train_flops_per_example(config, traffic, routed_rows):
+    """``routed_rows``: the rows a token sends to the held experts of one
+    layer; what the window's counters show (``rows_per_token``), never a
+    default."""
     seq = traffic["seq_len"]
-    if routed_rows is None:
-        routed_rows = rows_per_token(config)
     return 3 * seq * forward_flops_per_token(
         config, seq, routed_rows)["total"]
 

@@ -1,8 +1,9 @@
 """Operations of the ResNet-50 training step.
 
-``bench.py``'s ``RESNET50_TRAIN_FLOPS_PER_IMAGE``, copied: the forward pass
-at 224x224 is 4.09 G multiply-accumulates (the torchvision / fvcore count),
-two operations each, and a training step is three forwards' worth.
+The forward pass at 224x224 is 4.09 G multiply-accumulates (the torchvision
+/ fvcore count), two operations each, and a training step is three
+forwards' worth (``bench.py`` has the same constant as
+``RESNET50_TRAIN_FLOPS_PER_IMAGE``; nothing here reads it).
 """
 
 RESNET50_FORWARD_MACS_224 = 4.09e9

@@ -1,7 +1,8 @@
 """The least time attention's operations and bytes could take (the larger of
 operations over the bf16 peak and bytes over the HBM peak), over the device
-time of every flash-attention event.  Recomputation is not counted as
-useful: not the scores inside the backward kernels, not the second forward."""
+time of every flash-attention event (one forward, one dK/dV and one dQ
+kernel a site since PR 27).  Recomputation is not counted as useful: the
+scores that both backward kernels compute again."""
 
 
 def read(ctx):

@@ -1,7 +1,8 @@
 """The least time the fused-LN calls' bytes could take at the HBM peak, over
-the device time of every fused-LN event.  The forward kernel that the grad
-op runs a second time moves no byte the algorithm needs: its time counts,
-its bytes do not."""
+the device time of every fused-LN event: one forward and one backward
+kernel a site (since PR 27 the grad op takes the forward's residuals; a
+kernel that a step ran beside them would count by its time, not its
+bytes)."""
 
 
 def read(ctx):

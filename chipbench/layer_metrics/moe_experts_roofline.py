@@ -1,18 +1,21 @@
 """The least time the grouped products over the held experts could take for
-the rows the program's counters show were routed here (the larger of their
-operations over the bf16 peak and their bytes over the HBM peak), over the
-device time of the ``ragged-dot`` kernels: nine useful products a layer and
-the three the recompute region runs again."""
+the rows the program's counters show were routed here in the traced
+window's own steps (what they read where it closed less what they read
+where it opened; the larger of the rows' operations over the bf16 peak and
+their bytes over the HBM peak), over the device time of the ``ragged-dot``
+kernels in that window: nine useful products a layer and the three the
+recompute region runs again."""
 
 
 def read(ctx):
     spent = sum(s for k, s in ctx["trace"]["kernel_s"].items()
                 if "ragged-dot" in k)
     flops = ctx["flops"]
-    if not spent or not hasattr(flops, "counted_rows"):
+    if not spent or not hasattr(flops, "counted_between"):
         return None
     config = ctx["cell"]["config"]
-    layers = flops.counted_rows(config).values()
+    counted = ctx["state"]["counters"]
+    layers = flops.counted_between(counted["trace"], counted["end"]).values()
     if not layers:
         return None
     rows_a_step = sum(sum(c["rows"]) / c["steps"] for c in layers)

@@ -1,12 +1,15 @@
 """Device milliseconds a step under the Program-op tags ``moe_route`` (the
 router's scores and top-k) and ``moe_experts.dispatch`` and
 ``moe_experts.combine`` (sorting the choices, gathering rows to and from the
-held experts' buffer): the expert layer's cost beside its products.
-``tag_s`` names an op by its innermost ``pd<i>_<tag>`` scope, and where
-jax differentiates a recompute region it rewrites each scope to
-``transpose(jvp(pd..))``, which that reduction does not match: with every
-layer in a region this reads the forward pass alone; the region's re-run and
-its backward read under ``recompute_block_grad`` (PERF.md 7)."""
+held experts' buffer): the expert layer's cost beside its products, **in
+every pass**.  ``tag_s`` names an op by the innermost ``pd<i>_<tag>`` scope
+of its ``op_name``, through the ``jvp(..)`` and ``transpose(jvp(..))`` that
+jax puts around a scope inside a region it differentiates
+(``xplane.program_op``), so with every layer under ``recompute()`` this is
+the forward pass, the region's re-run and its backward together; until
+PR 35 it read the forward alone (19.46 ms of the kanana step, PERF.md 6).
+A ``while`` that holds the layer's loop is left out and its body's events
+count (``xplane.containers``)."""
 
 TAGS = ("moe_route", "moe_experts.dispatch", "moe_experts.combine")
 

@@ -159,17 +159,37 @@ def step_executions(dev):
 
 def dispatch_leads_ns(trace, why=None):
     """Per step and device: the start of the step module's execution less
-    the end of that step's ``*.dispatch`` annotation.  A device runs what
-    it is handed in order, so on each device the annotations sorted by
-    their ``step`` are paired with the executions sorted by their start,
-    after the ends that have no partner are dropped: executions that began
-    before the first dispatch did (handed over before the trace opened)
-    and dispatches that began after the last execution did (the trace
-    closed before they ran).  None where that cannot be done, with the
-    reason appended to ``why``: no annotation with a step, a step twice or
-    missing in the middle, a device with another number of executions than
-    steps left, or an execution that starts before its own dispatch
-    began."""
+    the end of that step's ``*.dispatch`` annotation.
+
+    **Paired by order, not by clock.**  A device runs what it is handed in
+    order, so on each device the k-th execution that has its dispatch in
+    the trace is the k-th dispatched step.  Only the ends can lack a
+    partner: executions in front that were handed over before the trace
+    opened, and dispatches behind whose step ran after it closed.  The
+    counts say how many: every execution over the number of steps is a
+    stranger in front, and each step left without an execution was
+    dispatched last.
+
+    The clock only checks that, and never to the millisecond: the device
+    plane's stamps lie 0.4-1.9 ms early against the host plane's, and the
+    first traced step starts on an idle device 1.3-2.9 ms after its
+    dispatch begins (PERF.md 6, PRs 26-29), so "this execution began
+    before that dispatch did" reads the wrong way round on some runs (the
+    reader that turned on it refused PRs 28 and 34).  No comparison of a
+    device stamp with a host stamp here turns on less than an allowance
+    taken from the trace itself, a quarter of the median execution of the
+    step module: far over any skew, far under the whole step by which a
+    wrong alignment is off.  Beyond it: an execution that began before the
+    first dispatch did is one more stranger; a stranger by the counts has
+    to have begun before the first dispatch did, a dispatch dropped behind
+    after the last execution did, and a pair's execution after its own
+    dispatch did, or the alignment is off.  A lead that the skew makes
+    slightly negative is reported as it reads.
+
+    None, with the reason appended to ``why``, for a fault of the program
+    or the trace: no annotation, one without a step or twice, a step
+    missing in the middle, no device plane, counts that the ends do not
+    explain, an alignment a whole step off."""
     why = [] if why is None else why
     dispatches = {}
     for name, start, dur, step in trace["program"]:
@@ -190,20 +210,36 @@ def dispatch_leads_ns(trace, why=None):
         why.append("dispatch annotations of steps %d..%d, %d of them"
                    % (steps[0], steps[-1], len(steps)))
         return None
+    ordered = [dispatches[s] for s in steps]
     leads = []
     for dev_name, dev in sorted(trace["devices"].items()):
-        ordered = [dispatches[s] for s in steps]
-        runs = [r for r in step_executions(dev) if r[0] >= ordered[0][0]]
-        while ordered and runs and ordered[-1][0] > runs[-1][0]:
-            ordered.pop()
-        if len(runs) != len(ordered) or not runs:
-            why.append("%s: %d executions of the step module for %d steps "
-                       "dispatched" % (dev_name, len(runs), len(ordered)))
+        runs = step_executions(dev)
+        if not runs:
+            why.append("%s: no execution of the step module" % dev_name)
             return None
-        for (d_start, d_end), (r_start, _) in zip(ordered, runs):
-            if r_start < d_start:
-                why.append("%s: an execution at %d ns before its dispatch "
-                           "began at %d" % (dev_name, r_start, d_start))
+        allow = statistics.median(end - start for start, end in runs) / 4
+        front = max(0, len(runs) - len(ordered))
+        if front and runs[front - 1][0] > ordered[0][0] + allow:
+            why.append("%s: %d executions of the step module for %d steps "
+                       "dispatched, and the first %d did not begin before "
+                       "the first dispatch" % (dev_name, len(runs),
+                                               len(ordered), front))
+            return None
+        while front < len(runs) and \
+                runs[front][0] < ordered[0][0] - allow:
+            front += 1
+        paired = runs[front:]
+        behind = ordered[len(paired):]
+        if not paired or (behind and
+                          behind[0][0] < paired[-1][0] - allow):
+            why.append("%s: %d executions of the step module for %d steps "
+                       "dispatched" % (dev_name, len(paired), len(ordered)))
+            return None
+        for (d_start, d_end), (r_start, _) in zip(ordered, paired):
+            if r_start < d_start - allow:
+                why.append("%s: an execution at %d ns, over the allowance "
+                           "of %d before its dispatch began at %d"
+                           % (dev_name, r_start, allow, d_start))
                 return None
             leads.append(r_start - d_end)
     return leads

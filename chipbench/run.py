@@ -5,7 +5,9 @@
 
 The last line of standard output is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
-its per-layer metrics with ``--trace 1``) and ``device``.  Earlier lines are
+its per-layer metrics with ``--trace 1``), ``device`` and, last,
+``compared``: each number that decided ``correct`` beside its limit, which
+are also the last lines of standard error.  Earlier lines are
 for people: the set-up's parts, the median step, tokens per second, the
 compiled step's memory.  Without a TPU, or with fewer chips than the cell
 asks for, the run exits non-zero and prints no result: no number ever comes
@@ -20,9 +22,10 @@ end-to-end values but ``setup_s``), ``t_window`` (where set-up ends),
 ``attempted``, ``failed``, ``clocks`` and a ``report`` for the earlier
 lines.  Its ``verify(state, cell, devices)`` returns the problems that make
 the run not ``correct``, and leaves ``kernels`` and ``op_names`` in the
-state for the trace reduction.  This file adds ``setup_s`` and the device's
-memory, picks the metrics the manifest names for the cell, and finds each
-per-layer metric's reader under ``layer_metrics/`` by the metric's name.
+state for the trace reduction and ``compared`` for the result's line.
+This file adds ``setup_s`` and the device's memory, picks the metrics the
+manifest names for the cell, and finds each per-layer metric's reader
+under ``layer_metrics/`` by the metric's name.
 """
 
 import time
@@ -184,6 +187,15 @@ def main(argv=None):
             program_op_ms_per_step={k: 1e3 * v for k, v in sorted(
                 trace["tag_s"].items(), key=lambda kv: -kv[1])[:15]},
             collective_ms_per_step=1e3 * trace["collective_s"])
+    # each number compared beside its limit: last in the line, and the
+    # last lines of standard error
+    result["compared"] = state.get("compared", {})
+    for name, pair in result["compared"].items():
+        sys.stderr.write("chipbench: compared %s %s\n"
+                         % (name, json.dumps(pair)))
+    if problems:
+        sys.stderr.write("chipbench: not correct: %s\n" % "; ".join(problems))
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

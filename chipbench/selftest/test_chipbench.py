@@ -3,8 +3,8 @@
 Nothing here measures anything.  The manifest and its data files are
 validated, a cell added as files only is found by name, the loop is run at
 ``BERT_TINY`` width, the trace reduction is held to hand-worked values on
-the trimmed recorded trace under ``testdata/``, and the FLOP and byte
-functions are held to ``bench.py``'s own arithmetic.
+the trimmed recorded traces under ``testdata/``, and the FLOP and byte
+functions are held to numbers worked by hand.
 """
 
 import copy
@@ -223,6 +223,13 @@ def test_drive_and_verify_on_bert_tiny(fused_qkv, monkeypatch):
         found = state["report"]["reference"]
         assert 0 < found["logits_rel_l2"] < 0.02
         assert state["op_names"] and state["kernels"] == {}
+        # each number that decided it, beside its limit
+        assert state["compared"]["logits_rel_l2"] == {
+            "value": found["logits_rel_l2"], "max": 0.02}
+        assert state["compared"]["loss_first"] == {
+            "value": state["losses"][0], "min": 6.0, "max": 8.0}
+        assert set(state["compared"]) == {
+            "loss_first", "loss_last_tenth", "logits_rel_l2", "loss_abs"}
         # the same check refuses a forward that is not the reference's
         real = mf.load_by_name
 
@@ -345,28 +352,37 @@ def test_peak_memory_is_the_runtime_counters_alone():
 
 # -- operations and bytes ----------------------------------------------------
 
-@pytest.mark.parametrize("seq_len", [128, 512])
-def test_bert_flops_equal_bench_py(seq_len):
-    import bench
+@pytest.mark.parametrize("seq_len, scored, per_token", [
+    # a layer: 8 d^2 + 4 d ff = 8 x 589,824 + 4 x 2,359,296 = 14,155,776,
+    # plus scores and context 4 T d = 3,072 T.  The head scores int(0.15 T)
+    # + 1 positions a sequence: 2 d V = 46,881,792 times scored / T.
+    # T 128: 12 x (14,155,776 + 393,216) = 174,587,904; head x 20 / 128 =
+    # 7,325,280; forward 181,913,184; training three forwards' worth
+    (128, 20, 545739552),
+    # T 512: 12 x (14,155,776 + 1,572,864) = 188,743,680; head x 77 / 512
+    # = 7,050,582; forward 195,794,262
+    (512, 77, 587382786),
+])
+def test_bert_flops_by_hand(seq_len, scored, per_token):
     from paddle_tpu.models import bert
 
     cell = mf.load_cell("bert_base_train_seq128_bs128")
     ours = mf.load_by_name("flops", "bert")
-    assert ours.max_pred(seq_len) == bert.default_max_pred(seq_len)
-    assert ours.train_flops_per_token(cell["config"], seq_len) == \
-        bench.model_train_flops_per_token(bert.BERT_BASE, seq_len)
+    assert ours.max_pred(seq_len) == scored == \
+        bert.default_max_pred(seq_len)
+    assert ours.train_flops_per_token(cell["config"], seq_len) == per_token
     assert ours.train_flops_per_example(
-        cell["config"], {"seq_len": seq_len}) == seq_len * \
-        bench.model_train_flops_per_token(bert.BERT_BASE, seq_len)
+        cell["config"], {"seq_len": seq_len}) == seq_len * per_token
 
 
-def test_resnet_flops_equal_bench_py():
-    import bench
-
+def test_resnet_flops_by_hand():
+    """4.09 G multiply-accumulates a forward image at 224 x 224 (the
+    torchvision / fvcore count), two operations each, three forwards'
+    worth a training step: 24.54 G."""
     cell = mf.load_cell("resnet50_train_bs128")
     ours = mf.load_by_name("flops", "resnet")
     assert ours.train_flops_per_example(cell["config"], cell["traffic"]) \
-        == bench.RESNET50_TRAIN_FLOPS_PER_IMAGE
+        == pytest.approx(24.54e9, rel=1e-12)
     with pytest.raises(ValueError):
         ours.train_flops_per_example(dict(cell["config"], depth=101), {})
 
@@ -428,6 +444,99 @@ def test_names_and_categories():
     assert xplane.kernel_of("jvp_fused_ln_fwd_", kernels) == \
         "jvp_fused_ln_fwd_"
     assert xplane.kernel_of("%fusion.12", kernels) is None
+
+
+@pytest.mark.parametrize("op_name, tag", [
+    # ``op_name`` paths of the kanana step's compiled text (the cell's
+    # builder at a small size, every layer under ``recompute()``), the
+    # path's last element, the primitive, shortened.  The forward pass:
+    ("jit(step_once)/pd2_recompute_block/pd15_mla_attention/dot_general",
+     "mla_attention"),
+    # the region's re-run, and its backward
+    ("jit(step_once)/pd22_recompute_block_grad/jvp(pd22_mla_attention)/"
+     "bhqd,bhkd->bhqk/dot_general", "mla_attention"),
+    ("jit(step_once)/pd22_recompute_block_grad/transpose(jvp("
+     "pd22_mla_attention))/jit(_where)/select_n", "mla_attention"),
+    ("jit(step_once)/pd23_recompute_block_grad/transpose(jvp(pd30_moe_route"
+     "))/jit(take_along_axis)/scatter-add", "moe_route"),
+    ("jit(step_once)/pd23_recompute_block_grad/transpose(jvp(pd42_moe_shared"
+     "))/jit(silu)/add_any", "moe_shared"),
+    # a part of an op, forward: two scopes, the innermost wins
+    ("jit(step_once)/pd2_recompute_block/pd37_moe_experts/while/body/"
+     "pd37_moe_experts.combine/scatter-add", "moe_experts.combine"),
+    ("jit(step_once)/pd23_recompute_block_grad/jvp(pd37_moe_experts)/"
+     "pd37_moe_experts.dispatch/jit(argsort)/sort", "moe_experts.dispatch"),
+    # a part in the backward of the layer's ``custom_vjp``: the program
+    # names it after the op it lowered last; the part is its op's
+    ("jit(step_once)/pd23_recompute_block_grad/transpose("
+     "pd23_recompute_block_grad)/jvp(pd37_moe_experts)/while/body/transpose("
+     "jvp(pd46_elementwise_add.products))/transpose(jvp())/concatenate",
+     "moe_experts.products"),
+    ("jit(step_once)/pd23_recompute_block_grad/transpose("
+     "pd23_recompute_block_grad)/jvp(pd37_moe_experts)/while/body/"
+     "pd46_elementwise_add.dispatch/jit(floor_divide)/sign",
+     "moe_experts.dispatch"),
+    # inside the op, outside any part
+    ("jit(step_once)/pd23_recompute_block_grad/transpose("
+     "pd23_recompute_block_grad)/jvp(pd37_moe_experts)/while/cond/lt",
+     "moe_experts"),
+    # the region's own: no inner scope
+    ("jit(step_once)/pd23_recompute_block_grad/optimization_barrier",
+     "recompute_block_grad"),
+    # outside any region, as before
+    ("jit(step_once)/pd19_lm_head/transpose(jvp())/dot_general", "lm_head"),
+    ("jit(step_once)/pd693_mul_grad/transpose(jvp())/dot_general",
+     "mul_grad"),
+    # a path with no scope of the Executor's
+    ("rw['decoder.layer1.moe.experts.0.down']", ""),
+    ("reduce_window_sum", ""),
+    # a name that merely ends in a scope's letters is none
+    ("jit(step_once)/xpd3_mul/dot_general", ""),
+])
+def test_the_tag_is_the_same_in_every_pass(op_name, tag):
+    assert xplane.program_op(op_name) == tag
+
+
+def test_an_event_that_holds_others_is_not_summed_with_them():
+    """Hand-made: a ``while`` [1100, 1700) over three events of its body
+    (two fusions and a kernel), a ``conditional`` over one, and a fusion
+    of its own; steps of 1000 ns, two in the window.  The body counts
+    once, the containers not at all; busy is the union as before."""
+    dev = {"modules": [["jit_step(1)", 0, 900], ["jit_step(1)", 1000, 900],
+                       ["jit_step(1)", 2000, 900]],
+           "ops": [["while.3", 1100, 600, "moe_experts"],
+                   ["fusion.1", 1100, 200, "moe_experts.dispatch"],
+                   ["ragged-dot-none.2", 1300, 300, ""],
+                   ["fusion.2", 1600, 100, "moe_experts.combine"],
+                   ["conditional.1", 1750, 50, "adam"],
+                   ["fusion.7", 1760, 30, "adam"],
+                   ["fusion.9", 2100, 400, "mul"]]}
+    out = xplane.reduce_trace({"devices": {"d": dev}, "host": []},
+                              {"ragged-dot-none"})
+    assert xplane.containers(dev["ops"]) == {id(dev["ops"][0]),
+                                             id(dev["ops"][4])}
+    assert out["steps"] == 2
+    assert out["busy_s"] == pytest.approx((600 + 50 + 400) * 1e-9)
+    assert out["tag_s"] == {
+        "moe_experts.dispatch": pytest.approx(100e-9),
+        "~ragged-dot-none": pytest.approx(150e-9),
+        "moe_experts.combine": pytest.approx(50e-9),
+        "adam": pytest.approx(15e-9), "mul": pytest.approx(200e-9)}
+    assert out["category_s"] == {
+        "other": pytest.approx(300e-9),       # the three of the loop's body
+        "optimizer": pytest.approx(15e-9),
+        "matmul/conv": pytest.approx(200e-9)}
+    assert out["kernel_s"] == {"ragged-dot-none": pytest.approx(150e-9)}
+    assert sum(out["category_s"].values()) <= out["busy_s"] / out["steps"]
+    # a loop whose body the trace does not show is an event like another
+    assert xplane.containers([["while.1", 0, 10, ""], ["b", 10, 5, ""],
+                              ["call.2", 14, 5, ""]]) == set()
+    # on the chip a copy holds an empty custom call, a fusion a
+    # slice-done: events that overlap, not bodies
+    assert xplane.containers([["copy.3014", 0, 172, ""],
+                              ["custom-call.1020", 0, 0, ""],
+                              ["fusion.7", 200, 50, "adam"],
+                              ["slice-done.2", 210, 5, ""]]) == set()
 
 
 def test_hlo_names_and_tags():

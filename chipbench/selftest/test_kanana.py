@@ -109,9 +109,8 @@ def test_flops_reproduce_the_issue_counts():
     # never top_k rows a token: that is eight chips' work
     assert fwd["routed_experts"] * 8 == pytest.approx(
         flops.forward_flops_per_token(config, 4096, 6.0)["routed_experts"])
-    flops.counted_rows = lambda config: {}
     with pytest.raises(RuntimeError):          # no counters: no guess
-        flops.train_flops_per_example(config, traffic)
+        flops.rows_per_token(config, {})
     per_example = flops.train_flops_per_example(
         config, traffic, flops.expected_rows_per_token(config))
     assert per_example / 4096 == pytest.approx(2.16e9, rel=2e-3)
@@ -171,7 +170,7 @@ def test_drive_and_verify_at_a_small_size():
         state = driver.drive(cell, 2800000123 % (2 ** 31 - 1), 0.5)
         assert driver.verify(state, cell, jax.devices()) == []
         counted = flops.counted_rows(KANANA_TINY)
-        rows = flops.rows_per_token(KANANA_TINY)
+        rows = flops.rows_per_token(KANANA_TINY, counted)
     assert not state["failed"] and state["compiles_in_window"] == 0
     found = state["report"]["reference"]
     assert 0 < found["logits_rel_l2"] < 0.03 and found["loss_abs"] < 0.05
@@ -179,6 +178,14 @@ def test_drive_and_verify_at_a_small_size():
     assert sorted(counted) == ["1", "2"]
     steps = 2 + state["steps"] + 2          # compile, warm-up, in flight
     assert {c["steps"] for c in counted.values()} == {steps}
+    # the loop read them where the window opened: after the step that
+    # compiled and the one that warmed up
+    at_window = state["counters"]["window"]
+    assert {c["steps"] for c in at_window.values()} == {2}
+    inside = flops.counted_between(at_window, counted)
+    assert {c["steps"] for c in inside.values()} == {state["steps"] + 2}
+    assert all(sum(c["rows"]) == sum(counted[k]["rows"])
+               - sum(at_window[k]["rows"]) for k, c in inside.items())
     assert 0 < rows <= 2 and rows == pytest.approx(
         2 * sum(sum(c["rows"]) for c in counted.values())
         / sum(c["possible"] for c in counted.values()))
@@ -226,17 +233,29 @@ def _recorded():
 
 
 def _ctx(recorded, counted=True):
+    """The counters as that run's readers took them: the whole run's
+    totals (``recorded``: PR 29, before the loop read them where its
+    windows open), here for the measured window's and for the traced
+    window's alike."""
     cell = mf.load_cell(CELL)
     flops = mf.load_by_name("flops", cell["config"]["flops"])
-    flops.counted_rows = lambda config: (
-        recorded["counted_rows"] if counted else {})
+    totals = recorded["counted_rows"] if counted else {}
+    twice = {k: {"rows": [2 * r for r in c["rows"]],
+                 "possible": 2 * c["possible"], "steps": 2 * c["steps"]}
+             for k, c in totals.items()}
     with open(os.path.join(BENCH, "peaks.json")) as f:
         peaks = json.load(f)["devices"]["TPU v5 lite"]
     trace = xplane.reduce_trace(recorded, set(recorded["kernels"]))
-    return {"cell": cell, "trace": trace, "peaks": peaks, "flops": flops}
+    return {"cell": cell, "trace": trace, "peaks": peaks, "flops": flops,
+            "state": {"counters": {"window": {}, "trace": totals,
+                                   "end": twice}}}
 
 
 def test_the_new_readers_read_the_recorded_trace():
+    """``kanana_trimmed.json`` (PR 29) keeps each op's tag as that PR's
+    reader worked it out, the forward pass's only; the arithmetic of the
+    readers is what this holds.  The tags of every pass are held on
+    ``kanana_passes_trimmed.json``, further down."""
     recorded = _recorded()
     ctx = _ctx(recorded)
     assert ctx["trace"]["steps"] == 3
@@ -266,6 +285,125 @@ def test_the_new_readers_read_the_recorded_trace():
     # an even spread
     assert rows / 4 / 16384 == pytest.approx(0.75, abs=0.15)
     assert values["moe_expert_load_max_over_mean"] < 4.0
+
+
+def _recorded_passes():
+    """``kanana_passes_trimmed.json`` (PR 35): ops carry an index into the
+    step's ``op_name`` strings as compiled; the tag is worked out here,
+    as ``xplane.read`` does it."""
+    with open(os.path.join(BENCH, "testdata",
+                           "kanana_passes_trimmed.json")) as f:
+        recorded = json.load(f)
+    for dev in recorded["devices"].values():
+        dev["ops"] = [[name, start, dur, recorded["op_names"][i]]
+                      for name, start, dur, i in dev["ops"]]
+    return recorded
+
+
+def test_the_backward_pass_reads_under_its_own_tags_on_a_recorded_trace():
+    """Every layer of the step is a ``recompute()`` region, so jax names
+    the re-run ``jvp(pd..)`` and the backward ``transpose(jvp(pd..))``.
+    Until PR 35 both read ``recompute_block_grad`` and
+    ``moe_route_ms_per_step`` the forward pass alone: 0.25915 ms a step in
+    this file's few ops (whole trace: 19.465); now every pass, 0.45685
+    (whole trace: 28.869).  Why the expected value moved: the yardstick,
+    not the program.  Both numbers are counted again below straight from
+    the ``op_name`` strings."""
+    recorded = _recorded_passes()
+    (dev,) = recorded["devices"].values()
+    lo, hi, steps = xplane.steady_window(dev)
+    assert steps == 2
+    inside = [o for o in dev["ops"] if o[1] >= lo and o[1] + o[2] <= hi]
+    loops = [o for o in inside if o[0].startswith("while")]
+    assert len(loops) == 2 * 8         # 4 layers: forward, and backward
+    assert all(o[3].endswith("_moe_experts)/while")
+               or o[3].endswith("_moe_experts/while") for o in loops)
+
+    def spent(pattern):     # ms a step, by the op_name's letters alone
+        import re
+        return sum(o[2] for o in inside if o not in loops
+                   and re.search(pattern, o[3])) / 1e6 / steps
+
+    tagged = [dict(d, ops=[[n, s, t, xplane.program_op(op_name)]
+                           for n, s, t, op_name in d["ops"]])
+              for d in recorded["devices"].values()]
+    trace = dict(recorded, devices={"/device:TPU:0": tagged[0]})
+    out = xplane.reduce_trace(trace, set(recorded["kernels"]))
+    ctx = {"trace": out}
+    route = mf.load_by_name("layer_metrics", "moe_route_ms_per_step")
+    every_pass = spent(r"_moe_route\)*/|\.(dispatch|combine)\)*/")
+    forward = spent(r"/pd\d+_moe_route/|/pd\d+_moe_experts\."
+                    r"(dispatch|combine)/")
+    assert route.read(ctx) == pytest.approx(every_pass)
+    assert every_pass == pytest.approx(0.4568505, abs=1e-7)
+    assert forward == pytest.approx(0.25915, abs=1e-7)
+    tag_s = out["tag_s"]
+    # the backward's parts are named, the program's slip of naming a
+    # part after another op is put right, the region keeps what is its own
+    assert {"mla_attention", "moe_shared", "dense_mlp", "lm_head",
+            "moe_experts.products", "moe_route", "rms_norm"} <= set(tag_s)
+    assert not any(t.startswith("elementwise_add.") for t in tag_s)
+    assert tag_s["recompute_block_grad"] == pytest.approx(
+        spent(r"recompute_block_grad/optimization_barrier$") / 1e3)
+    # the loops are counted by their bodies: nothing twice
+    assert sum(out["category_s"].values()) <= out["busy_s"] / steps
+    assert sum(out["category_s"].values()) + sum(
+        o[2] for o in loops) / 1e9 / steps > out["busy_s"] / steps
+    # the kernels' times are events of their own names: untouched
+    assert out["kernel_calls"]["flash_attention_dq"] == pytest.approx(
+        sum(1 for o in inside if o[0].startswith("flash_attention_dq"))
+        / steps)
+    assert mf.load_by_name("layer_metrics", "optimizer_ms_per_step").read(
+        ctx) == pytest.approx(spent(r"/pd\d+_adam/"))
+
+
+def test_the_counters_readers_read_a_windows_steps_alone():
+    """Two hand-made readings of one layer's counters, 5 steps of set-up
+    apart from 45 of the window, and a third after 18 traced steps.
+    Set-up sent every row to expert 0; the window spread 100 rows a step
+    evenly over 4 experts but for 10 more to expert 1; the traced steps
+    sent 40 a step to each."""
+    recorded = _recorded()
+    ctx = _ctx(recorded)
+    flops, config = ctx["flops"], ctx["cell"]["config"]
+    possible = 16384 * 6
+    window = {"1": {"rows": [5000, 0, 0, 0], "possible": 5 * possible,
+                    "moved": 5 * 8192, "steps": 5}}
+    trace = {"1": {"rows": [5000 + 45 * 25, 45 * 35, 45 * 25, 45 * 25],
+                   "possible": 50 * possible, "moved": 50 * 8192,
+                   "steps": 50}}
+    end = {"1": {"rows": [r + 18 * 40 for r in trace["1"]["rows"]],
+                 "possible": 68 * possible, "moved": 68 * 8192,
+                 "steps": 68}}
+    assert flops.counted_between(window, trace) == {"1": {
+        "rows": [45 * 25, 45 * 35, 45 * 25, 45 * 25],
+        "possible": 45 * possible, "moved": 45 * 8192, "steps": 45}}
+    assert flops.counted_between({}, window) == window
+    assert flops.counted_between(trace, trace) == {}      # not a step
+    ctx["state"] = {"counters": {"window": window, "trace": trace,
+                                 "end": end}}
+    ctx["measured"] = {"train_examples_per_s": 6.0}
+
+    def read(name):
+        return mf.load_by_name("layer_metrics", name).read(ctx)
+
+    # fullest 35 over the mean 27.5 a step; set-up's 5000 : 0 is not in it
+    assert read("moe_expert_load_max_over_mean") == pytest.approx(35 / 27.5)
+    # 110 rows a step of 98,304 choices: that share of top_k a token
+    rows = 6 * 110 / possible
+    assert flops.rows_per_token(config, flops.counted_between(
+        window, trace)) == pytest.approx(rows)
+    assert read("mfu") == pytest.approx(
+        100 * 6.0 * flops.train_flops_per_example(
+            config, ctx["cell"]["traffic"], rows) / 197e12)
+    # the roofline takes the traced steps' 160 rows a step, 4 experts
+    # with a row in each; the recorded kernels' time as it is
+    ragged = sum(s for k, s in ctx["trace"]["kernel_s"].items()
+                 if "ragged-dot" in k)
+    least = max(160 * 18 * 2048 * 768 / 197e12,
+                9 * (160 * (2048 + 768) + 4 * 2048 * 768) * 2 / 819e9)
+    assert read("moe_experts_roofline") == pytest.approx(
+        100 * least / ragged)
 
 
 def test_the_new_readers_return_nothing_where_there_is_nothing_to_read():

@@ -10,6 +10,7 @@ from those files.
 
 import json
 import os
+import statistics
 import sys
 
 import pytest
@@ -133,19 +134,110 @@ def _three_steps():
                     for n in (7, 8, 9)]}
 
 
-@pytest.mark.parametrize("cut, pairs", [
+@pytest.mark.parametrize("cut, leads", [
     # an execution handed over before the trace opened
     (lambda t: t["devices"]["d"]["modules"].insert(0, ["jit_step(1)", 1, 95]),
-     3),
+     [80] * 3),
+    # the same, begun 12 ns before the first dispatch did, inside the
+    # allowance (95 / 4): the counts make it the stranger, not the clock
+    (lambda t: t["devices"]["d"]["modules"].insert(
+        0, ["jit_step(1)", 690, 95]), [80] * 3),
     # the trace opened after step 7 was dispatched and before it ran
-    (lambda t: t["program"].pop(0), 2),
+    (lambda t: t["program"].pop(0), [80] * 2),
     # the trace closed before step 9 ran
-    (lambda t: t["devices"]["d"]["modules"].pop(), 2),
+    (lambda t: t["devices"]["d"]["modules"].pop(), [80] * 2),
+    # both ends cut at once, as many executions as steps: the stranger
+    # began a whole step before the first dispatch did, the last
+    # dispatch after the last execution
+    (lambda t: (t["devices"]["d"]["modules"].pop(),
+                t["devices"]["d"]["modules"].insert(
+                    0, ["jit_step(1)", 602, 95])), [80] * 2),
+    # an execution whose stamp lies 12 ns before its own dispatch began
+    # (PR 28's and PR 34's refusals: the planes' skew): it pairs, and
+    # the lead is reported as it reads
+    (lambda t: t["devices"]["d"]["modules"][1].__setitem__(1, 790),
+     [80, -20, 80]),
 ])
-def test_dispatch_lead_drops_the_ends_without_a_partner(cut, pairs):
+def test_dispatch_lead_drops_the_ends_without_a_partner(cut, leads):
     trace = _three_steps()
     cut(trace)
-    assert ps.dispatch_leads_ns(trace) == [80] * pairs
+    assert ps.dispatch_leads_ns(trace) == leads
+
+
+MS = 1000000
+
+
+def _idle_start(early_ns, steps=(40, 41, 42, 43)):
+    """A traced window as the loop makes one: a step of 100 ms; the first
+    dispatch begins at 5 ms on an idle device and lasts 4 ms; the device's
+    stamp for the first execution lies ``early_ns`` BEFORE that dispatch
+    begins (lag less skew, PERF.md 7); the later executions follow one
+    another, and each later dispatch begins 6 ms after the execution
+    before its own did."""
+    first = 5 * MS - early_ns
+    runs = [first + 100 * MS * k for k in range(len(steps))]
+    starts = [5 * MS] + [r + 6 * MS for r in runs[:-1]]
+    return {"devices": {"d": {"modules": [
+        ["jit_step(1)", r, 99 * MS] for r in runs]}},
+        "program": [["executor.dispatch", d, 4 * MS, n]
+                    for d, n in zip(starts, steps)]}
+
+
+@pytest.mark.parametrize("early_ns", [1, 400000, 1900000, 2 * MS, -2 * MS])
+def test_dispatch_lead_pairs_a_first_execution_stamped_before_its_dispatch(
+        early_ns):
+    """The reader that refused PRs 28 and 34 dropped this execution, faced
+    three with four steps and read nothing."""
+    trace = _idle_start(early_ns)
+    first = -early_ns - 4 * MS
+    assert ps.dispatch_leads_ns(trace) == [first] + [90 * MS] * 3
+    assert ps.dispatch_lead_ms(trace) == 90.0      # the median of four
+    # and with the window's other end cut as well
+    trace["devices"]["d"]["modules"].pop()
+    assert ps.dispatch_leads_ns(trace) == [first] + [90 * MS] * 2
+
+
+@pytest.mark.parametrize("name, shift_ms", [
+    (name, shift) for name in ("program_spans_seq128.json",
+                               "program_spans_dp4.json")
+    for shift in (-3, -2, -1, 1, 3)])
+def test_dispatch_lead_on_the_recordings_with_the_planes_skewed(name,
+                                                                shift_ms):
+    """Every device stamp moved against the host plane's: a number at
+    every skew, the unshifted reading plus the shift."""
+    rec = _recorded(name)
+    straight = ps.dispatch_leads_ns(rec)
+    for dev in rec["devices"].values():
+        for module in dev["modules"]:
+            module[1] += shift_ms * MS
+    assert ps.dispatch_leads_ns(rec) == [v + shift_ms * MS
+                                         for v in straight]
+    assert ps.dispatch_lead_ms(rec) == pytest.approx(
+        statistics.median(straight) / MS + shift_ms)
+
+
+@pytest.mark.parametrize("shift_ms", [0, -1, -2, -3, 1])
+def test_dispatch_lead_on_a_whole_recorded_window_from_an_idle_device(
+        shift_ms):
+    """The kanana cell's traced window as a run makes it (PR 35): ten
+    dispatches, ten executions, the first on an idle device 624,244 ns
+    after its dispatch begins.  As recorded both readers pair it; with
+    the device plane 1 ms earlier (the planes' skew was measured at
+    0.4-1.9 ms) that execution begins before its dispatch does, and the
+    reader of PRs 26-34 dropped it, faced nine with ten steps and read
+    nothing, at every shift below 0 here."""
+    rec = _recorded("program_spans_kanana_window.json")
+    for module in rec["devices"]["/device:TPU:0"]["modules"]:
+        module[1] += shift_ms * MS
+    dispatch = min((e for e in rec["program"]
+                    if e[0] == "executor.dispatch"), key=lambda e: e[3])
+    first = ps.step_executions(rec["devices"]["/device:TPU:0"])[0][0]
+    assert (first < dispatch[1]) == (shift_ms < 0)
+    leads = ps.dispatch_leads_ns(rec)
+    assert len(leads) == 10
+    assert leads[0] == -3000865 + shift_ms * MS
+    assert ps.dispatch_lead_ms(rec) == pytest.approx(
+        585.9092765 + shift_ms)         # the run's own result line, 0
 
 
 @pytest.mark.parametrize("breach, reason", [
@@ -159,9 +251,17 @@ def test_dispatch_lead_drops_the_ends_without_a_partner(cut, pairs):
     # a step dispatched twice
     (lambda t: t["program"].append(list(t["program"][0])),
      "twice for step 7"),
-    # an execution that starts before its own dispatch began
-    (lambda t: t["devices"]["d"]["modules"][1].__setitem__(1, 750),
+    # an execution that begins 42 ns before its own dispatch did, over
+    # the allowance of 95 / 4: no skew, an alignment off
+    (lambda t: (t["devices"]["d"]["modules"][1].__setitem__(1, 760),
+                t["devices"]["d"]["modules"][2].__setitem__(1, 860)),
      "before its dispatch began"),
+    # an execution more than steps, and it is not a stranger in front
+    (lambda t: t["devices"]["d"]["modules"].append(["jit_step(1)", 1090,
+                                                    95]),
+     "did not begin before the first dispatch"),
+    (lambda t: t["devices"]["d"]["modules"].clear(),
+     "no execution of the step module"),
     (lambda t: t["devices"].clear(), "no device plane"),
     (lambda t: t["program"].clear(), "no *.dispatch annotation"),
 ])

@@ -136,10 +136,17 @@ def quantile(values, q):
     return s[lo] + (s[hi] - s[lo]) * (pos - lo)
 
 
-def measure(exe, program, loss, pools, traffic, seconds, trace_dir=None):
+def measure(exe, program, loss, pools, traffic, seconds, trace_dir=None,
+            counters=dict):
     """Warm-up, the measured window and, where ``trace_dir`` is given, a
     traced window of ``trace_steps`` steps after it.  Returns the loop's
-    clocks and losses."""
+    clocks and losses, and under ``counters`` what ``counters()`` (the
+    program's device counters through the configuration's ``flops``
+    module; nothing for a configuration without any) read where the
+    window opened (``window``: a host sync before ``t_window``, so inside
+    set-up), where the traced window opened (``trace``) and where it
+    closed (``end``): a reader takes one from another and so counts a
+    window's steps alone."""
     import jax
 
     compiles = CompileCounter()
@@ -155,10 +162,11 @@ def measure(exe, program, loss, pools, traffic, seconds, trace_dir=None):
         warmup.append({"s": time.perf_counter() - t0,
                        "compiled": compiles.compiled[n0:]})
     before = compiles.compiles, compiles.traces
+    counted = {"window": counters()}
     t_window = time.perf_counter()
     window = loop.run(seconds=seconds)
     out = dict(window, compile_s=compile_s, t_window=t_window,
-               warmup=warmup,
+               warmup=warmup, counters=counted,
                compiles_in_window=compiles.compiles - before[0],
                traces_in_window=compiles.traces - before[1],
                losses=loop.losses, dispatch_s=loop.dispatch_s)
@@ -167,11 +175,13 @@ def measure(exe, program, loss, pools, traffic, seconds, trace_dir=None):
         # attributed to; the loop's own spans need only the host tracer
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
+        counted["trace"] = counters()
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         try:
             out["traced"] = loop.run(steps=traffic["trace_steps"])
         finally:
             jax.profiler.stop_trace()
+        counted["end"] = counters()
     return out
 
 
@@ -197,7 +207,12 @@ def drive(cell, seed, seconds, trace_dir=None, t_start=None):
     exe.run(startup)                       # the weights, on the device
     t_weights = time.perf_counter()
 
-    state = measure(exe, program, loss, pools, traffic, seconds, trace_dir)
+    flops = mf.load_by_name("flops", config["flops"]) \
+        if "flops" in config else None
+    counters = (lambda: flops.counted_rows(config)) \
+        if hasattr(flops, "counted_rows") else dict
+    state = measure(exe, program, loss, pools, traffic, seconds, trace_dir,
+                    counters)
     intervals = state["intervals_s"]
     state["window_losses"] = state["losses"][
         state["first"]:state["first"] + state["steps"]]
@@ -354,10 +369,27 @@ def compare_with_reference(exe, builder, cell, pools):
     return problems, measured
 
 
+def compared_with_limits(config, first_loss, head, tail, distances):
+    """Each number ``verify`` compared beside its limit, under short plain
+    names, for the result's line and the run's last lines."""
+    band = config["loss_band_first_step"]
+    out = {"loss_first": {"value": first_loss, "min": band[0],
+                          "max": band[1]},
+           "loss_last_tenth": {"value": tail, "below": head}}
+    for name, limits in config.get("reference", {}).get(
+            "tolerance", {}).items():
+        for kind, limit in limits.items():
+            key = "%s_%s" % (name, kind)
+            if key in distances:
+                out[key] = {"value": distances[key], "max": limit}
+    return out
+
+
 def verify(state, cell, devices):
     """Outside the window: the losses, the compiled step and the plain
     reference.  Returns the problems; adds ``kernels`` and ``op_names`` (for
-    the trace reduction) and its findings to ``state``."""
+    the trace reduction), ``compared`` (each number beside its limit) and
+    its findings to ``state``."""
     workload, config, traffic = (cell["workload"], cell["config"],
                                  cell["traffic"])
     t0 = time.perf_counter()
@@ -378,7 +410,9 @@ def verify(state, cell, devices):
     more, distances = compare_with_reference(
         state["exe"], state["builder"], cell, state["pools"])
     problems += more
-    state.update(kernels=kernels, op_names=op_names)
+    state.update(kernels=kernels, op_names=op_names,
+                 compared=compared_with_limits(
+                     config, state["losses"][0], head, tail, distances))
     state["report"].update(
         loss_first=state["losses"][0], loss_window_first_tenth=head,
         loss_window_last_tenth=tail, kernels=kernels,

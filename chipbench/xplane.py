@@ -25,16 +25,39 @@ import os
 import re
 
 HOST_SPAN_PREFIX = "chipbench."
-_PD_SCOPE = re.compile(r"pd(\d+)_([A-Za-z0-9_.]+?)(?:/|$)")
+# a scope of the Executor's in an ``op_name`` path, bare or inside any
+# nesting of the wrappers jax puts around a scope it differentiates
+# (``jvp(pd3_x)``, ``transpose(jvp(pd3_x))``, ``checkpoint(..)``): the
+# name ends at ``/``, at ``)`` or with the path
+_PD_SCOPE = re.compile(r"(?<![A-Za-z0-9_])pd(\d+)_([A-Za-z0-9_.]+)(?=[/)]|$)")
 _COLLECTIVE = re.compile(
     r"^%?(all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all|collective-broadcast)")
 
 
 def program_op(text):
-    """Innermost ``pd<idx>_<type>`` tag in a scope path, or ""."""
+    """The tag of the innermost ``pd<idx>_<tag>`` scope in an ``op_name``
+    path, or "": the same tag whichever pass the op belongs to, since jax
+    rewrites a scope inside a region it differentiates (every layer under
+    ``recompute()``) to ``jvp(pd..)`` for the re-run and
+    ``transpose(jvp(pd..))`` for the backward.
+
+    A part of an op (``ctx.part_scope``: ``pd<idx>_<op>.<part>``) lies
+    inside its op's scope and is named after THAT: ``<the enclosing op's
+    tag>.<part>``.  In the forward pass the two agree; the backward of a
+    ``custom_vjp`` is traced after its op's lowering has returned, and the
+    program then names the part after the op it lowered last
+    (``jvp(pd37_moe_experts)/while/body/transpose(jvp(
+    pd46_elementwise_add.products))`` in the kanana step: PERF.md 7)."""
     found = _PD_SCOPE.findall(text or "")
-    return found[-1][1] if found else ""
+    if not found:
+        return ""
+    tag = found[-1][1]
+    if "." in tag:
+        ops = [t for _, t in found[:-1] if "." not in t]
+        if ops:
+            return "%s.%s" % (ops[-1], tag.split(".", 1)[1])
+    return tag
 
 
 def newest_xplane(trace_dir):
@@ -206,6 +229,28 @@ def kernel_of(name, kernels):
 
 # -- reductions --------------------------------------------------------------
 
+CONTAINER_OPS = ("while", "conditional", "call")
+
+
+def containers(ops):
+    """``{id(op)}`` of the events that hold other events of their line: a
+    ``while`` (``conditional``, ``call``) is an event of its own over the
+    events of its body, and a sum over both counts the body twice.  By
+    the HLO name and by time: such an op, and the event after it on the
+    line begins and ends inside it.  (Time alone will not do: copies,
+    empty custom calls and a few fusions overlap other events on the
+    chip's traces without holding them.)"""
+    found, open_ops = set(), []
+    for op in sorted(ops, key=lambda o: (o[1], -o[2])):
+        while open_ops and open_ops[-1][1] + open_ops[-1][2] <= op[1]:
+            open_ops.pop()
+        if open_ops and op[1] + op[2] <= open_ops[-1][1] + open_ops[-1][2]:
+            found.add(id(open_ops[-1]))
+        if base_name(op[0]) in CONTAINER_OPS:
+            open_ops.append(op)
+    return found
+
+
 def reduce_trace(trace, kernels=()):
     """Everything the per-layer readers take from a trace, per step and
     averaged over the devices::
@@ -213,7 +258,8 @@ def reduce_trace(trace, kernels=()):
         steps, window_s, busy_s, idle_share,
         kernel_s: {kernel: seconds per step}, kernel_calls: {kernel: calls
         per step}, category_s: {category: seconds per step},
-        tag_s: {Program op, or ~hlo name where none: seconds per step},
+        tag_s: {Program op, or ~hlo name where none: seconds per step}
+        (both without the events that hold others: ``containers``),
         collective_s, collective_exposed_s (per step),
         gaps: [[host span, seconds]..] the ten longest idle gaps
 
@@ -232,11 +278,15 @@ def reduce_trace(trace, kernels=()):
         rest = union([o[1], o[1] + o[2]] for o in sync
                      if not is_collective(o[0]))
         kernel_s, kernel_calls, category_s, tag_s = {}, {}, {}, {}
-        for name, _, dur, tag in sync:
+        holds_others = containers(sync)
+        for op in sync:
+            name, _, dur, tag = op
             k = kernel_of(name, kernels)
             if k:
                 kernel_s[k] = kernel_s.get(k, 0.0) + dur / 1e9 / steps
                 kernel_calls[k] = kernel_calls.get(k, 0) + 1.0 / steps
+            if id(op) in holds_others:
+                continue        # its body's events count, not it too
             cat = category(name, tag)
             category_s[cat] = category_s.get(cat, 0.0) + dur / 1e9 / steps
             tag = tag or "~" + base_name(name)
