@@ -4,8 +4,10 @@ Drives the main path once on the attached TPU, through the public entry
 points, at the full width of BERT-base (L12 D768 H12 FF3072 V30522,
 random weights from a fixed seed), and fails loudly:
 
-    python chip_smoke.py            # one chip: train seq128, train seq512, serve
+    python chip_smoke.py            # one chip: train seq128, train seq512,
+                                    # the window's edge, serve
     python chip_smoke.py --chips 4  # four chips: data-parallel vs one chip, only
+    python chip_smoke.py --probe window_edge    # that phase alone
 
 Without a TPU it exits non-zero before building anything — there is no CPU
 branch and no small model.  Any phase that raises, loses a kernel, or
@@ -20,6 +22,7 @@ One process holds the chip(s); nothing is spawned.
 import argparse
 import copy
 import json
+import math
 import os
 import shutil
 import sys
@@ -134,6 +137,79 @@ def phase_train(name, cfg, seq_len, batch, steps, required_kernels):
           hlo_recompile_s=round(hlo_s, 2), losses=losses,
           fusion_families=report.counts(), kernels=dict(kernels))
     return losses
+
+
+def phase_window_edge(t=8192, heads=32, kv_heads=4, d=128, window=1024,
+                      keys=(0, 1023, 1024, 4095, 7168), batch=2):
+    """Where the flash kernels' band begins and ends, row for row, at the
+    shapes of the grouped-query cell (an edge off by one stays inside any
+    tolerance on logits; this does not).  ``k = 0``, so every visible key
+    weighs ``1 / n_i`` in row i (n_i keys visible); ``v`` is zero but for
+    ones at ONE key j0 a (batch, key-value head): row i of the output is
+    ``1 / n_i`` where j0 is visible to i and exactly 0 elsewhere, and the
+    visible rows must be ``j0 <= i < j0 + window`` (a sliding layer) or
+    ``i >= j0`` (a full one).  Backward with ``q_i = dO_i = e_(i mod d)``:
+    row i reaches ``dv[j0]`` with ``1 / n_i`` and ``dk[j0]`` with ``(1 /
+    n_i)(1 - 1 / n_i) / sqrt(d)``, both in feature ``i mod d``, from each
+    query head of the group: a row too many or too few moves one
+    feature's sum by an eighth."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                       routes_to_kernel)
+
+    group, rows = heads // kv_heads, np.arange(t)
+    slots = [(b, h) for b in range(batch) for h in range(kv_heads)]
+    if len(keys) > len(slots):
+        _fail("window_edge: %d keys for %d (batch, key-value head) slots"
+              % (len(keys), len(slots)))
+    probes = list(zip(slots, keys))
+    code = np.eye(d, dtype="float32")[rows % d]
+    q = jnp.asarray(np.broadcast_to(code, (batch, heads, t, d)),
+                    jnp.bfloat16)
+    k = jnp.zeros((batch, kv_heads, t, d), jnp.bfloat16)
+    v = np.zeros((batch, kv_heads, t, d), "float32")
+    for (b, h), j0 in probes:
+        v[b, h, j0] = 1.0
+    v = jnp.asarray(v, jnp.bfloat16)
+    if not routes_to_kernel(q, k, None, v):
+        _fail("window_edge: these shapes do not route to the flash kernels")
+    worst = {}
+    for kind, w in (("sliding", window), ("full", None)):
+        out, pullback = jax.vjp(lambda q, k, v, w=w: flash_attention(
+            q, k, v, causal=True, window=w), q, k, v)
+        _, dk, dv = pullback(q)
+        out, dk, dv = (np.asarray(x, "float64") for x in (out, dk, dv))
+        n = np.minimum(rows + 1, w or t)
+        for (b, h), j0 in probes:
+            seen = (rows >= j0) & (rows - j0 < (w or t))
+            p = np.where(seen, 1.0 / n, 0.0)
+            for head in range(h * group, (h + 1) * group):
+                got = out[b, head]
+                wrong = np.flatnonzero(((got != 0).any(axis=1)) != seen)
+                if wrong.size:
+                    _fail("window_edge: %s layer, key %d, head %d: rows %s "
+                          "%s it" % (kind, j0, head, wrong[:8].tolist(),
+                                     "do not see" if seen[wrong[0]]
+                                     else "see"))
+                off = np.abs(got[seen] / p[seen, None] - 1).max()
+                worst[kind] = max(worst.get(kind, 0.0), float(off))
+            for name, got, weight in (
+                    ("dv", dv[b, h, j0], p),
+                    ("dk", dk[b, h, j0], p * (1 - p) / math.sqrt(d))):
+                want = group * np.bincount(rows % d, weights=weight,
+                                           minlength=d)
+                off = np.abs(got - want).max() / np.abs(want).max()
+                worst[kind + "_" + name] = max(
+                    worst.get(kind + "_" + name, 0.0), float(off))
+    # bfloat16 rounds 1 / n_i by 2^-9; a row more or less is 1 in 8
+    if max(worst.values()) > 2e-2:
+        _fail("window_edge: values off by %s" % worst)
+    _emit("window_edge", seq_len=t, heads=heads, kv_heads=kv_heads,
+          window=window, keys=list(keys), rows="as stated, every head",
+          worst_relative=worst)
+    return worst
 
 
 def _encoder_program(cfg, seq_len):
@@ -307,6 +383,8 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the data-parallel path and the "
                          "one-chip run it is compared with")
+    ap.add_argument("--probe", choices=("window_edge",),
+                    help="run only this phase (one chip)")
     args = ap.parse_args(argv)
 
     import jax
@@ -337,7 +415,9 @@ def main(argv=None):
 
     base = copy.copy(bert.BERT_BASE)
     base.fused_ln = True
-    if args.chips == 1:
+    if args.probe:
+        phase_window_edge()
+    elif args.chips == 1:
         # the shipped flagship graph (bench.py child_bert): fused QKV at
         # seq128 only, fused LN, fuse_attn="auto", masked-gather head
         flagship = copy.copy(base)
@@ -347,6 +427,7 @@ def main(argv=None):
         phase_train("train_seq512", base, 512, 16, 4,
                     ("fused_ln_fwd", "fused_ln_bwd", "flash_attention_fwd",
                      "flash_attention_dkv", "flash_attention_dq"))
+        phase_window_edge()
         phase_serve(bert.BERT_BASE, 128, (1, 3, 2, 4, 1, 1, 2),
                     buckets=(2, 4))
     else:

@@ -1081,6 +1081,7 @@ def _compile_step(runner, build, program, feed_vals, fetch_names):
     ph.set_attr("compile_ms", round(ph.dur_ms, 2))
     _obs.record_compile(ph.dur_ms, runner=runner)
     _obs.record_moe_layers(program, ph)
+    _obs.record_attention_layers(program, ph)
     _register_compile_telemetry(compiled, program, feed_vals, fetch_names)
     return compiled
 

@@ -1118,11 +1118,15 @@ def fused_dropout_add_ln(x, residual, dropout_prob=0.0, epsilon=1e-5,
 
 
 def fused_multihead_attention(q, k, v, bias=None, causal=False, scale=None,
-                              dropout_rate=0.0, name=None):
-    """Fused multi-head attention over q, k: [B, H, T, Dh] and v: [B, H,
-    T, Dv] (Dv may differ from Dh and is the output's width); on TPU this
+                              dropout_rate=0.0, window=None, name=None):
+    """Fused multi-head attention over q: [B, H, T, Dh], k: [B, Hkv, T,
+    Dh] and v: [B, Hkv, T, Dv] (Dv may differ from Dh and is the output's
+    width; Hkv may divide H: query head h reads head ``h // (H // Hkv)``
+    of k and v, which are never expanded to H heads); on TPU this
     is a single Pallas flash-attention kernel (O(T) memory), elsewhere XLA
-    attention.  `scale` defaults to 1/sqrt(Dh).  `bias` is an additive
+    attention.  `scale` defaults to 1/sqrt(Dh).  `window` (with `causal`):
+    a query sees only the last `window` keys up to its own, and the
+    kernels visit only that band.  `bias` is an additive
     key bias ([B, Tk] or [B,1,1,Tk], e.g. a padding mask); no gradient flows to it.  dropout_rate applies
     attention-probability dropout INSIDE the kernel (train mode only) —
     the [B,H,T,T] mask never materializes in HBM."""
@@ -1136,6 +1140,8 @@ def fused_multihead_attention(q, k, v, bias=None, causal=False, scale=None,
         attrs["dropout_rate"] = float(dropout_rate)
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op(
         type="fused_multihead_attention",
         inputs=inputs,
@@ -1160,19 +1166,32 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 
 def rotary_embedding(x, rotary_dim=None, offset=0, theta=10000.0,
-                     interleaved=True, name=None):
+                     interleaved=True, frequency_scale=None, magnitude=1.0,
+                     name=None):
     """Rotary position embedding over x: [..., T, Dh], positions 0..T-1 on
     the last axis but one.  The ``rotary_dim`` features of a head from
     ``offset`` on are rotated (default: from ``offset`` to the end), the
     others pass through: a head that is part position-free, part rotary.
-    ``interleaved`` pairs features (2i, 2i+1); else (i, i + half)."""
+    ``interleaved`` pairs features (2i, 2i+1); else (i, i + half).  Pair
+    i turns by ``t * theta^(-2i/rotary_dim) * frequency_scale[i]``
+    (``frequency_scale``: ``rotary_dim / 2`` factors, for a table scaled
+    by frequency: interpolation, NTK, YaRN), and cos and sin are both
+    times ``magnitude``."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
+    rot = int(rotary_dim or x.shape[-1] - offset)
+    attrs = {"rotary_dim": rot, "offset": int(offset),
+             "theta": float(theta), "interleaved": bool(interleaved)}
+    if frequency_scale is not None:
+        if len(frequency_scale) != rot // 2:
+            raise ValueError("frequency_scale holds %d factors for %d pairs"
+                             % (len(frequency_scale), rot // 2))
+        attrs["frequency_scale"] = [float(f) for f in frequency_scale]
+    if magnitude != 1.0:
+        attrs["magnitude"] = float(magnitude)
     helper.append_op(
         type="rotary_embedding", inputs={"X": [x]}, outputs={"Out": [out]},
-        attrs={"rotary_dim": int(rotary_dim or x.shape[-1] - offset),
-               "offset": int(offset), "theta": float(theta),
-               "interleaved": bool(interleaved)})
+        attrs=attrs)
     return out
 
 
@@ -1187,17 +1206,20 @@ def swiglu(x, y, name=None):
 
 def moe_route(input, num_experts, top_k, scale=1.0, norm_topk_prob=True,
               center_bias=False, keep_input=None, param_attr=None,
-              bias_attr=None, name=None):
+              bias_attr=None, score_func="sigmoid", name=None):
     """The router of a top-k expert layer, over all ``num_experts``:
-    ``s = sigmoid(x W)`` in float32, the ``top_k`` largest of ``s + b``
-    chosen, gates ``scale * s_i / sum_chosen s_j`` (the sum left out
+    ``s = sigmoid(x W)`` in float32 (``score_func="softmax"``: the
+    softmax over all the experts), the ``top_k`` largest of ``s + b``
+    (of ``log s + b`` under ``"softmax"``) chosen, gates ``scale * s_i / sum_chosen s_j`` (the sum left out
     unless ``norm_topk_prob``).  Creates W [D, num_experts] and the
     correction bias b [num_experts], which takes no gradient.  Returns
     ``(index [N, top_k] int32, gate [N, top_k] float32)``, N the rows of
     ``input`` flattened to [N, D].
 
     ``center_bias``: in training the choice is made not with b but with
-    minus each expert's mean score over the step's rows (what balances
+    minus each expert's mean score over the step's rows (under
+    ``"softmax"``: minus the log-score that ``top_k / num_experts`` of
+    the rows give it more than; what balances
     the load of a model that is not trained yet; ``parallel/moe.py``
     ``sigmoid_topk_route``), and a third value is returned, the bias
     used [num_experts]: assign it to b after ``optimizer.minimize`` (b's
@@ -1207,6 +1229,8 @@ def moe_route(input, num_experts, top_k, scale=1.0, norm_topk_prob=True,
     ``keep_input``: a name; the rows the router read are left in a
     persistable variable of that name, the very numbers (behind an
     optimization barrier), for a check that routes them again."""
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError("moe_route has no score_func %r" % (score_func,))
     helper = LayerHelper("moe_route", **locals())
     w = helper.create_parameter(
         attr=helper.param_attr, shape=[input.shape[-1], num_experts],
@@ -1231,12 +1255,13 @@ def moe_route(input, num_experts, top_k, scale=1.0, norm_topk_prob=True,
         attrs={"top_k": int(top_k), "scale": float(scale),
                "norm_topk_prob": bool(norm_topk_prob),
                "center_bias": bool(center_bias),
-               "keep_input": bool(keep_input)})
+               "keep_input": bool(keep_input), "score_func": score_func})
     return (index, gate, used) if center_bias else (index, gate)
 
 
 def moe_experts(input, index, gate, expert_width, experts_held,
-                first_expert=0, param_attr=None, name=None):
+                first_expert=0, param_attr=None, experts_total=None,
+                name=None):
     """The part of a top-k expert layer that the ``experts_held`` experts
     from ``first_expert`` on give: ``sum_k gate[t,k] * E_index[t,k](x_t)``
     over the choices that name a held expert, ``E(x) = W_down (silu(W_gate
@@ -1248,7 +1273,11 @@ def moe_experts(input, index, gate, expert_width, experts_held,
     [D, F] and ``.down`` [F, D], e counted from 0 over the experts held,
     ``<name>`` and the initializer from ``param_attr`` (as a checkpoint of
     such a model stores them, an expert a tensor; the lowering stacks
-    them).  Returns ``(out, rows)``; ``rows`` [held] int32 are the rows
+    them).  ``experts_total``: how many experts ``index`` counts over;
+    where given, the loop that walks the routed rows takes blocks of one
+    and a half times the held experts' even share of a step's choices
+    (``parallel/moe.py`` ``block_rows``), else of 8,192 rows.  Returns
+    ``(out, rows)``; ``rows`` [held] int32 are the rows
     each held expert was given (see :func:`moe_count_rows`)."""
     helper = LayerHelper("moe_experts", **locals())
     d = input.shape[-1]
@@ -1273,18 +1302,20 @@ def moe_experts(input, index, gate, expert_width, experts_held,
                 "WUp": weights("up", [d, expert_width]),
                 "WDown": weights("down", [expert_width, d])},
         outputs={"Out": [out], "Rows": [rows]},
-        attrs={"first_expert": int(first_expert)})
+        attrs={"first_expert": int(first_expert),
+               "experts_total": int(experts_total or 0)})
     return out, rows
 
 
-def moe_count_rows(rows, index, layer, name=None):
+def moe_count_rows(rows, index, layer, experts_total=None, name=None):
     """Keeps an expert layer's counters on the device: a persistable
     int32 vector ``<name>`` of the rows given to each held expert so far,
     then the rows possible (tokens * top_k), the rows dispatch moved (a
     whole block for each block the layer's loop ran) and the steps,
     updated inside the step with no host sync.  ``observability.runtime.
     publish_moe_counters`` reads them into the metrics registry under the
-    label ``layer``.  Call it outside any recompute region."""
+    label ``layer``.  ``experts_total`` as :func:`moe_experts` was given
+    it (the block's size).  Call it outside any recompute region."""
     helper = LayerHelper("moe_count_rows", **locals())
     stats = helper.create_or_get_global_variable(
         name or "moe_rows.layer%s" % layer, shape=[rows.shape[0] + 3],
@@ -1294,7 +1325,9 @@ def moe_count_rows(rows, index, layer, name=None):
     helper.append_op(
         type="moe_count_rows",
         inputs={"Rows": [rows], "Index": [index], "Stats": [stats]},
-        outputs={"StatsOut": [stats]}, attrs={"layer": str(layer)})
+        outputs={"StatsOut": [stats]},
+        attrs={"layer": str(layer),
+               "experts_total": int(experts_total or 0)})
     return stats
 
 

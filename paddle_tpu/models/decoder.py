@@ -2,10 +2,23 @@
 the public API as ``models/bert.py`` is: pre-norm blocks ``h = x +
 Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, a final RMSNorm and an
 untied head.  The configuration's keys are the model's own (the names a
-DeepSeek-V3-family ``config.json`` gives them) plus what this device holds
-of it:
+DeepSeek-V3-family ``config.json`` gives them, and for grouped-query
+attention those of a ``layer_types`` / ``rope_parameters`` config) plus
+what this device holds of it:
 
-* attention: ``attention = "mla"``, latent attention.  ``q = W_q x`` as
+* attention, chosen in one place (``_attention``): ``attention = "gqa"``,
+  grouped-query heads: ``q = W_q x`` as ``num_attention_heads`` heads of
+  ``head_dim``, ``k = W_k x`` and ``v = W_v x`` as
+  ``num_key_value_heads``, rotary on the whole head in halves (pair
+  ``(i, i + head_dim / 2)``), causal attention in which query head h
+  reads key-value head ``h // group`` (``fused_multihead_attention``: K
+  and V stay ``num_key_value_heads`` wide all the way into the flash
+  kernels).  ``layer_types[i]`` says which kind layer i is:
+  ``sliding_attention`` sees the last ``sliding_window`` keys and turns
+  by ``rope_parameters["sliding_attention"]``, ``full_attention`` sees
+  all and turns by ``rope_parameters["full_attention"]`` (``rope_type``
+  ``default``, or ``yarn``: ``rotary_table``).
+  ``attention = "mla"`` (the default), latent attention.  ``q = W_q x`` as
   heads of ``[q_nope | q_rope]``; ``[c | k_rope] = W_kva x`` with ``c`` of
   ``kv_lora_rank`` and ONE ``k_rope`` shared by all heads; ``[k_nope | v]``
   a head ``= W_kvb RMSNorm(c)``; rotary on ``q_rope`` and ``k_rope``;
@@ -18,7 +31,8 @@ of it:
   ``num_experts_per_tok`` by score + correction bias, gates normalised and
   scaled by ``routed_scaling_factor``; with ``router_bias_from_batch`` a
   training step's correction bias is minus each expert's mean score over
-  the step's tokens, kept for test mode; with ``keep_router_input`` a
+  the step's tokens (under softmax scores, minus the log-score that the
+  top ``k / E`` of them give it more than), kept for test mode; with ``keep_router_input`` a
   test-mode program leaves what each router read in ``<layer>.moe.
   router.x``), the part of the result that the
   ``experts_held`` experts from ``first_expert`` on give
@@ -30,11 +44,13 @@ of it:
 * ``recompute``: each layer in ``fluid.layers.recompute()``.
 
 Each part is built under ``framework.device_tag`` (``mla_attention``,
-``dense_mlp``, ``moe_shared``, ``lm_head``; the router and the experts
-tag themselves), so a device profile reads by part, and each expert
+or ``swa_attention`` for a sliding and ``gqa_attention`` for a full
+grouped-query layer; ``dense_mlp``, ``moe_shared``, ``lm_head``; the
+router and the experts tag themselves), so a device profile reads by part, and each expert
 layer keeps its counters on the device (``layers.moe_count_rows``).
 """
 
+import functools
 import math
 
 import paddle_tpu as fluid
@@ -52,6 +68,27 @@ DECODER_TINY = {
     "routed_scaling_factor": 2.448, "norm_topk_prob": True,
     "initializer_range": 0.02, "router_bias_std": 0.01,
     "router_bias_from_batch": True, "recompute": True,
+}
+
+
+MELLUM_TINY = {
+    "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 4,
+    "attention": "gqa", "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "sliding_window": 24,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "rope_parameters": {
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 64, "beta_fast": 4,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782}},
+    "rms_norm_eps": 1e-6, "first_k_dense_replace": 0,
+    "moe_intermediate_size": 32, "n_routed_experts": 8, "experts_held": 2,
+    "first_expert": 0, "n_shared_experts": 0, "num_experts_per_tok": 2,
+    "scoring_func": "softmax", "routed_scaling_factor": 1.0,
+    "norm_topk_prob": True, "initializer_range": 0.02,
+    "router_bias_std": 0.01, "router_bias_from_batch": True,
+    "recompute": True,
 }
 
 
@@ -108,6 +145,73 @@ def _mla(x, cfg, prefix):
     return _linear(ctx, cfg["hidden_size"], prefix + ".o", cfg)
 
 
+def rotary_table(params, head_dim):
+    """``(frequency_scale, magnitude)`` of one ``rope_parameters``
+    section for ``layers.rotary_embedding``: None and 1 for ``rope_type``
+    ``default``.  ``yarn`` (static, whatever the sequence length): pair i
+    of a head turns ``f_i = theta^(-2i/head_dim)`` a position.  ``c(r) =
+    head_dim ln(original / (2 pi r)) / (2 ln theta)`` is the pair that
+    turns r times over the ``original_max_position_embeddings``; pairs up
+    to ``low = floor(c(beta_fast))`` keep ``f_i``, pairs from ``high =
+    ceil(c(beta_slow))`` on turn at ``f_i / factor``, those between blend
+    linearly, and cos and sin are times ``attention_factor``."""
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return None, 1.0
+    if kind != "yarn":
+        raise ValueError("models/decoder.py has no rope_type %r" % kind)
+    pairs = head_dim // 2
+
+    def pair_turning(rotations):
+        return (head_dim * math.log(
+            params["original_max_position_embeddings"]
+            / (2 * math.pi * rotations)) / (2 * math.log(params["rope_theta"])))
+
+    low = max(math.floor(pair_turning(params["beta_fast"])), 0)
+    high = min(math.ceil(pair_turning(params["beta_slow"])), pairs - 1)
+    ramp = [min(max((i - low) / max(high - low, 0.001), 0.0), 1.0)
+            for i in range(pairs)]
+    return ([1.0 - r + r / params["factor"] for r in ramp],
+            float(params["attention_factor"]))
+
+
+def _gqa(x, cfg, prefix, kind):
+    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    dh, rope = cfg["head_dim"], cfg["rope_parameters"][kind]
+    scale, magnitude = rotary_table(rope, dh)
+
+    def rotary(t):
+        return fluid.layers.rotary_embedding(
+            t, theta=rope["rope_theta"], interleaved=False,
+            frequency_scale=scale, magnitude=magnitude)
+
+    q = rotary(_heads(_linear(x, heads * dh, prefix + ".q", cfg), heads, dh))
+    k = rotary(_heads(_linear(x, kv * dh, prefix + ".k", cfg), kv, dh))
+    v = _heads(_linear(x, kv * dh, prefix + ".v", cfg), kv, dh)
+    ctx = fluid.layers.fused_multihead_attention(
+        q, k, v, causal=True, scale=1.0 / math.sqrt(dh),
+        window=cfg["sliding_window"] if kind == "sliding_attention"
+        else None)
+    ctx = fluid.layers.reshape(
+        fluid.layers.transpose(ctx, [0, 2, 1, 3]), [0, 0, heads * dh])
+    return _linear(ctx, cfg["hidden_size"], prefix + ".o", cfg)
+
+
+def _attention(i, cfg):
+    """Layer i's attention: ``(its device tag, its builder)``."""
+    kind = cfg.get("attention", "mla")
+    if kind == "mla":
+        return "mla_attention", _mla
+    if kind == "gqa":
+        layer = cfg["layer_types"][i]
+        if layer not in ("sliding_attention", "full_attention"):
+            raise ValueError("models/decoder.py has no layer type %r"
+                             % layer)
+        return ("swa_attention" if layer == "sliding_attention"
+                else "gqa_attention"), functools.partial(_gqa, kind=layer)
+    raise ValueError("models/decoder.py has no attention kind %r" % kind)
+
+
 def _swiglu_mlp(x, width, prefix, cfg):
     return _linear(
         fluid.layers.swiglu(_linear(x, width, prefix + ".gate", cfg),
@@ -125,6 +229,7 @@ def _expert_layer(x, cfg, prefix, train):
         x, cfg["n_routed_experts"], cfg["num_experts_per_tok"],
         scale=cfg["routed_scaling_factor"],
         norm_topk_prob=cfg["norm_topk_prob"],
+        score_func=cfg.get("scoring_func", "sigmoid"),
         center_bias=train and bool(cfg.get("router_bias_from_batch")),
         keep_input=prefix + ".router.x"
         if cfg.get("keep_router_input") and not train else None,
@@ -137,25 +242,26 @@ def _expert_layer(x, cfg, prefix, train):
     routed, rows = fluid.layers.moe_experts(
         x, index, gate, cfg["moe_intermediate_size"], cfg["experts_held"],
         first_expert=cfg.get("first_expert", 0),
+        experts_total=cfg["n_routed_experts"],
         param_attr=fluid.ParamAttr(name=prefix + ".experts",
                                    initializer=init))
-    with device_tag("moe_shared"):
-        shared = _swiglu_mlp(
-            x, cfg["n_shared_experts"] * cfg["moe_intermediate_size"],
-            prefix + ".shared", cfg)
-        out = fluid.layers.elementwise_add(routed, shared)
+    out = routed
+    if cfg["n_shared_experts"]:
+        with device_tag("moe_shared"):
+            shared = _swiglu_mlp(
+                x, cfg["n_shared_experts"] * cfg["moe_intermediate_size"],
+                prefix + ".shared", cfg)
+            out = fluid.layers.elementwise_add(routed, shared)
     return out, (rows, index), [(bias, u) for u in used]
 
 
 def _layer(x, i, cfg, train):
     prefix = "decoder.layer%d" % i
-    with device_tag("mla_attention"):
-        if cfg.get("attention", "mla") != "mla":
-            raise ValueError("models/decoder.py has no attention kind %r"
-                             % cfg["attention"])
+    tag, attention = _attention(i, cfg)
+    with device_tag(tag):
         h = fluid.layers.elementwise_add(
-            x, _mla(_rms_norm(x, prefix + ".ln1", cfg), cfg,
-                    prefix + ".attn"))
+            x, attention(_rms_norm(x, prefix + ".ln1", cfg), cfg,
+                         prefix + ".attn"))
     normed = _rms_norm(h, prefix + ".ln2", cfg)
     counted, used = None, []
     if i < cfg["first_k_dense_replace"]:
@@ -186,7 +292,8 @@ def decoder(input_ids, cfg, train=True):
             x, counted, used = _layer(x, i, cfg, train)
         # outside the region: state, not rematerialised
         if counted is not None and train:
-            fluid.layers.moe_count_rows(*counted, layer=i)
+            fluid.layers.moe_count_rows(
+                *counted, layer=i, experts_total=cfg["n_routed_experts"])
         for name, bias in used:
             fluid.layers.assign(
                 bias, output=x.block.program.global_block().var(name))

@@ -20,7 +20,7 @@ from .metrics import telemetry_enabled
 __all__ = [
     "record_step", "record_step_done", "record_jit_cache",
     "record_compile", "record_grad_residual_sites",
-    "record_flash_blocks",
+    "record_flash_blocks", "record_attention_layers",
     "record_moe_layers", "publish_moe_counters",
     "record_fusion_resolve", "record_feed_cache",
     "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
@@ -270,22 +270,53 @@ def record_flash_blocks(blocks, compile_phase):
     ``blocks[(kernel, kind)]`` as ``flash_attention.noting_blocks``
     counted them (kernel ``fwd`` | ``dkv`` | ``dq``; kind ``possible``:
     the whole rectangle, ``visited``: those a sweep computes, ``masked``:
-    visited blocks the causal diagonal crosses), summed over the sites: three
-    attributes of the block's ``compile`` phase, a step without a flash
-    kernel gets none."""
+    visited blocks the causal diagonal or a window's edge crosses; the
+    same three again as ``window_<kind>`` for the sites with a window),
+    summed over the sites: attributes ``flash_blocks_<kind>`` of the
+    block's ``compile`` phase, and ``flash_window_blocks_<kind>`` where a
+    site has a window; a step without a flash kernel gets none."""
     if not blocks:
         return
-    for kind in ("possible", "visited", "masked"):
-        compile_phase.set_attr(
-            "flash_blocks_" + kind,
-            sum(n for (_, k), n in blocks.items() if k == kind))
+    totals = collections.Counter()
+    for (_, kind), n in blocks.items():
+        totals["flash_window_blocks_" + kind[len("window_"):]
+               if kind.startswith("window_") else "flash_blocks_" + kind] += n
+    for name, n in sorted(totals.items()):
+        compile_phase.set_attr(name, n)
     if not telemetry_enabled():
         return
     for (kernel, kind), n in blocks.items():
-        _m.counter("flash_blocks_total",
-                   "grid blocks of the flash kernels in traced steps: "
-                   "possible, visited, and masked among the visited",
-                   kernel=kernel, kind=kind).inc(n)
+        if kind.startswith("window_"):
+            continue
+        windowed = blocks.get((kernel, "window_" + kind), 0)
+        for window, n in (("false", n - windowed), ("true", windowed)):
+            if n or window == "false":
+                _m.counter("flash_blocks_total",
+                           "grid blocks of the flash kernels in traced "
+                           "steps: possible, visited, and masked among the "
+                           "visited; window: of a site with a window",
+                           kernel=kernel, kind=kind, window=window).inc(n)
+
+
+def record_attention_layers(program, compile_phase):
+    """The fused attention sites of a newly compiled block, as attributes
+    of its ``compile`` phase: ``attention_layers_sliding`` (sites with a
+    window), ``attention_layers_full`` and ``kv_heads`` (of the first
+    site whose K has fewer heads than its Q, else of the first); a block
+    without a site gets none."""
+    sites = [op for b in getattr(program, "blocks", ()) for op in b.ops
+             if op.type == "fused_multihead_attention"]
+    if not sites:
+        return
+    sliding = sum(1 for op in sites if op.attrs.get("window"))
+    compile_phase.set_attr("attention_layers_sliding", sliding)
+    compile_phase.set_attr("attention_layers_full", len(sites) - sliding)
+    heads = [(op.block._find_var_recursive(op.input("Q")[0]).shape[1],
+              op.block._find_var_recursive(op.input("K")[0]).shape[1])
+             for op in sites]
+    compile_phase.set_attr(
+        "kv_heads", int(next((kv for h, kv in heads if kv != h),
+                             heads[0][1])))
 
 
 # stats var of each expert layer a compiled step holds -> its layer

@@ -518,14 +518,15 @@ def _run_recompute_grad(op, sub_block, env, ctx, run_block_fn):
         return tuple(e[n] for n in out_names)
 
     cap_vals = tuple(env[n] for n in cap_names)
+    gouts = {n: env[n] for n in gout_names
+             if n and n != EMPTY_VAR_NAME and env.get(n) is not None}
     if cap_vals:
-        cap_vals = jax.lax.optimization_barrier(cap_vals)
+        cap_vals, gouts = jax.lax.optimization_barrier((cap_vals, gouts))
     primal, vjp_fn = jax.vjp(f, cap_vals)
     cots = []
     for i, p in enumerate(primal):
         gname = gout_names[i] if i < len(gout_names) else EMPTY_VAR_NAME
-        g = env.get(gname) if gname and gname != EMPTY_VAR_NAME else None
-        cots.append(_nonzero_cotangent(g, p))
+        cots.append(_nonzero_cotangent(gouts.get(gname), p))
     (gcap,) = vjp_fn(tuple(cots))
     names = op.outputs.get("Captured@GRAD", [])
     for n, g, p in zip(names, gcap, cap_vals):

@@ -533,9 +533,13 @@ def _flash_site(ctx, attrs, Q, K, V, BiasQK=None):
 def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
     """Fused scaled-dot-product attention (reference analogue: the
     fusion_* attention kernels under ``paddle/fluid/operators/fused/``).
-    Q,K: [B, H, T, Dh]; V: [B, H, Tk, Dv], where Dv may differ from Dh
-    (latent attention: 192 against 128) and is the output's width;
-    BiasQK: additive key bias [B, Tk] or [B,1,1,Tk].  Lowered to the
+    Q: [B, H, T, Dh]; K: [B, Hkv, Tk, Dh]; V: [B, Hkv, Tk, Dv], where Dv
+    may differ from Dh (latent attention: 192 against 128) and is the
+    output's width, and Hkv may divide H (grouped-query heads: query
+    head h reads head ``h // (H // Hkv)`` of K and V, which are never
+    expanded); BiasQK: additive key bias [B, Tk] or [B,1,1,Tk]; attr
+    ``window`` (with ``causal``): a query sees the last ``window`` keys
+    up to its own.  Lowered to the
     Pallas FlashAttention-2 TPU kernel when
     profitable, XLA attention otherwise (ops/pallas/flash_attention.py);
     its backward is the custom-vjp flash backward, reached through the
@@ -562,7 +566,8 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
         rate = 0.0
     return flash_attention(Q, K, V, bias=BiasQK, causal=causal,
                            sm_scale=scale, dropout_rate=rate,
-                           dropout_seed=seed)
+                           dropout_seed=seed,
+                           window=attrs.get("window") or None)
 
 
 def _fused_ln_rate(ctx, attrs):
@@ -625,15 +630,22 @@ def rotary_embedding(ctx, attrs, X):
     positions 0..T-1 along the last axis but one; the ``rotary_dim``
     features from ``offset`` on are rotated, the rest pass through.
     ``interleaved``: pair i is features (2i, 2i+1) of the part, else
-    (i, i + rotary_dim/2); its angle is ``t * theta^(-2i/rotary_dim)``.
-    Computed in float32."""
+    (i, i + rotary_dim/2); its angle is ``t * theta^(-2i/rotary_dim)``,
+    times ``frequency_scale[i]`` where that attr is given (a factor a
+    pair: position interpolation, NTK and YaRN blends), and cos and sin
+    are both times ``magnitude``.  Computed in float32."""
     t, dh = jnp.shape(X)[-2], jnp.shape(X)[-1]
     offset = int(attrs.get("offset", 0))
     rot = int(attrs.get("rotary_dim", 0)) or dh - offset
     theta = float(attrs.get("theta", 10000.0))
     inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    if attrs.get("frequency_scale"):
+        inv = inv * jnp.asarray(attrs["frequency_scale"], jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)                  # [T, rot/2]
+    magnitude = float(attrs.get("magnitude", 1.0))
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     part = X[..., offset:offset + rot].astype(jnp.float32)
     if attrs.get("interleaved", True):
         pairs = part.reshape(part.shape[:-1] + (rot // 2, 2))
@@ -691,12 +703,15 @@ def _moe_experts_shapes(op, block):
              infer_shape=_moe_route_shapes)
 def moe_route(ctx, attrs, X, Weight, Bias):
     """The router of a top-k expert layer over ALL its experts
-    (``parallel/moe.py`` ``sigmoid_topk_route``).  X: [..., D]; Weight:
-    [D, E]; Bias: [E], added to the scores for the choice only.  Index:
+    (``parallel/moe.py`` ``sigmoid_topk_route``; attr ``score_func``
+    ``sigmoid`` or ``softmax``).  X: [..., D]; Weight:
+    [D, E]; Bias: [E], added to the scores (under ``softmax`` to their
+    logarithms) for the choice only.  Index:
     [N, top_k] int32 and Gate: [N, top_k] float32, N the rows of X;
     BiasOut: [E], the bias the choice was made with: Bias, or with
     ``center_bias`` outside test mode minus each expert's mean score
-    over these rows.  XOut (``keep_input``): the rows of X as the router
+    over these rows (``softmax``: minus the log-score that its share,
+    ``top_k / E``, of the rows lie above).  XOut (``keep_input``): the rows of X as the router
     read them, behind an optimization barrier: the compiler keeps more
     than bfloat16 between the ops it fuses, so a copy of X made by
     another op need not hold the very numbers this one was given."""
@@ -710,7 +725,8 @@ def moe_route(ctx, attrs, X, Weight, Bias):
     idx, gates, used = sigmoid_topk_route(
         x, Weight, Bias, int(attrs["top_k"]),
         float(attrs.get("scale", 1.0)),
-        bool(attrs.get("norm_topk_prob", True)), center)
+        bool(attrs.get("norm_topk_prob", True)), center,
+        attrs.get("score_func", "sigmoid"))
     return {"Index": idx, "Gate": gates, "BiasOut": used, "XOut": x}
 
 
@@ -731,7 +747,8 @@ def moe_experts(ctx, attrs, X, Index, Gate, WGate, WUp, WDown):
     out, rows = held_experts_ffn(
         X.reshape(-1, shape[-1]), Index, Gate, jnp.stack(WGate),
         jnp.stack(WUp), jnp.stack(WDown),
-        first=int(attrs.get("first_expert", 0)), scope=ctx.part_scope)
+        first=int(attrs.get("first_expert", 0)), scope=ctx.part_scope,
+        total=int(attrs.get("experts_total", 0)))
     return {"Out": out.reshape(shape), "Rows": rows}
 
 
@@ -741,14 +758,17 @@ def moe_count_rows(ctx, attrs, Rows, Index, Stats):
     """Adds one step to an expert layer's counters, on the device:
     Stats is int32 [held + 3], the rows given to each held expert so
     far, then the rows possible (tokens * top_k), the rows dispatch
-    moved (``parallel/moe.py``: ``BLOCK_ROWS`` times the blocks the
-    layer's loop ran) and the steps.  int32 wraps;
+    moved (``parallel/moe.py``: ``block_rows`` times the blocks the
+    layer's loop ran; attr ``experts_total`` as ``moe_experts``' own) and
+    the steps.  int32 wraps;
     ``observability.runtime.publish_moe_counters`` reads differences."""
-    from ..parallel.moe import BLOCK_ROWS, blocks_run
+    from ..parallel.moe import block_rows, blocks_run
 
+    block = block_rows(Index.size, Rows.shape[0],
+                       int(attrs.get("experts_total", 0)))
     step = jnp.concatenate([
         Rows.astype(jnp.int32),
-        jnp.stack([jnp.int32(Index.size), BLOCK_ROWS * blocks_run(Rows),
+        jnp.stack([jnp.int32(Index.size), block * blocks_run(Rows, block),
                    jnp.int32(1)])])
     return Stats + step
 

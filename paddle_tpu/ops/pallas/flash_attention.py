@@ -23,15 +23,24 @@ score matrix never touches HBM:
 Supported bias: an additive key-padding bias of shape [B, Tk] (the common
 [B,1,1,Tk] mask squeezed), broadcast over heads and query positions; it is
 treated as constant (no gradient — padding masks are data, not parameters).
-Causal masking is a flag.  A sweep's loop bounds come from where the
-diagonal lies: chunks wholly under it run without a mask, chunks it
-crosses run the same step with the mask (two loops over one body; the
-dK/dV kernel computes a square block in two parts that leave out the
-quarter above the diagonal: ``_tiles``), chunks above it are not visited
-at all; where a sequence needs several spans, a
-grid step whose span lies wholly above the diagonal runs no chunk and its
-block index is clamped to the last one fetched, so nothing is fetched for
-it either.
+Causal masking is a flag, and with it ``window``: key j is visible to
+query i iff ``j <= i`` and ``i - j < window`` (a band of ``window`` keys,
+the query's own among them).  A sweep's loop bounds come from where the
+diagonal and the window's edge lie: chunks wholly inside the band run
+without a mask, chunks the diagonal or the edge crosses run the same step
+with the mask (loops over one body; the dK/dV kernel computes a square
+block on the diagonal in two parts that leave out the quarter above it:
+``_tiles``), chunks above the diagonal or wholly behind the edge are not
+visited at all; where a sequence needs several spans, a grid step whose
+span lies wholly outside the band runs no chunk and its block index is
+clamped to the nearest one inside, so nothing is fetched for it either.
+
+Head groups: K and V may have fewer heads than Q (``[B, Hkv, T, d]``, ``H
+% Hkv == 0``); query head h reads key-value head ``h // (H // Hkv)``.
+The block specs of K and V (and of dK and dV) index the key-value head,
+so nothing ``[B, H, T, d]`` is ever made from K or V; the dK/dV kernel's
+sweep runs over the group's query heads as well as their Q chunks and
+writes a K block's dK and dV once, summed over the group.
 
 Q and K share one head width ``d`` (the contraction of the scores); V has
 its own, ``dv``, which is also the output's: latent attention carries a
@@ -181,16 +190,25 @@ def noting_blocks(counts):
         _noting.counts = was
 
 
-def _note_blocks(kernel, bh, nq, nk, block_q, block_k, causal):
+def _note_blocks(kernel, bh, nq, nk, block_q, block_k, causal, window=None):
     counts = getattr(_noting, "counts", None)
     if counts is None:
         return
-    seen = [j * block_k + block_k - 1 > i * block_q      # crossed?
-            for i in range(nq) for j in range(nk)
-            if not causal or j * block_k <= i * block_q + block_q - 1]
+    band = math.inf if window is None else window
+
+    def crossed(i, j):  # by the diagonal, or by the window's edge
+        return (j * block_k + block_k - 1 > i * block_q
+                or i * block_q + block_q - 1 - j * block_k >= band)
+
+    seen = [crossed(i, j) for i in range(nq) for j in range(nk)
+            if not causal or (j * block_k <= i * block_q + block_q - 1
+                              and i * block_q - (j * block_k + block_k - 1)
+                              < band)]
     for kind, n in (("possible", nq * nk), ("visited", len(seen)),
                     ("masked", sum(seen) if causal else 0)):
         counts[kernel, kind] += bh * n
+        if window is not None:  # the sites with a window, again
+            counts[kernel, "window_" + kind] += bh * n
 
 
 def _span(n, rows, row_bytes):
@@ -209,9 +227,20 @@ def _span(n, rows, row_bytes):
 def _sweep(ranges, step):
     """``step(c, masked)`` for the chunks ``lo <= c < hi`` of each
     ``(masked, lo, hi)``: a loop in the kernel, its bounds static (not
-    causal) or computed from where the diagonal lies."""
-    for masked, lo, hi in ranges:
-        if isinstance(hi, int) and isinstance(lo, int) and hi - lo == 1:
+    causal) or computed from where the diagonal and the window's edge
+    lie.  ``(masked, lo, hi, lo2, hi2)``: the chunks ``lo2 <= c < hi2``
+    too, in the same loop (the edge's chunks and the diagonal's are one
+    masked loop over one body, with the chunks between them left out)."""
+    for masked, lo, hi, *then in ranges:
+        if then:
+            first = _isub(hi, lo)
+            skip = _isub(then[0], hi)
+            jax.lax.fori_loop(
+                0, _iadd(first, _isub(then[1], then[0])),
+                lambda t, _, masked=masked, lo=lo, first=first, skip=skip:
+                step(_iadd(_iadd(lo, t), jax.lax.select(
+                    jax.lax.lt(t, first), 0, skip)), masked), None)
+        elif isinstance(hi, int) and isinstance(lo, int) and hi - lo == 1:
             step(lo, masked)  # not causal, one block: no loop
         else:
             jax.lax.fori_loop(
@@ -248,8 +277,8 @@ def _tiles(masked, block_q, block_k):
     products: its second copy of the step is traced once a site, the
     forward's would be twice, for a third less to save.)"""
     half = block_q // 2
-    if not masked or block_q != block_k or half % 128:
-        return [(0, block_q, 0, block_k)]
+    if masked is not True or block_q != block_k or half % 128:
+        return [(0, block_q, 0, block_k)]  # EDGE: no line corner to corner
     return [(0, half, 0, half), (half, half, 0, block_k)]
 
 
@@ -315,12 +344,13 @@ def _column(row):
     return jnp.broadcast_to(row.reshape(n, 1), (n, 128))
 
 
-def _scores(x, y, sm_scale, bias, offset, keys_down):
+def _scores(x, y, sm_scale, bias, offset, keys_down, window=None):
     """``x @ y.T * sm_scale (+ bias)`` in float32 from input-dtype
     operands.  ``offset`` (None: no mask) is the first key's position
     less the first query's: the score of query r and key c of the block
-    is visible iff ``r - c >= offset``; ``keys_down`` says the keys run
-    down the rows (the dK/dV kernel's orientation)."""
+    is visible iff ``r - c >= offset`` and, under a ``window``, ``r - c <
+    offset + window``; ``keys_down`` says the keys run down the rows (the
+    dK/dV kernel's orientation)."""
     # matmuls run at the INPUT dtype with f32 accumulation: under bf16
     # AMP the MXU's bf16 rate is ~4x its f32 rate, and bf16xbf16->f32 is
     # bit-identical to upcast-then-f32 (bf16 casts are exact;
@@ -333,21 +363,63 @@ def _scores(x, y, sm_scale, bias, offset, keys_down):
     if offset is not None:
         r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        seen = jax.lax.ge(_sub(c, r) if keys_down else _sub(r, c), offset)
+        ahead = _sub(c, r) if keys_down else _sub(r, c)
+        seen = jax.lax.ge(ahead, offset)
+        if window is not None:
+            seen = jax.lax.bitwise_and(
+                seen, jax.lax.lt(ahead, _iadd(offset, window)))
         s = _select(seen, s, NEG_INF)
     return s
 
 
-def _k_sweep(causal, i, J, span, block_q, block_k):
+EDGE = "edge"  # masked, by a window's edge too: truthy, and not True
+
+
+def _k_sweep(causal, window, i, J, span, block_q, block_k):
     """The K chunks of grid step ``J``'s span that Q block ``i`` sees:
-    those wholly under the diagonal unmasked, then those it crosses."""
+    those wholly under the diagonal unmasked, then those it crosses;
+    under a ``window``, those wholly inside the band, then those its
+    edge crosses with those the diagonal crosses, and none behind the
+    edge."""
     if not causal:
         return [(False, 0, span)]
     first, base = _imul(i, block_q), _imul(J, span)
     whole = _within(_isub(_div(_iadd(first, 1), block_k), base), span)
     seen = _within(_isub(_iadd(_div(_iadd(first, block_q - 1), block_k), 1),
                          base), span)
-    return [(False, 0, whole), (True, whole, seen)]
+    if window is None:
+        return [(False, 0, whole), (True, whole, seen)]
+    # keys before ``first - window + 1`` lie behind the edge for every
+    # query of the block; from ``first + block_q - window`` on, for none
+    edge = _within(_isub(_div(jax.lax.max(_isub(first, window - 1), 0),
+                              block_k), base), span)
+    inside = jax.lax.min(whole, _within(_isub(_div(jax.lax.max(
+        _iadd(first, block_q - window + block_k - 1), 0), block_k), base),
+        span))
+    return [(False, inside, whole), (EDGE, edge, inside, whole, seen)]
+
+
+def _q_sweep(causal, window, j, I, span, block_q, block_k):
+    """The Q chunks of grid step ``I``'s span that see K block ``j``:
+    those the diagonal crosses, then those under it; under a ``window``
+    the latter end where its edge begins to cross, the chunks it crosses
+    share the diagonal's loop (whole blocks then: ``_tiles``), and none
+    follow them."""
+    if not causal:
+        return [(False, 0, span)]
+    first, base = _imul(j, block_k), _imul(I, span)
+    seen = _within(_isub(_div(first, block_q), base), span)
+    whole = _within(_isub(
+        _div(_iadd(first, block_k + block_q - 2), block_q), base), span)
+    if window is None:
+        return [(True, seen, whole), (False, whole, span)]
+    # a query from ``first + window`` on has lost the block's first key,
+    # one from ``first + block_k - 1 + window`` on its last
+    inside = _within(_isub(_div(_iadd(first, window), block_q), base), span)
+    last = _within(_isub(_iadd(_div(
+        _iadd(first, window + block_k - 2), block_q), 1), base), span)
+    crossed = jax.lax.min(whole, inside)
+    return [(EDGE, seen, crossed, inside, last), (False, crossed, inside)]
 
 
 # ---------------------------------------------------------------------------
@@ -355,7 +427,8 @@ def _k_sweep(causal, i, J, span, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
-                has_bias, block_q, block_k, dropout_rate, dropout_debug):
+                window, has_bias, block_q, block_k, dropout_rate,
+                dropout_debug):
     bias_ref = rest[0] if has_bias else None
     seed_ref, o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = \
         rest[has_bias:]
@@ -376,7 +449,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
         s = _scores(
             q_ref[0], k_ref[0, keys, :], sm_scale,
             bias_ref[0, :, keys].astype(jnp.float32) if has_bias else None,
-            _isub(k_pos, q_pos) if masked else None, False)
+            _isub(k_pos, q_pos) if masked else None, False, window)
         # m and l stay in their (bq, 128) form, every lane the row's
         # number: the only reductions of a step are the scores' own
         m_prev = m_ref[:]
@@ -400,7 +473,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
                 _cast(p, v), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
 
-    _sweep(_k_sweep(causal, i, J, span, block_q, block_k), _step)
+    _sweep(_k_sweep(causal, window, i, J, span, block_q, block_k), _step)
 
     @pl.when(J == n_major - 1)
     def _finalize():
@@ -418,52 +491,71 @@ def _operands(q, k, v, bias, q_rows, k_rows, blocks_of):
     """``spec`` and the specs and arguments every kernel starts with, q,
     k, v (, bias), in blocks of ``q_rows`` and ``k_rows`` rows.
     ``blocks_of(*grid indices) -> (b, i, j)`` says which blocks a grid
-    step holds; ``spec(shape, index)`` is the BlockSpec at
-    ``index(b, i, j)``."""
+    step holds, ``b`` the query's batch·head; ``spec(shape, index)`` is
+    the BlockSpec at ``index(b, i, j)``.  K and V are indexed by their
+    own head, ``b // group``."""
+    group = q.shape[0] // k.shape[0]
+
     def spec(shape, index):
         return pl.BlockSpec(shape, lambda *g: index(*blocks_of(*g)))
 
+    def kv(b, i, j):
+        return (b if group == 1 else _div(b, group), j, 0)
+
     specs = [spec((1, q_rows, q.shape[2]), lambda b, i, j: (b, i, 0)),
-             spec((1, k_rows, k.shape[2]), lambda b, i, j: (b, j, 0)),
-             spec((1, k_rows, v.shape[2]), lambda b, i, j: (b, j, 0))]
+             spec((1, k_rows, k.shape[2]), kv),
+             spec((1, k_rows, v.shape[2]), kv)]
     args = [q, k, v]
     if bias is not None:
-        nheads = k.shape[0] // bias.shape[0]
+        nheads = q.shape[0] // bias.shape[0]
         specs.append(spec((1, 1, k_rows),
                           lambda b, i, j: (b // nheads, 0, j)))
         args.append(bias.reshape(bias.shape[0], 1, k.shape[1]))
     return spec, specs, args
 
 
-def _q_major(causal, block_q, k_rows, n_major):
+def _clamped(J, lo=None, hi=None):
+    """Span ``J`` of a sweep, or the nearest that the band of its block
+    touches (``lo .. hi``, None: the sequence's end): a step outside the
+    band runs no chunk, and a block index that repeats is not fetched
+    again."""
+    if hi is not None:
+        J = jnp.minimum(J, hi)
+    return J if lo is None else jnp.maximum(J, lo)
+
+
+def _q_major(causal, window, block_q, k_rows, n_major):
     """``blocks_of`` for a grid (b, Q block i, span J of K): a causal
     step whose span lies wholly above the diagonal is given the last
-    span under it, and a block index that repeats is not fetched
-    again."""
+    span under it, one wholly behind the window's edge the first span
+    the band touches."""
     if not causal or n_major == 1:
         return lambda b, i, J: (b, i, J)
-    return lambda b, i, J: (b, i, jnp.minimum(
-        J, _div(i * block_q + block_q - 1, k_rows)))
+    return lambda b, i, J: (b, i, _clamped(
+        J, None if window is None
+        else _div(jnp.maximum(i * block_q - (window - 1), 0), k_rows),
+        _div(i * block_q + block_q - 1, k_rows)))
 
 
 def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
-               interpret, dropout_rate, dropout_debug):
+               interpret, dropout_rate, dropout_debug, window=None):
     bh, tq, d = q.shape
     _, tk, dv = v.shape
     nq, nk = tq // block_q, tk // block_k
-    _note_blocks("fwd", bh, nq, nk, block_q, block_k, causal)
+    _note_blocks("fwd", bh, nq, nk, block_q, block_k, causal, window)
     span = _span(nk, block_k, (d + dv) * k.dtype.itemsize)
     k_rows = span * block_k
     _, in_specs, args = _operands(
         q, k, v, bias, block_q, k_rows,
-        _q_major(causal, block_q, k_rows, nk // span))
+        _q_major(causal, window, block_q, k_rows, nk // span))
     stat = pl.BlockSpec((1, 1, block_q), lambda b, i, J: (b, 0, i))
 
     o, m_out, l_out = pl.pallas_call(
         functools.partial(
             _fwd_kernel, n_major=nk // span, sm_scale=sm_scale, causal=causal,
-            has_bias=bias is not None, block_q=block_q, block_k=block_k,
-            dropout_rate=dropout_rate, dropout_debug=dropout_debug),
+            window=window, has_bias=bias is not None, block_q=block_q,
+            block_k=block_k, dropout_rate=dropout_rate,
+            dropout_debug=dropout_debug),
         name="flash_attention_fwd",
         grid=(bh, nq, nk // span),
         in_specs=in_specs + [pl.BlockSpec(memory_space=pltpu.SMEM)],
@@ -490,20 +582,27 @@ def _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
-                    has_bias, block_q, block_k, dropout_rate,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, n_major, group, sm_scale,
+                    causal, window, has_bias, block_q, block_k, dropout_rate,
                     dropout_debug):
     """dK and dV of one K block over its sweep of Q chunks, with the keys
     down the rows of every score-shaped value (the transpose of the
     forward's): the rows' statistics m, l, delta are then lane vectors
-    used as saved, and P^T dO and dS^T Q are plain products."""
+    used as saved, and P^T dO and dS^T Q are plain products.  The grid's
+    last axis walks the ``group`` query heads that read this key-value
+    head, and each one's ``n_major`` spans of Q and dO: the sums of all
+    of them are written once."""
     bias_ref = rest[0] if has_bias else None
     seed_ref, do_ref, m_ref, l_ref, dl_ref, dk_ref, dv_ref, dk_acc, \
         dv_acc, *bias_col = rest[has_bias:]
-    b, j, I = pl.program_id(0), pl.program_id(1), _major(2, n_major)
+    b, j, G = pl.program_id(0), pl.program_id(1), _major(2, group * n_major)
+    I = G
+    if group > 1:  # b: the key-value head's; the query head's for dropout
+        b = _iadd(_imul(b, group), _div(G, n_major))
+        I = jax.lax.rem(G, n_major) if n_major > 1 else 0
     span = q_ref.shape[1] // block_q
 
-    @pl.when(I == 0)
+    @pl.when(G == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -531,7 +630,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
                 k_ref[0, keys, :], q, sm_scale,
                 _lanes(bias_col[0][keys, :], qn) if has_bias else None,
                 _isub(_iadd(k_pos, k0), _iadd(q_pos, q0)) if masked
-                else None, True)
+                else None, True, window)
             p = _mul(_exp(_sub(s, _down(m_ref[0, :, rows], kn))),
                      _down(jax.lax.div(1.0, l_ref[0, :, rows]), kn))
             # dP^T = V @ dO^T
@@ -558,23 +657,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
                 preferred_element_type=jnp.float32,
             ))
 
-    if causal:  # the Q chunks the diagonal crosses, then those under it
-        first, base = _imul(j, block_k), _imul(I, span)
-        seen = _within(_isub(_div(first, block_q), base), span)
-        whole = _within(_isub(
-            _div(_iadd(first, block_k + block_q - 2), block_q), base), span)
-        _sweep([(True, seen, whole), (False, whole, span)], _step)
-    else:
-        _sweep([(False, 0, span)], _step)
+    _sweep(_q_sweep(causal, window, j, I, span, block_q, block_k), _step)
 
-    @pl.when(I == n_major - 1)
+    @pl.when(G == group * n_major - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
-                   has_bias, block_q, block_k, dropout_rate, dropout_debug):
+                   window, has_bias, block_q, block_k, dropout_rate,
+                   dropout_debug):
     bias_ref = rest[0] if has_bias else None
     seed_ref, do_ref, m_ref, l_ref, dl_ref, dq_ref, dq_acc, m_col, \
         linv_col, dl_col = rest[has_bias:]
@@ -597,7 +690,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
         s = _scores(
             q_ref[0], k, sm_scale,
             bias_ref[0, :, keys].astype(jnp.float32) if has_bias else None,
-            _isub(k_pos, q_pos) if masked else None, False)
+            _isub(k_pos, q_pos) if masked else None, False, window)
         p = _mul(_exp(_sub(s, _lanes(m_col[:], block_k))),
                  _lanes(linv_col[:], block_k))
         dp = jax.lax.dot_general(
@@ -614,7 +707,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
             preferred_element_type=jnp.float32,
         ))
 
-    _sweep(_k_sweep(causal, i, J, span, block_q, block_k), _step)
+    _sweep(_k_sweep(causal, window, i, J, span, block_q, block_k), _step)
 
     @pl.when(J == n_major - 1)
     def _finalize():
@@ -622,18 +715,20 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, n_major, sm_scale, causal,
 
 
 def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
-               block_q, block_k, interpret, dropout_rate, dropout_debug):
+               block_q, block_k, interpret, dropout_rate, dropout_debug,
+               window=None):
     bh, tq, d = q.shape
-    _, tk, d_v = v.shape
+    bkv, tk, d_v = v.shape
+    group = bh // bkv
     nq, nk = tq // block_q, tk // block_k
     item = q.dtype.itemsize
 
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, None, :]  # [bh, 1, tq], matching the saved m/l row layout
-    kw = dict(sm_scale=sm_scale, causal=causal, has_bias=bias is not None,
-              block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
-              dropout_debug=dropout_debug)
+    kw = dict(sm_scale=sm_scale, causal=causal, window=window,
+              has_bias=bias is not None, block_q=block_q, block_k=block_k,
+              dropout_rate=dropout_rate, dropout_debug=dropout_debug)
 
     def operands(q_rows, k_rows, blocks_of):
         """... then seed, dO, m, l, delta: the rest of both kernels'."""
@@ -645,29 +740,37 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
                   stat, stat, stat]
         return specs, args + [seed, do, m, l, delta]
 
-    # --- dK/dV: a K block's sweep of Q chunks ---
-    _note_blocks("dkv", bh, nq, nk, block_q, block_k, causal)
+    # --- dK/dV: a K block's sweep of the group's heads and their Q chunks ---
+    _note_blocks("dkv", bh, nq, nk, block_q, block_k, causal, window)
     span = _span(nq, block_q, (d + d_v) * item + 12)
-    q_rows = span * block_q
-    if causal and nq > span:  # a span above the diagonal: see _q_major
-        def blocks_of(b, j, I):
-            return b, jnp.maximum(I, _div(j * block_k, q_rows)), j
-    else:
-        def blocks_of(b, j, I):
-            return b, I, j
+    q_rows, n_major = span * block_q, nq // span
+
+    def blocks_of(b, j, G):
+        """Grid (key-value head b, K block j, G = query head of the group
+        x span I of Q): a span outside the band, see _q_major."""
+        I = G
+        if group > 1:
+            b, I = b * group + _div(G, n_major), jax.lax.rem(G, n_major)
+        if causal and n_major > 1:
+            I = _clamped(I, _div(j * block_k, q_rows),
+                         None if window is None else _div(
+                             j * block_k + block_k + window - 2, q_rows))
+        return b, I, j
+
     specs, args = operands(q_rows, block_k, blocks_of)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_major=nq // span, **kw),
+        functools.partial(_bwd_dkv_kernel, n_major=n_major, group=group,
+                          **kw),
         name="flash_attention_dkv",
-        grid=(bh, nk, nq // span),
+        grid=(bkv, nk, group * n_major),
         in_specs=specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, I: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, j, I: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, G: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, G: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d_v), v.dtype),
+            jax.ShapeDtypeStruct((bkv, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, tk, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -678,11 +781,11 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
     )(*args)
 
     # --- dQ: a Q block's sweep of K chunks ---
-    _note_blocks("dq", bh, nq, nk, block_q, block_k, causal)
+    _note_blocks("dq", bh, nq, nk, block_q, block_k, causal, window)
     span = _span(nk, block_k, (d + d_v) * item)
     k_rows = span * block_k
-    specs, args = operands(block_q, k_rows,
-                           _q_major(causal, block_q, k_rows, nk // span))
+    specs, args = operands(block_q, k_rows, _q_major(
+        causal, window, block_q, k_rows, nk // span))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, n_major=nk // span, **kw),
         name="flash_attention_dq",
@@ -703,26 +806,35 @@ def _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal, sm_scale,
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
-                  dropout_rate=0.0, seed=None, debug=False):
-    """Plain-XLA multi-head attention. q,k: [B,H,T,D]; v: [B,H,Tk,Dv];
-    bias: [B,Tk].
+                  dropout_rate=0.0, seed=None, debug=False, window=None):
+    """Plain-XLA multi-head attention. q: [B,H,T,D]; k: [B,Hkv,Tk,D]; v:
+    [B,Hkv,Tk,Dv], query head h reading head ``h // (H // Hkv)`` of k
+    and v; bias: [B,Tk]; ``window`` (with ``causal``): only the last
+    ``window`` keys up to a query's own are visible to it.
     With dropout: upscale-in-train on the probabilities; the mask comes
     from the debug position hash (bit-matching the kernel's debug mode)
     or jax.random (statistically matching the kernel's hardware PRNG)."""
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    heads, group = q.shape[1], q.shape[1] // k.shape[1]
+    if group > 1:  # the group's heads as rows of their key-value head's
+        q = q.reshape(q.shape[0], k.shape[1], group * q.shape[2], d)
     # matmuls run in the INPUT dtype (bf16 under AMP → full-rate MXU;
     # upcasting the operands to f32 would quarter the matmul rate) with
     # f32 accumulation; softmax statistics stay f32 either way
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * sm_scale
+    if group > 1:
+        s = s.reshape(s.shape[0], heads, -1, s.shape[-1])
     if bias is not None:
         s = s + bias[:, None, None, :].astype(jnp.float32)
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate and dropout_rate > 0.0:
@@ -737,8 +849,12 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                 jax.random.PRNGKey(sd[0]), 1.0 - dropout_rate, p.shape)
         keep = jax.lax.stop_gradient(keep)
         p = jnp.where(keep, p, 0.0) / (1.0 - dropout_rate)
+    if group > 1:
+        p = p.reshape(p.shape[0], v.shape[1], -1, p.shape[-1])
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
                    preferred_element_type=jnp.float32)
+    if group > 1:
+        o = o.reshape(o.shape[0], heads, -1, o.shape[-1])
     return o.astype(q.dtype)
 
 
@@ -850,27 +966,25 @@ def routes_to_kernel(q, k, bias=None, v=None):
         dv=None if v is None else v.shape[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
-           interpret, dropout_rate, dropout_debug):
+           interpret, dropout_rate, dropout_debug, window=None):
     o, _, _ = _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q,
-                         block_k, interpret, dropout_rate, dropout_debug)
+                         block_k, interpret, dropout_rate, dropout_debug,
+                         window)
     return o
 
 
-def _flash_fwd_rule(q, k, v, bias, seed, causal, sm_scale, block_q,
-                    block_k, interpret, dropout_rate, dropout_debug):
-    o, m, l = _flash_fwd(q, k, v, bias, seed, causal, sm_scale, block_q,
-                         block_k, interpret, dropout_rate, dropout_debug)
+def _flash_fwd_rule(q, k, v, bias, seed, *static):
+    o, m, l = _flash_fwd(q, k, v, bias, seed, *static)
     return o, (q, k, v, bias, seed, o, m, l)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret,
-                    dropout_rate, dropout_debug, res, do):
+def _flash_bwd_rule(*static_res_do):
+    *static, res, do = static_res_do
     q, k, v, bias, seed, o, m, l = res
-    dq, dk, dv = _flash_bwd(q, k, v, bias, seed, o, m, l, do, causal,
-                            sm_scale, block_q, block_k, interpret,
-                            dropout_rate, dropout_debug)
+    dq, dk, dv = _flash_bwd(q, k, v, bias, seed, o, m, l, do, *static)
     dbias = None if bias is None else jnp.zeros_like(bias)
     return (dq, dk, dv, dbias, None)  # int seed: no cotangent
 
@@ -879,12 +993,16 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    dropout_rate=0.0, dropout_seed=None):
+                    dropout_rate=0.0, dropout_seed=None, window=None):
     """Multi-head attention: Pallas flash kernel on TPU, XLA elsewhere.
 
-    q,k: [B, H, T, D]; v: [B, H, Tk, Dv] (Dv may differ from D); bias:
-    additive key bias [B, Tk] or [B,1,1,Tk] (no gradient flows to bias);
-    returns [B, H, Tq, Dv].  ``sm_scale`` defaults to 1/sqrt(D).
+    q: [B, H, T, D]; k: [B, Hkv, Tk, D]; v: [B, Hkv, Tk, Dv] (Dv may
+    differ from D; ``H % Hkv == 0``: query head h reads key-value head
+    ``h // (H // Hkv)``, and K and V are never expanded to H heads);
+    bias: additive key bias [B, Tk] or [B,1,1,Tk] (no gradient flows to
+    bias); returns [B, H, Tq, Dv].  ``sm_scale`` defaults to 1/sqrt(D).
+    ``window`` (an int >= 1, with ``causal``): key j is visible to query
+    i iff ``j <= i`` and ``i - j < window``.
 
     dropout_rate > 0 applies attention-probability dropout IN-KERNEL
     (upscale-in-train semantics); ``dropout_seed`` is an int32 scalar or
@@ -909,14 +1027,24 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
             "upscale by 1/0)" % dropout_rate)
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError("window=%r wants causal=True and window >= 1"
+                             % window)
+        if window >= k.shape[2]:
+            window = None  # every key up to the query's own: causal
     interpret = use_pallas()[1]
     debug = _dropout_debug()
     b, h, tq, _ = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    if h % hkv or v.shape[1] != hkv:
+        raise ValueError("%d query heads do not divide over k's %d and "
+                         "v's %d" % (h, hkv, v.shape[1]))
     qf = q.reshape(b * h, tq, d)
-    kf = k.reshape(b * h, tk, d)
+    kf = k.reshape(b * hkv, tk, d)
     dv = v.shape[-1]
-    vf = v.reshape(b * h, tk, dv)
+    vf = v.reshape(b * hkv, tk, dv)
     seed = jnp.reshape(
         jnp.asarray(0 if dropout_seed is None else dropout_seed,
                     jnp.int32), (1,))
@@ -924,7 +1052,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         return mha_reference(q, k, v, bias=bias, causal=causal,
                              sm_scale=sm_scale,
                              dropout_rate=dropout_rate, seed=seed,
-                             debug=debug)
+                             debug=debug, window=window)
     if interpret and dropout_rate > 0.0 and not debug:
         # the pltpu hardware PRNG has no CPU/interpret lowering — without
         # the debug hash the kernel would die deep in Pallas with an
@@ -937,5 +1065,5 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
             "PADDLE_TPU_PALLAS to use the XLA fallback")
     bq, bk = _pick_blocks(tq, tk)
     o = _flash(qf, kf, vf, bias, seed, causal, sm_scale, bq, bk,
-               interpret, dropout_rate, debug)
+               interpret, dropout_rate, debug, window)
     return o.reshape(b, h, tq, dv)

@@ -32,7 +32,7 @@ layer's result that its own experts give.  Nothing is dropped whatever
 the imbalance: every choice has its place in an order of ``tokens *
 top_k`` choices (every choice of every token may land here), those of
 the held experts first and sorted by expert, and one loop walks that
-order in blocks of ``BLOCK_ROWS`` as far as the rows routed here reach,
+order in blocks (:func:`block_rows`) as far as the rows routed here reach,
 its trip count read from the row counts on the device.  A block gathers
 its tokens' rows, runs the three products as :func:`grouped_dot`
 (``jax.lax.ragged_dot``) over the held experts' row counts inside the
@@ -55,7 +55,8 @@ from jax.sharding import PartitionSpec as P
 __all__ = ["moe_ffn", "moe_ffn_local", "init_moe_params",
            "moe_dispatch", "moe_combine", "MOE_RING_ID",
            "sigmoid_topk_route", "held_rows", "plan_held_rows",
-           "grouped_dot", "BLOCK_ROWS", "blocks_run", "held_experts_ffn"]
+           "grouped_dot", "share_level", "BLOCK_ROWS", "block_rows",
+           "blocks_run", "held_experts_ffn"]
 
 # ring-id convention (see parallel/pipeline.py / README "Analyzer")
 MOE_RING_ID = 2
@@ -230,13 +231,37 @@ def moe_ffn(x, params, mesh, axis_name, capacity_factor=1.25,
 # The dropless share of a top-k expert layer (module docstring)
 # ---------------------------------------------------------------------------
 
+SCORE_FUNCS = ("sigmoid", "softmax")
+
+
+def share_level(scores, share, rounds=24):
+    """scores: [T, E].  For each column the level that ``share`` of its T
+    values lie above, to ``2 ** -rounds`` of the column's range: halved
+    in, ``rounds`` passes of compare and count (a sort of T x E values
+    costs more, and nothing here needs their order)."""
+    want = share * scores.shape[0]
+
+    def halve(_, bounds):
+        low, high = bounds
+        mid = 0.5 * (low + high)
+        over = jnp.sum(scores > mid, axis=0) > want
+        return jnp.where(over, mid, low), jnp.where(over, high, mid)
+
+    bounds = jnp.min(scores, axis=0), jnp.max(scores, axis=0)
+    return jax.lax.fori_loop(0, rounds, halve, bounds)[1]
+
+
 def sigmoid_topk_route(x, w_router, bias, top_k, scale=1.0, norm=True,
-                       center=False):
+                       center=False, score_func="sigmoid"):
     """x: [T, D]; w_router: [D, E]; bias: [E] (the choice's correction
-    bias, no gradient).  Scores ``s = sigmoid(x W)`` in float32 at the
+    bias, no gradient).  Scores ``s = sigmoid(x W)`` (``score_func``
+    ``"softmax"``: the softmax over all E) in float32 at the
     highest matmul precision (a near-tie decides which expert runs); the
-    ``top_k`` largest of ``s + bias`` are chosen, the gates are the
-    chosen ``s`` (over their sum where ``norm``) times ``scale``.
+    ``top_k`` largest of ``s + bias`` are chosen (under ``"softmax"`` of
+    ``log s + bias``: the same experts where the bias is zero, and a bias
+    that can move a choice among scores that lie decades apart), the
+    gates are the chosen ``s`` (over their sum where ``norm``) times
+    ``scale``.
 
     ``center``: the bias is not the one given but minus each expert's
     mean score over these T tokens, so that an expert is chosen by how
@@ -244,17 +269,34 @@ def sigmoid_topk_route(x, w_router, bias, top_k, scale=1.0, norm=True,
     It is what balances the load of tokens that differ little among
     themselves, as a model's do before it is trained: there a few
     experts' mean scores lie above all the others' whatever the token,
-    and a bias kept from one batch does not fit the next.  (Under data
-    parallelism the mean would be taken over all the step's tokens.)
+    and a bias kept from one batch does not fit the next.  Under
+    ``"softmax"`` it is minus the log-score that ``top_k / E`` of the
+    tokens give the expert more than (:func:`share_level`): log-scores
+    spread and lean differently from expert to expert, and counted from
+    their means the experts' loads still lay 0.6 to 1.5 times the even
+    share, by the weights.  (Under data parallelism either would be
+    taken over all the step's tokens.)
 
     Returns ``(idx [T, top_k] int32, gates [T, top_k] float32, bias [E]
     as used)``."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    bias = jax.lax.stop_gradient(
-        -jnp.mean(s, axis=0) if center else bias.astype(jnp.float32))
-    _, idx = jax.lax.top_k(jax.lax.stop_gradient(s) + bias, top_k)
+    if score_func not in SCORE_FUNCS:
+        raise ValueError("score_func %r is none of %s"
+                         % (score_func, SCORE_FUNCS))
+    z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    if score_func == "softmax":
+        s, chosen_by = jax.nn.softmax(z, axis=-1), jax.nn.log_softmax(z, -1)
+    else:
+        s = chosen_by = jax.nn.sigmoid(z)
+    chosen_by = jax.lax.stop_gradient(chosen_by)
+    if not center:
+        bias = bias.astype(jnp.float32)
+    elif score_func == "softmax":
+        bias = -share_level(chosen_by, top_k / chosen_by.shape[1])
+    else:
+        bias = -jnp.mean(chosen_by, axis=0)
+    bias = jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(chosen_by + bias, top_k)
     gates = jnp.take_along_axis(s, idx, axis=-1)
     if norm:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
@@ -322,11 +364,25 @@ grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 BLOCK_ROWS = 8192
 
 
-def blocks_run(rows):
+def block_rows(choices, held, total=None):
+    """Rows a block of :func:`held_experts_ffn`'s loop holds, for a layer
+    that holds ``held`` of ``total`` experts and routes ``choices``
+    (tokens x top_k) choices a step: one and a half times the rows an
+    even spread gives it, in whole 1,024s, so that one trip holds a
+    step's rows unless the layer is given half as much again (a trip
+    more or less is 6 to 16 ms on the v5e, and with the even share at a
+    block's end the number of trips turned on the seed: PERF.md 6,
+    PRs 35 and 36).  ``BLOCK_ROWS`` where ``total`` is not known."""
+    if not total:
+        return BLOCK_ROWS
+    return max(1024, -(-3 * choices * held // (2 * total * 1024)) * 1024)
+
+
+def blocks_run(rows, block=BLOCK_ROWS):
     """Trips the loop of :func:`held_experts_ffn` makes for ``rows`` (the
-    rows given to each held expert): the blocks of ``BLOCK_ROWS`` buffer
-    rows that hold a routed row.  Read on the device."""
-    return (jnp.sum(rows) + (BLOCK_ROWS - 1)) // BLOCK_ROWS
+    rows given to each held expert): the blocks of ``block`` buffer rows
+    that hold a routed row.  Read on the device."""
+    return (jnp.sum(rows) + (block - 1)) // block
 
 
 def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes):
@@ -343,43 +399,43 @@ def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes):
         return y.astype(jnp.float32) * g[:, None]
 
 
-def _block_inputs(scope, i, x, gates, order, rows):
-    """Block i of the buffer: the flat choices it takes, their tokens,
-    those tokens' rows of x, the choices' gates, and the held experts'
-    cumulative row counts cut to the block's range."""
+def _block_inputs(scope, size, i, x, gates, order, rows):
+    """Block i of the buffer (``size`` rows): the flat choices it takes,
+    their tokens, those tokens' rows of x, the choices' gates, and the
+    held experts' cumulative row counts cut to the block's range."""
     with scope("dispatch"):
-        lo = i * BLOCK_ROWS
-        choice = jax.lax.dynamic_slice(order, (lo,), (BLOCK_ROWS,))
+        lo = i * size
+        choice = jax.lax.dynamic_slice(order, (lo,), (size,))
         token = choice // gates.shape[1]    # in [0, T): no bounds to check
         xs = x.at[token].get(mode="promise_in_bounds")
         g = gates.reshape(-1).at[choice].get(mode="promise_in_bounds")
         ends = jnp.cumsum(rows)
-        sizes = (jnp.clip(ends - lo, 0, BLOCK_ROWS)
-                 - jnp.clip(ends - rows - lo, 0, BLOCK_ROWS))
+        sizes = (jnp.clip(ends - lo, 0, size)
+                 - jnp.clip(ends - rows - lo, 0, size))
     return choice, token, xs, g, sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _walk_blocks(scope, x, gates, w_gate, w_up, w_down, order, rows):
-    """``held_experts_ffn`` behind its plan: order [a multiple of
-    BLOCK_ROWS] and rows [held] from :func:`plan_held_rows`."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk_blocks(scope, size, x, gates, w_gate, w_up, w_down, order, rows):
+    """``held_experts_ffn`` behind its plan: order [a multiple of the
+    block's ``size``] and rows [held] from :func:`plan_held_rows`."""
     def block(i, out):
-        _, token, xs, g, sizes = _block_inputs(scope, i, x, gates, order,
-                                               rows)
+        _, token, xs, g, sizes = _block_inputs(scope, size, i, x, gates,
+                                               order, rows)
         y = _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes)
         with scope("combine"):
             return out.at[token].add(y, mode="promise_in_bounds")
 
-    out = jax.lax.fori_loop(0, blocks_run(rows), block,
+    out = jax.lax.fori_loop(0, blocks_run(rows, size), block,
                             jnp.zeros(x.shape, jnp.float32))
     return out.astype(x.dtype)
 
 
-def _walk_blocks_fwd(scope, *args):
-    return _walk_blocks(scope, *args), args
+def _walk_blocks_fwd(scope, size, *args):
+    return _walk_blocks(scope, size, *args), args
 
 
-def _walk_blocks_bwd(scope, args, ct):
+def _walk_blocks_bwd(scope, size, args, ct):
     """The same loop again: a block is computed anew and differentiated
     (``jax.vjp`` of the one block function), nothing is kept between
     blocks, and the cotangents of x (in x's dtype, as the transpose of a
@@ -388,8 +444,8 @@ def _walk_blocks_bwd(scope, args, ct):
 
     def block(i, carry):
         d_x, d_gates, d_weights = carry
-        choice, token, xs, g, sizes = _block_inputs(scope, i, x, gates,
-                                                    order, rows)
+        choice, token, xs, g, sizes = _block_inputs(scope, size, i, x,
+                                                    gates, order, rows)
         _, pullback = jax.vjp(
             lambda *a: _block_ffn(scope, *a, sizes), xs, g, w_gate, w_up,
             w_down)
@@ -400,7 +456,7 @@ def _walk_blocks_bwd(scope, args, ct):
                 [a + b for a, b in zip(d_weights, d_w)])
 
     d_x, d_gates, d_weights = jax.lax.fori_loop(
-        0, blocks_run(rows), block,
+        0, blocks_run(rows, size), block,
         (jnp.zeros_like(x), jnp.zeros(gates.size, gates.dtype),
          [jnp.zeros_like(w) for w in (w_gate, w_up, w_down)]))
     return d_x, d_gates.reshape(gates.shape), *d_weights, None, None
@@ -410,20 +466,21 @@ _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 
 
 def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
-                     scope=jax.named_scope):
+                     scope=jax.named_scope, total=None):
     """The held experts' part of ``sum_k gates[t,k] * E_idx[t,k](x[t])``
     with ``E(x) = W_down (silu(W_gate x) * W_up x)``.
 
     x: [T, D]; idx, gates: [T, K] from :func:`sigmoid_topk_route`;
     w_gate, w_up: [held, D, F]; w_down: [held, F, D]: the experts
-    ``first .. first + held - 1``.  Returns ``(y [T, D], rows [held]
+    ``first .. first + held - 1`` of ``total`` (sizes the loop's block:
+    :func:`block_rows`).  Returns ``(y [T, D], rows [held]
     int32)``; ``rows`` are the rows each held expert was given, summing
     to the work done.
 
     One path whatever the load: every choice of every token has its
     place in an order of ``T * K`` choices, those of the held experts
     first and sorted by expert (:func:`plan_held_rows`), and one loop
-    walks that order in blocks of ``BLOCK_ROWS`` as far as the routed
+    walks that order in blocks of :func:`block_rows` as far as the routed
     rows reach (:func:`blocks_run`, read from ``rows`` on the device).  A
     block gathers its choices' tokens from x, runs the three products
     grouped by the held experts' row counts cut to the block's range,
@@ -438,11 +495,12 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
     three parts of a block for a device trace: ``dispatch``, ``products``
     and ``combine``."""
     held = w_gate.shape[0]
+    size = block_rows(idx.size, held, total)
     with scope("dispatch"):
         order, rows = plan_held_rows(idx, first, held)
         # padded places lie past every routed row: choice 0, which adds 0
-        order = jnp.pad(order, (0, -order.size % BLOCK_ROWS))
-    out = _walk_blocks(scope, x, gates, w_gate.astype(x.dtype),
+        order = jnp.pad(order, (0, -order.size % size))
+    out = _walk_blocks(scope, size, x, gates, w_gate.astype(x.dtype),
                        w_up.astype(x.dtype), w_down.astype(x.dtype),
                        order, rows)
     return out, rows

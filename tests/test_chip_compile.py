@@ -146,6 +146,22 @@ def test_flash_attention_at_d_qk_other_than_d_v(chip, grad):
     assert n == (3 if grad else 1)
 
 
+@pytest.mark.parametrize("grad", [False, True])
+@pytest.mark.parametrize("window", [1024, None])
+def test_flash_attention_with_grouped_heads_and_a_window(chip, window, grad):
+    """The grouped-query cell's two sites at the published widths: 32
+    query heads on 4 key-value heads of 128, 2 x 8192 (two spans a sweep,
+    block indices clamped to the band), a window of 1024 or none."""
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window)
+
+    if grad:
+        fn = _grad_sum(fn, (0, 1, 2))
+    n = _kernels_in(fn, chip, ((2, 32, 8192, 128), BF16),
+                    ((2, 4, 8192, 128), BF16), ((2, 4, 8192, 128), BF16))
+    assert n == (3 if grad else 1)
+
+
 # -- the dropless expert layer -------------------------------------------------
 
 @pytest.mark.parametrize("grad", [False, True])

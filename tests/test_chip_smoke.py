@@ -311,3 +311,40 @@ def test_phase_data_parallel_at_tiny_width(chip_smoke, capsys,
     assert many["spans"] == {"batch": n, "gradient": n, "parameter": n}
     assert one["all_reduces"] == 0 and many["all_reduces"] > 0
     assert verdict["loss_rel_diff_max"] <= verdict["rtol"]
+
+
+def test_phase_window_edge_at_a_small_size(chip_smoke, capsys, monkeypatch):
+    """The probe of the band's edges through the flash kernels in
+    interpret mode: 512 positions, 4 query heads on 2, a window of 200
+    (no multiple of a block); and an edge one row off fails it, forward
+    or backward."""
+    import importlib
+
+    FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_K", "128")
+    small = dict(t=512, heads=4, kv_heads=2, d=64, window=200,
+                 keys=(0, 199, 200, 311), batch=2)
+    worst = chip_smoke.phase_window_edge(**small)
+    (line,) = _phase_lines(capsys)
+    assert line["phase"] == "window_edge" and line["keys"] == [0, 199, 200,
+                                                               311]
+    assert set(worst) == {"sliding", "full", "sliding_dv", "sliding_dk",
+                          "full_dv", "full_dk"}
+    assert max(worst.values()) < 1e-2
+    real = FA._scores
+
+    def one_row_more(x, y, sm_scale, bias, offset, keys_down, window=None):
+        return real(x, y, sm_scale, bias, offset, keys_down,
+                    window and window + 1)
+
+    def forward_only(*a, **kw):     # the dK/dV kernel's orientation alone
+        return (one_row_more if a[5] else real)(*a, **kw)
+
+    for broken, said in ((one_row_more, "rows [200"), (forward_only, "off")):
+        monkeypatch.setattr(FA, "_scores", broken)
+        with pytest.raises(SystemExit) as exc:
+            chip_smoke.phase_window_edge(**small)
+        assert "window_edge" in str(exc.value.code)
+        assert said in str(exc.value.code)

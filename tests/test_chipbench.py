@@ -15,3 +15,4 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench.selftest.test_chipbench import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_program_spans import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_kanana import *  # noqa: E402,F401,F403
+from chipbench.selftest.test_mellum2 import *  # noqa: E402,F401,F403
