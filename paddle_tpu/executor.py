@@ -931,7 +931,18 @@ def _run_ops_into_env(block, env, ctx, ops=None):
     and a list with no backward lowers exactly as before); the grad op,
     if it is fed the very values the forward saw, takes the kept
     residuals.  Anything else is the generic arm.  What each kernel site
-    took is noted in ``ctx.residual_sites`` (if the caller set one)."""
+    took is noted in ``ctx.residual_sites`` (if the caller set one).
+
+    Inside a ``recompute_block`` region the grad is one ``jax.vjp`` over
+    the region run again, so a forward kernel there would run in both.
+    Where the region's grad op is in THIS call's op list, the two share
+    a ``registry.RegionKept`` (in ``kept`` too, under the region's op
+    id): a kernel site's lowering puts into it what its forward kernel
+    computed and its backward kernels read (flash attention: ``o, m,
+    l``; never an activation, which is what the region drops), and the
+    re-run takes that in place of a second forward kernel
+    (``kept_across_region``).  A region whose two ops are lowered by
+    different calls keeps nothing and reads ``recomputed``."""
     import jax
 
     from .ops import control_flow as cf_ops
@@ -940,15 +951,18 @@ def _run_ops_into_env(block, env, ctx, ops=None):
     ops = block.ops if ops is None else ops
     twin_ids = {op.attrs["__fwd_op_id__"] for op in ops
                 if "__fwd_op_id__" in op.attrs}
-    kept = {}   # forward op id -> registry.KeptForward
+    kept = {}   # forward op id -> registry.KeptForward | RegionKept
     for i, op in enumerate(ops):
         if op.type in ("feed", "fetch"):
             continue
+        op_id = op.attrs.get("__fwd_op_id__", op.attrs.get("__op_id__", 0))
         if op.type in cf_ops.SUB_BLOCK_OPS:
             # control-flow ops need names + the sub-block, not just values
+            if op.type == "recompute_block" and op_id in twin_ids:
+                kept[op_id] = op_registry.RegionKept()
             with jax.named_scope("pd%d_%s" % (i, op.type)):
                 cf_ops.run_sub_block_op(op, block, env, ctx,
-                                        _run_ops_into_env)
+                                        _run_ops_into_env, kept.get(op_id))
             continue
         opdef = op_registry.get_op_def(op.type)
         ins = {}
@@ -960,7 +974,6 @@ def _run_ops_into_env(block, env, ctx, ops=None):
                 else:
                     vals.append(env.get(n))
             ins[slot] = vals
-        op_id = op.attrs.get("__fwd_op_id__", op.attrs.get("__op_id__", 0))
         ctx.op_scope = "pd%d_%s" % (i, op.attrs.get("device_tag", op.type))
         with jax.named_scope(ctx.op_scope):
             if (op.attrs.get("__op_id__") in twin_ids
@@ -994,14 +1007,13 @@ def _lower_kernel_site_grad(opdef, ctx, ins, attrs, fwd_id, kept):
     in ``ctx.residual_sites`` which of the two a kernel site took."""
     if kept is not None and not kept.saw(ins):
         kept = None
-    sites = getattr(ctx, "residual_sites", None)
-    if sites is not None and (
-            kept is not None
-            or op_registry.routes_to_kernel(opdef, ctx, ins, attrs)):
-        sites[fwd_id] = (opdef.fwd_def.type,
-                         "reused" if kept is not None else "recomputed")
-    return op_registry.call_op(opdef, ctx, ins, attrs, op_id=fwd_id,
+    outs = op_registry.call_op(opdef, ctx, ins, attrs, op_id=fwd_id,
                                kept=kept)
+    if kept is not None:
+        ctx.note_residual_site(opdef.fwd_def.type, "reused")
+    elif op_registry.routes_to_kernel(opdef, ctx, ins, attrs):
+        ctx.note_residual_site(opdef.fwd_def.type, "recomputed")
+    return outs
 
 
 def _check_feed_shapes(program, feed_vals):

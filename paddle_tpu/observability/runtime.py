@@ -246,22 +246,29 @@ def record_compile(ms, runner="executor"):
 
 
 def record_grad_residual_sites(sites, compile_phase):
-    """What the grad op of each Mosaic kernel site of a newly traced
-    block took, ``(op_type, "reused" | "recomputed")`` each: the forward
-    op's saved residuals, or a second run of the forward kernel
-    (``executor._run_ops_into_env``).  The two counts are attributes of
-    the block's ``compile`` phase."""
-    counts = collections.Counter(sites)
-    for path in ("reused", "recomputed"):
+    """What the backward of each Mosaic kernel site of a newly traced
+    block took, ``(op_type, path, kept_bytes)`` each: the forward op's
+    saved residuals (``reused``), what the site's forward kernel gave,
+    kept across its recompute region (``kept_across_region``, and the
+    bytes so kept), or a second run of the forward kernel
+    (``recomputed``; ``executor._run_ops_into_env``).  The three counts
+    and ``recompute_kept_bytes`` are attributes of the block's
+    ``compile`` phase."""
+    sites = list(sites)
+    counts = collections.Counter((t, p) for t, p, _ in sites)
+    for path in ("reused", "recomputed", "kept_across_region"):
         compile_phase.set_attr(
             "grad_residual_sites_" + path,
             sum(n for (_, p), n in counts.items() if p == path))
+    compile_phase.set_attr("recompute_kept_bytes",
+                           sum(b for _, _, b in sites))
     if not telemetry_enabled():
         return
     for (op_type, path), n in counts.items():
         _m.counter("grad_residual_sites_total",
-                   "kernel sites whose grad op reused the forward op's "
-                   "residuals, or ran the forward kernel again",
+                   "kernel sites whose backward reused the forward op's "
+                   "residuals, took what was kept across a recompute "
+                   "region, or ran the forward kernel again",
                    op_type=op_type, path=path).inc(n)
 
 

@@ -15,9 +15,11 @@ buffer + length scalar — `array_write` is a dynamic_update_slice, the
 TPU-static analogue of the reference's growable vector<LoDTensor>.
 """
 
+import contextlib
+
 import numpy as np
 
-from .registry import register_op, EMPTY_VAR_NAME
+from .registry import register_op, EMPTY_VAR_NAME, RegionKept
 
 SUB_BLOCK_OPS = ("while", "conditional_block", "recurrent",
                  "recurrent_grad", "conditional_block_grad", "while_grad",
@@ -92,7 +94,30 @@ def _clean_grad(g, primal):
     return g
 
 
-def run_sub_block_op(op, block, env, ctx, run_block_fn):
+@contextlib.contextmanager
+def _lowering_region(ctx, region):
+    """``ctx.region`` is ``region`` while a sub-block is lowered."""
+    was, ctx.region = ctx.region, region
+    try:
+        yield
+    finally:
+        ctx.region = was
+
+
+def run_sub_block_op(op, block, env, ctx, run_block_fn, region=None):
+    """Lower one op of ``SUB_BLOCK_OPS`` into ``env``.  ``region``
+    (``recompute_block`` and its grad): the ``registry.RegionKept`` the
+    two share where one call lowers both, else None."""
+    if op.type == "recompute_block":
+        # no twin in this call: the enclosing region's store, if any
+        region = region or ctx.region
+    elif op.type != "recompute_block_grad":
+        region = None  # a loop's or a branch's body is a trace of its own
+    with _lowering_region(ctx, region):
+        _run_sub_block_op(op, block, env, ctx, run_block_fn)
+
+
+def _run_sub_block_op(op, block, env, ctx, run_block_fn):
     import jax
     import jax.numpy as jnp
 
@@ -157,7 +182,8 @@ def run_sub_block_op(op, block, env, ctx, run_block_fn):
         # call is never differentiated by jax — grads are explicit ops),
         # emitting every written name into env.  Unconsumed entries are
         # ordinary unbarriered values, so XLA DCEs them; the remat effect
-        # lives entirely in the GRAD op's barriered re-forward.
+        # lives entirely in the GRAD op's barriered re-forward.  Only
+        # what a kernel site puts into ``ctx.region`` outlives the region.
         out_names = list(op.outputs.get("Out", []))
         cap = [n for n in op.inputs.get("Captured", [])
                or sub_block_external_reads(sub_block) if n in env]
@@ -503,7 +529,13 @@ def _run_recompute_grad(op, sub_block, env, ctx, run_block_fn):
     (jax.checkpoint's own mechanism) makes the recompute a distinct
     subgraph XLA cannot CSE with the forward op's chain — without it the
     'recompute' would alias the original activations and their liveness
-    would span fwd→bwd again, defeating the remat."""
+    would span fwd→bwd again, defeating the remat.  The barrier ties the
+    captured values to the region's incoming gradients, so a re-run
+    cannot start before the backward pass reaches its region; what the
+    region's kernel sites kept of its forward run (``ctx.region``, where
+    the forward op was lowered by the same call) goes through the same
+    barrier, and the re-run's lowering of such a site takes it in place
+    of a second forward kernel."""
     import jax
 
     cap_names = op.inputs.get("Captured", [])
@@ -520,9 +552,12 @@ def _run_recompute_grad(op, sub_block, env, ctx, run_block_fn):
     cap_vals = tuple(env[n] for n in cap_names)
     gouts = {n: env[n] for n in gout_names
              if n and n != EMPTY_VAR_NAME and env.get(n) is not None}
+    kept = {} if ctx.region is None else ctx.region.values
     if cap_vals:
-        cap_vals, gouts = jax.lax.optimization_barrier((cap_vals, gouts))
-    primal, vjp_fn = jax.vjp(f, cap_vals)
+        cap_vals, gouts, kept = jax.lax.optimization_barrier(
+            (cap_vals, gouts, kept))
+    with _lowering_region(ctx, RegionKept(kept)):
+        primal, vjp_fn = jax.vjp(f, cap_vals)
     cots = []
     for i, p in enumerate(primal):
         gname = gout_names[i] if i < len(gout_names) else EMPTY_VAR_NAME

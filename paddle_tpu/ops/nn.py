@@ -16,6 +16,7 @@ Reference kernels: ``paddle/fluid/operators/softmax_op.cc`` (+cuDNN variant),
   reference's hand-written fused grad kernel.
 """
 
+import functools
 import math
 
 import jax
@@ -546,7 +547,10 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
     registry's generic grad derivation: over the residuals (``m``, ``l``)
     of the forward op's own kernel call where the Executor lowers both
     ops in one call and the site routes to the kernel (``_flash_site``),
-    else over a ``jax.vjp`` that re-derives the forward."""
+    else over a ``jax.vjp`` that re-derives the forward.  In a recompute
+    region (``ctx.region``) a kernel site keeps its forward kernel's
+    ``(o, m, l)`` across the region and the grad op's re-run, which
+    computes Q, K and V again, takes those and runs no forward kernel."""
     from .pallas.flash_attention import flash_attention
 
     causal = bool(attrs.get("causal", False))
@@ -564,10 +568,27 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
                                   dtype=jnp.int32)
     else:
         rate = 0.0
-    return flash_attention(Q, K, V, bias=BiasQK, causal=causal,
-                           sm_scale=scale, dropout_rate=rate,
-                           dropout_seed=seed,
-                           window=attrs.get("window") or None)
+    attend = functools.partial(
+        flash_attention, Q, K, V, bias=BiasQK, causal=causal, sm_scale=scale,
+        dropout_rate=rate, dropout_seed=seed,
+        window=attrs.get("window") or None)
+    region = ctx.region
+    if region is None:
+        return attend()
+    if not region.rerun:
+        out, region.values[ctx.op_id] = attend(return_residuals=True)
+        return out
+    kept = region.values.get(ctx.op_id)
+    if kept is None:
+        # nothing crossed the region: no kernel here, or the region's
+        # forward was lowered in another call and its kernel runs again
+        if _flash_site(ctx, attrs, Q, K, V, BiasQK):
+            ctx.note_residual_site("fused_multihead_attention", "recomputed")
+        return attend()
+    ctx.note_residual_site(
+        "fused_multihead_attention", "kept_across_region",
+        sum(x.size * x.dtype.itemsize for x in kept))
+    return attend(residuals=kept)
 
 
 def _fused_ln_rate(ctx, attrs):

@@ -88,7 +88,10 @@ def pallas_kernels_in(hlo_text):
     kernels; a program with no backward holds the plain names.  A plain
     AND a ``jvp_`` forward of one kernel in one step means a site's grad
     op ran the forward again (``grad_residual_sites_total``,
-    ``path="recomputed"``)."""
+    ``path="recomputed"``).  A flash site inside a recompute region
+    holds the plain names, the forward once too (what it computed
+    crosses the region, ``path="kept_across_region"``); twice the
+    sites' forwards there is the same doubling."""
     return collections.Counter(_KERNEL_CALL.findall(hlo_text))
 
 

@@ -992,8 +992,28 @@ def _flash_bwd_rule(*static_res_do):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(8, 16)))
+def _flash_kept(q, k, v, bias, seed, o, m, l, *static):
+    """:func:`_flash` where the forward kernel has run already and its
+    ``(o, m, l)`` were kept: traces no kernel forward, and backward the
+    two backward kernels over the same residuals."""
+    return o
+
+
+def _flash_kept_fwd_rule(q, k, v, bias, seed, o, m, l, *static):
+    return o, (q, k, v, bias, seed, o, m, l)
+
+
+def _flash_kept_bwd_rule(*static_res_do):
+    return _flash_bwd_rule(*static_res_do) + (None, None, None)
+
+
+_flash_kept.defvjp(_flash_kept_fwd_rule, _flash_kept_bwd_rule)
+
+
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    dropout_rate=0.0, dropout_seed=None, window=None):
+                    dropout_rate=0.0, dropout_seed=None, window=None,
+                    residuals=None, return_residuals=False):
     """Multi-head attention: Pallas flash kernel on TPU, XLA elsewhere.
 
     q: [B, H, T, D]; k: [B, Hkv, Tk, D]; v: [B, Hkv, Tk, Dv] (Dv may
@@ -1010,6 +1030,14 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     rate is applied with jax.random (debug hash under
     PADDLE_TPU_FLASH_DROPOUT_DEBUG=iota, where both paths draw the
     identical mask for cross-checking).
+
+    ``return_residuals``: return ``(out, residuals)``, where residuals
+    is what the forward kernel computed and the backward kernels read,
+    ``(o, m, l)`` as the kernels shape them, or None where XLA attention
+    ran.  ``residuals``: such a triple from an earlier call on the same
+    inputs; the forward kernel is not run again, and the gradient is the
+    backward kernels' over these (a recompute region's re-run:
+    ``ops/control_flow.py``).
     """
     if bias is not None:
         # constant on BOTH paths: the Pallas custom_vjp returns zero bias
@@ -1049,10 +1077,11 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         jnp.asarray(0 if dropout_seed is None else dropout_seed,
                     jnp.int32), (1,))
     if not routes_to_kernel(q, k, bias, v):
-        return mha_reference(q, k, v, bias=bias, causal=causal,
-                             sm_scale=sm_scale,
-                             dropout_rate=dropout_rate, seed=seed,
-                             debug=debug, window=window)
+        out = mha_reference(q, k, v, bias=bias, causal=causal,
+                            sm_scale=sm_scale,
+                            dropout_rate=dropout_rate, seed=seed,
+                            debug=debug, window=window)
+        return (out, None) if return_residuals else out
     if interpret and dropout_rate > 0.0 and not debug:
         # the pltpu hardware PRNG has no CPU/interpret lowering — without
         # the debug hash the kernel would die deep in Pallas with an
@@ -1064,6 +1093,16 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
             "hash, identical masks on kernel and XLA paths) or unset "
             "PADDLE_TPU_PALLAS to use the XLA fallback")
     bq, bk = _pick_blocks(tq, tk)
-    o = _flash(qf, kf, vf, bias, seed, causal, sm_scale, bq, bk,
-               interpret, dropout_rate, debug, window)
-    return o.reshape(b, h, tq, dv)
+    static = (causal, sm_scale, bq, bk, interpret, dropout_rate, debug,
+              window)
+    if residuals is None:
+        if not return_residuals:
+            return _flash(qf, kf, vf, bias, seed,
+                          *static).reshape(b, h, tq, dv)
+        # the kernel once, outside any differentiation; the gradient goes
+        # through _flash_kept, over what the kernel gave
+        residuals = _flash_fwd(*map(jax.lax.stop_gradient, (qf, kf, vf)),
+                               bias, seed, *static)
+    out = _flash_kept(qf, kf, vf, bias, seed, *residuals,
+                      *static).reshape(b, h, tq, dv)
+    return (out, residuals) if return_residuals else out

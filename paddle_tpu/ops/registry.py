@@ -21,7 +21,12 @@ function per op subsumes all three:
   where the same lowering call also holds the grad twin the Executor
   takes that forward once, through ``jax.vjp`` (:func:`call_op_keeping_vjp`),
   and hands the grad op the kept residuals (:class:`KeptForward`) in
-  place of a second forward.  Ops can still register a hand-written
+  place of a second forward.  Inside a ``recompute_block`` region the
+  grad is one ``jax.vjp`` over the whole region run again, which is what
+  the region is for; but a kernel site's lowering may keep what its
+  forward kernel computed and its backward kernels read across the
+  region (:class:`RegionKept`, ``ctx.region``), and the re-run then runs
+  no forward kernel there.  Ops can still register a hand-written
   ``<type>_grad`` where a different formula is preferable.
 
 This mirrors the precedent the reference itself set for graph-compiler
@@ -44,6 +49,7 @@ __all__ = [
     "call_op_keeping_vjp",
     "routes_to_kernel",
     "KeptForward",
+    "RegionKept",
     "infer_shapes",
     "infer_output_structs",
     "EMPTY_VAR_NAME",
@@ -181,10 +187,15 @@ class LoweringContext:
         # applied to every op output at trace time (executor sets it when
         # a PADDLE_TPU_FAULT_SPEC names value faults; None = zero cost)
         self.fault_value_hook = None
-        # {forward op id: (op type, "reused" | "recomputed")}, filled at
-        # trace time for the grad ops of Mosaic kernel sites (executor
-        # ``_run_ops_into_env``) when the caller sets a dict; None: no note
+        # {forward op id: (op type, "reused" | "recomputed" |
+        # "kept_across_region", bytes kept across a region)}, filled at
+        # trace time for the grad ops of Mosaic kernel sites
+        # (:meth:`note_residual_site`) when the caller sets a dict;
+        # None: no note
         self.residual_sites = None
+        # the :class:`RegionKept` of the recompute region whose sub-block
+        # is being lowered (``ops/control_flow.py``), else None
+        self.region = None
         # the ``jax.named_scope`` of the op being lowered, set by the
         # Executor (``_run_ops_into_env``): ``pd<index>_<tag>``
         self.op_scope = "pd0_op"
@@ -199,6 +210,18 @@ class LoweringContext:
     def set_op(self, op_id):
         self._op_id = op_id
         self._rng_count = 0
+
+    @property
+    def op_id(self):
+        """The id of the op being lowered (its forward twin's, in a grad
+        op): what its RNG draws and its site's notes are keyed by."""
+        return self._op_id
+
+    def note_residual_site(self, op_type, path, kept_bytes=0):
+        """Note what the backward of the kernel site being lowered took
+        of its forward (``residual_sites``)."""
+        if self.residual_sites is not None:
+            self.residual_sites[self._op_id] = (op_type, path, kept_bytes)
 
     def rng(self):
         import jax
@@ -281,6 +304,22 @@ class KeptForward:
                     a is not b for a, b in zip(vals, theirs)):
                 return False
         return True
+
+
+class RegionKept:
+    """What the Mosaic kernel sites of one recompute region keep of its
+    forward run for its grad op's re-run: ``values[site op id]``, arrays.
+    The forward ``recompute_block`` fills it where its grad twin is in
+    the same lowering call (``rerun`` false), the grad op reads it
+    (``rerun`` true; empty where the forward was lowered elsewhere).
+    What to keep is the site's decision: only what a forward kernel
+    computed and its backward kernels read, never an activation."""
+
+    __slots__ = ("values", "rerun")
+
+    def __init__(self, values=None):
+        self.rerun = values is not None
+        self.values = {} if values is None else values
 
 
 def _fwd_slot_values(fwd_def, kwargs):

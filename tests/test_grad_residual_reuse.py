@@ -278,8 +278,12 @@ def test_reuse_under_the_accumulation_split(kind, monkeypatch):
 def test_fused_sites_inside_recompute_block_train():
     """Inside ``recompute_block`` the forward is lowered by the sub-block's
     own call (no grad twin there) and the backward by a vjp over the whole
-    block: neither arm applies, nothing is kept, and BERT with fused LN
-    and fused attention under recompute still trains."""
+    block run again: neither arm of ``_run_ops_into_env`` applies.  A
+    flash site keeps its forward kernel's ``o, m, l`` across its region
+    (one forward kernel a site, ``tests/test_recompute_kept.py``); a
+    fused-LN site's output is an activation, which a region is there to
+    drop, so its kernel runs in the forward pass and in the re-run and no
+    path names it; and BERT with both under recompute still trains."""
     from paddle_tpu.models import bert
 
     fluid.unique_name.switch()
@@ -297,14 +301,20 @@ def test_fused_sites_inside_recompute_block_train():
                 for _ in range(4)]
         kernels = _kernels(_step_jaxpr(feed))
     assert np.isfinite(vals).all() and vals[-1] < vals[0]
-    assert kernels["fused_ln_fwd"] and kernels["flash_attention_fwd"]
+    assert (kernels["flash_attention_fwd"], kernels["flash_attention_dkv"],
+            kernels["flash_attention_dq"]) == (2, 2, 2)
+    # four LN sites in the two regions twice, the embedding's once
+    assert (kernels["fused_ln_fwd"], kernels["fused_ln_bwd"]) == (9, 5)
     # the embedding LN sits outside the recompute blocks: one plain site
-    assert _sites() == {("fused_dropout_add_ln", "reused"): 1}
+    assert _sites() == {
+        ("fused_multihead_attention", "kept_across_region"): 2,
+        ("fused_dropout_add_ln", "reused"): 1}
 
 
 def test_compile_span_carries_the_two_counts():
-    """The ``compile`` phase of the step that traced the block holds both
-    counts as attributes."""
+    """The ``compile`` phase of the step that traced the block holds the
+    three counts as attributes, and the bytes kept across regions (none
+    here: no region)."""
     from paddle_tpu.observability import tracing
 
     with tracing.span("test.root"):     # inside a trace every step records
@@ -313,5 +323,7 @@ def test_compile_span_carries_the_two_counts():
              if r["name"] == "executor.compile"
              and "grad_residual_sites_reused" in r["attrs"]]
     assert [(s["attrs"]["grad_residual_sites_reused"],
-             s["attrs"]["grad_residual_sites_recomputed"])
-            for s in spans][-1] == (N_SITES, 0)
+             s["attrs"]["grad_residual_sites_recomputed"],
+             s["attrs"]["grad_residual_sites_kept_across_region"],
+             s["attrs"]["recompute_kept_bytes"])
+            for s in spans][-1] == (N_SITES, 0, 0, 0)

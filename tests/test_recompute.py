@@ -6,7 +6,10 @@ Oracles: (1) losses/grad-trajectory identical with and without the
 region over several optimizer steps; (2) the compiled train step's temp
 memory drops when a deep stack is wrapped (the point of remat)."""
 
+import contextlib
+
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.executor import Scope, scope_guard
@@ -16,21 +19,27 @@ WIDTH = 256
 DEPTH = 6
 
 
-def _build(use_recompute, seed=3):
+def _build(use_recompute, seed=3, attention=False):
+    """``attention``: the stack ends in a flash attention site (the 64
+    rows of a batch as 2 heads of 128 positions)."""
     fluid.unique_name.switch()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = seed
     with fluid.program_guard(main, startup):
-        x = fluid.layers.data(name="x", shape=[WIDTH], dtype="float32")
+        x = fluid.layers.data(name="x", shape=[64, WIDTH] if attention
+                              else [WIDTH], dtype="float32",
+                              append_batch_size=not attention)
         y = fluid.layers.data(name="y", shape=[1], dtype="float32")
         h = x
-        if use_recompute:
-            with fluid.layers.recompute():
-                for _ in range(DEPTH):
-                    h = fluid.layers.fc(input=h, size=WIDTH, act="relu")
-        else:
+        with (fluid.layers.recompute() if use_recompute
+              else contextlib.nullcontext()):
             for _ in range(DEPTH):
                 h = fluid.layers.fc(input=h, size=WIDTH, act="relu")
+            if attention:
+                q = fluid.layers.reshape(h, [1, 2, 128, 64])
+                h = h + fluid.layers.reshape(
+                    fluid.layers.fused_multihead_attention(q, q, q),
+                    [64, WIDTH])
         pred = fluid.layers.fc(input=h, size=1)
         loss = fluid.layers.reduce_mean(
             fluid.layers.square_error_cost(input=pred, label=y))
@@ -63,7 +72,9 @@ class TestRecompute:
                                    rtol=1e-5, atol=1e-7)
         assert traj[True][-1] < traj[True][0]
 
-    def test_backward_recomputes_behind_barrier(self):
+    @pytest.mark.parametrize("kernel_site", [False, True])
+    def test_backward_recomputes_behind_barrier(self, kernel_site,
+                                                monkeypatch):
         """Structural oracle: the lowered (pre-optimization) module must
         contain the region's EXTRA forward matmuls plus the
         optimization_barrier that roots them — byte-identical to what
@@ -71,17 +82,27 @@ class TestRecompute:
         both away — verified against native jax.checkpoint, which shows
         the same temp bytes with and without remat on CPU — so a
         temp-size assertion is only meaningful on TPU, where the
-        scheduler honors the barrier.)"""
+        scheduler honors the barrier.)
+
+        The ONE barrier ties everything the re-run reads to a gradient
+        the backward pass made, so no re-run can start before the
+        backward pass reaches its region (the scheduler had hoisted every
+        region's re-run to the front: 15.16 against 11.48 GiB compiled,
+        PERF.md PR 36).  ``kernel_site``: what a flash kernel site keeps
+        of the region's forward run (``o, m, l``) crosses the same
+        barrier, and the backward kernels read it behind it."""
         import jax
         import jax.numpy as jnp
 
         import paddle_tpu.executor as ex
 
+        if kernel_site:
+            monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
         rng = np.random.RandomState(1)
         feed = {k: jnp.asarray(v) for k, v in _feed(rng, batch=64).items()}
         dots = {}
         for use in (False, True):
-            main, startup, loss = _build(use)
+            main, startup, loss = _build(use, attention=kernel_site)
             sc = Scope()
             with scope_guard(sc):
                 exe = fluid.Executor(fluid.CPUPlace())
@@ -91,15 +112,41 @@ class TestRecompute:
                                        sc, "train")
                 rw = {n: sc.get(n) for n in cb.rw_names}
                 ro = {n: sc.get(n) for n in cb.ro_names}
-                txt = cb.jitted.lower(feed, rw, ro,
-                                      ex.rng_key(0)).as_text()
+                traced = cb.jitted.trace(feed, rw, ro, ex.rng_key(0))
+                txt = traced.lower().as_text()
                 dots[use] = txt.count("stablehlo.dot_general")
-                if use:
-                    assert txt.count("optimization_barrier") >= 1, (
-                        "recompute grad must root its re-forward in a "
-                        "barrier")
         # the remat graph re-runs the DEPTH hidden matmuls in backward
         assert dots[True] >= dots[False] + DEPTH, dots
+
+        eqns = traced.jaxpr.jaxpr.eqns
+        made_at = {v: i for i, e in enumerate(eqns) for v in e.outvars}
+        (barrier,) = [e for e in eqns
+                      if e.primitive.name == "optimization_barrier"]
+        loss_at = made_at[traced.jaxpr.jaxpr.outvars[0]]
+        assert max(made_at.get(v, -1) for v in barrier.invars) > loss_at, (
+            "the barrier must hold the region's incoming gradient")
+        behind = {v: i for i, v in enumerate(barrier.outvars)}
+        kernels = {e.params["name"]: e for e in eqns
+                   if e.primitive.name == "pallas_call"}
+        if not kernel_site:
+            assert not kernels
+            # DEPTH weights and biases, x, and the one incoming gradient
+            assert len(barrier.invars) == 2 * DEPTH + 2
+            return
+        assert sorted(kernels) == ["flash_attention_dkv", "flash_attention_dq",
+                                   "flash_attention_fwd"]
+        assert len(barrier.invars) == 2 * DEPTH + 2 + 3
+        kept = kernels["flash_attention_fwd"].outvars
+        assert all(any(v is k for v in barrier.invars) for k in kept)
+        m, l = (barrier.outvars[[v is k for v in barrier.invars].index(True)]
+                for k in kept[1:])
+        for name in ("flash_attention_dkv", "flash_attention_dq"):
+            reads = kernels[name].invars
+            assert any(v is m for v in reads) and any(v is l for v in reads)
+            assert not any(v is k for v in reads for k in kept)
+            # Q, K and V come from the re-run, behind the barrier
+            assert all(made_at.get(v, -1) > made_at[barrier.outvars[0]]
+                       or v in behind for v in reads)
 
     def test_multi_region_all_params_train(self):
         """Regression: the region op must DECLARE its captures as formal
