@@ -399,9 +399,11 @@ def publish_moe_counters(scope=None):
             continue
         raw = np.asarray(value).astype(np.int64) & 0xFFFFFFFF
         last, total = _moe_read.get(name, (np.zeros_like(raw),) * 2)
-        # the device's int32 wraps; a step count that fell is a restart
-        delta = raw if raw[-1] < last[-1] else (raw - last) & 0xFFFFFFFF
-        total = delta + (0 if raw[-1] < last[-1] else total)
+        # the device's int32 wraps; a step count that fell, or a vector of
+        # another length (another program's layer), is a restart
+        restart = last.shape != raw.shape or raw[-1] < last[-1]
+        delta = raw if restart else (raw - last) & 0xFFFFFFFF
+        total = delta + (0 if restart else total)
         _moe_read[name] = (raw, total)
         rows = [int(x) for x in total[:-3]]
         out[layer] = {"rows": rows, "possible": int(total[-3]),

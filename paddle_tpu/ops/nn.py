@@ -581,10 +581,11 @@ def fused_multihead_attention(ctx, attrs, Q, K, V, BiasQK=None):
 def _keeping_across_region(ctx, op_type, call, routes):
     """``call()``, a kernel site's lowering, in or out of a recompute
     region (``ctx.region``): the region's forward keeps what the forward
-    kernel gave (``call(return_residuals=True)``), the grad op's re-run
-    hands it back (``call(residuals=...)``) and runs no forward kernel;
-    where nothing crossed (no kernel here, or the region's forward was
-    lowered in another call) the kernel runs again, noted as
+    kernel gave (``call(return_residuals=True)``; the expert layer: its
+    plan and its first block's gate and up products), the grad op's
+    re-run hands it back (``call(residuals=...)``) and runs no forward
+    kernel; where nothing crossed (no kernel here, or the region's forward
+    was lowered in another call) the kernel runs again, noted as
     ``recomputed`` where ``routes()``."""
     region = ctx.region
     if region is None:
@@ -839,15 +840,21 @@ def moe_experts(ctx, attrs, X, Index, Gate, WGate, WUp, WDown):
     [D, F]) and WDown ([F, D]) are experts ``first_expert .. first_expert
     + held - 1`` of those Index counts over, stacked here for the grouped
     products.  Dropless.  Out: X's shape; Rows: [held] int32, the rows
-    each held expert was given this step."""
+    each held expert was given this step.  In a recompute region
+    (``ctx.region``) the site keeps its plan (the choices' order and the
+    rows) and the loop's first block's gate and up products across the
+    region; the re-run walks that plan and its backward takes the
+    products in place of that block's three forward products."""
     from ..parallel.moe import held_experts_ffn
 
     shape = jnp.shape(X)
-    out, rows = held_experts_ffn(
-        X.reshape(-1, shape[-1]), Index, Gate, jnp.stack(WGate),
-        jnp.stack(WUp), jnp.stack(WDown),
+    ffn = functools.partial(
+        held_experts_ffn, X.reshape(-1, shape[-1]), Index, Gate,
+        jnp.stack(WGate), jnp.stack(WUp), jnp.stack(WDown),
         first=int(attrs.get("first_expert", 0)), scope=ctx.part_scope,
         total=int(attrs.get("experts_total", 0)))
+    out, rows = _keeping_across_region(ctx, "moe_experts", ffn,
+                                       lambda: True)
     return {"Out": out.reshape(shape), "Rows": rows}
 
 

@@ -385,18 +385,52 @@ def blocks_run(rows, block=BLOCK_ROWS):
     return (jnp.sum(rows) + (block - 1)) // block
 
 
-def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes):
-    """One block of the buffer: xs [B, D] the rows' tokens, g [B] their
-    gates, sizes [held] the rows of each held expert inside the block.
-    Returns [B, D] float32, what each row adds to its token."""
+def _swiglu(h, u):
+    """``silu(h) * u`` in float32, in h's dtype."""
+    return (jax.nn.silu(h.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(h.dtype)
+
+
+def _gate_and_up(scope, xs, w_gate, w_up, sizes):
+    """A block's gate and up products, [B, F] each."""
     with scope("products"):
-        h = grouped_dot(xs, w_gate, sizes)
-        u = grouped_dot(xs, w_up, sizes)
-        a = (jax.nn.silu(h.astype(jnp.float32))
-             * u.astype(jnp.float32)).astype(xs.dtype)
-        y = grouped_dot(a, w_down, sizes)
+        return grouped_dot(xs, w_gate, sizes), grouped_dot(xs, w_up, sizes)
+
+
+def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes, kept=None):
+    """One block of the buffer: xs [B, D] the rows' tokens, g [B] their
+    gates, sizes [held] the rows of each held expert inside the block,
+    ``kept`` its gate and up products where they were computed before.
+    Returns [B, D] float32, what each row adds to its token."""
+    h, u = _gate_and_up(scope, xs, w_gate, w_up, sizes) if kept is None \
+        else kept
+    with scope("products"):
+        y = grouped_dot(_swiglu(h, u), w_down, sizes)
     with scope("combine"):
         return y.astype(jnp.float32) * g[:, None]
+
+
+def _block_ffn_bwd(scope, xs, g, w_gate, w_up, w_down, sizes, h, u, ct):
+    """The backward of :func:`_block_ffn` at ``ct`` [B, D] float32, from
+    the block's gate and up products ``h``, ``u``: six grouped products,
+    none of them forward (the pullbacks' other halves are dead, and XLA
+    drops them).  The gate's cotangent ``<y[r], ct[r]>`` is taken as
+    ``<a[r], (ct W_down^T)[r]>`` (``a = silu(h) * u``, ``y = a W_down``),
+    and ``ct W_down^T``, times the gate, is the cotangent of ``a``.
+    Returns ``(d_xs, d_g, [d_w_gate, d_w_up, d_w_down])``."""
+    with scope("products"):
+        a, swiglu_pullback = jax.vjp(_swiglu, h, u)
+        _, d_w_down, _ = _grouped_dot_bwd(
+            (a, w_down, sizes), (ct * g[:, None]).astype(xs.dtype))
+        ct_a, _, _ = _grouped_dot_bwd((a, w_down, sizes),
+                                      ct.astype(xs.dtype))
+        d_g = jnp.sum(a.astype(jnp.float32) * ct_a.astype(jnp.float32),
+                      axis=1)
+        d_h, d_u = swiglu_pullback(
+            (ct_a.astype(jnp.float32) * g[:, None]).astype(xs.dtype))
+        d_xs_gate, d_w_gate, _ = _grouped_dot_bwd((xs, w_gate, sizes), d_h)
+        d_xs_up, d_w_up, _ = _grouped_dot_bwd((xs, w_up, sizes), d_u)
+    return d_xs_gate + d_xs_up, d_g, [d_w_gate, d_w_up, d_w_down]
 
 
 def _block_inputs(scope, size, i, x, gates, order, rows):
@@ -415,19 +449,54 @@ def _block_inputs(scope, size, i, x, gates, order, rows):
     return choice, token, xs, g, sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _walk_blocks(scope, size, x, gates, w_gate, w_up, w_down, order, rows):
-    """``held_experts_ffn`` behind its plan: order [a multiple of the
-    block's ``size``] and rows [held] from :func:`plan_held_rows`."""
-    def block(i, out):
+def _forward_trip(scope, size, x, gates, w_gate, w_up, w_down, order,
+                  rows, kept=None):
+    """The forward walk's body: block i computed (from ``kept`` where
+    given) and added to the ``[T, D]`` float32 result it carries."""
+    def trip(i, out):
         _, token, xs, g, sizes = _block_inputs(scope, size, i, x, gates,
                                                order, rows)
-        y = _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes)
+        y = _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes, kept)
         with scope("combine"):
             return out.at[token].add(y, mode="promise_in_bounds")
+    return trip
 
-    out = jax.lax.fori_loop(0, blocks_run(rows, size), block,
-                            jnp.zeros(x.shape, jnp.float32))
+
+def _backward_trip(scope, size, args, ct, kept=None):
+    """The backward walk's body: block i differentiated from ``kept``
+    where given, else from its gate and up products computed anew, and
+    the cotangents of x (in x's dtype, as the transpose of a gather from x
+    adds them), the gates and the weights added to those it carries."""
+    x, gates, w_gate, w_up, w_down, order, rows = args
+
+    def trip(i, carry):
+        d_x, d_gates, d_weights = carry
+        choice, token, xs, g, sizes = _block_inputs(scope, size, i, x,
+                                                    gates, order, rows)
+        h, u = _gate_and_up(scope, xs, w_gate, w_up, sizes) \
+            if kept is None else kept
+        d_xs, d_g, d_w = _block_ffn_bwd(
+            scope, xs, g, w_gate, w_up, w_down, sizes, h, u,
+            ct.at[token].get(mode="promise_in_bounds").astype(jnp.float32))
+        return (d_x.at[token].add(d_xs, mode="promise_in_bounds"),
+                d_gates.at[choice].add(d_g, mode="promise_in_bounds"),
+                [a + b for a, b in zip(d_weights, d_w)])
+    return trip
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk_blocks(scope, size, x, gates, w_gate, w_up, w_down, order, rows,
+                 kept):
+    """``held_experts_ffn`` behind its plan: order [a multiple of the
+    block's ``size``] and rows [held] from :func:`plan_held_rows`, and
+    ``kept`` block 0's gate and up products (:func:`_first_products`) or
+    None.  Block 0 runs ahead of the loop whatever the load (on no routed
+    row it adds zero, as no trip does), from ``kept`` where given; the
+    loop takes the blocks after it."""
+    trip = functools.partial(_forward_trip, scope, size, x, gates, w_gate,
+                             w_up, w_down, order, rows)
+    out = trip(kept)(0, jnp.zeros(x.shape, jnp.float32))
+    out = jax.lax.fori_loop(1, blocks_run(rows, size), trip(), out)
     return out.astype(x.dtype)
 
 
@@ -436,37 +505,53 @@ def _walk_blocks_fwd(scope, size, *args):
 
 
 def _walk_blocks_bwd(scope, size, args, ct):
-    """The same loop again: a block is computed anew and differentiated
-    (``jax.vjp`` of the one block function), nothing is kept between
-    blocks, and the cotangents of x (in x's dtype, as the transpose of a
-    gather from x adds them), the gates and the weights are carried."""
-    x, gates, w_gate, w_up, w_down, order, rows = args
-
-    def block(i, carry):
-        d_x, d_gates, d_weights = carry
-        choice, token, xs, g, sizes = _block_inputs(scope, size, i, x,
-                                                    gates, order, rows)
-        _, pullback = jax.vjp(
-            lambda *a: _block_ffn(scope, *a, sizes), xs, g, w_gate, w_up,
-            w_down)
-        d_xs, d_g, *d_w = pullback(
-            ct.at[token].get(mode="promise_in_bounds").astype(jnp.float32))
-        return (d_x.at[token].add(d_xs, mode="promise_in_bounds"),
-                d_gates.at[choice].add(d_g, mode="promise_in_bounds"),
-                [a + b for a, b in zip(d_weights, d_w)])
-
+    """The same walk again (``_backward_trip``), block 0 from ``kept``
+    where the forward was given it; nothing is kept between blocks.
+    ``kept`` takes no cotangent: it is what a forward computed, not an
+    input."""
+    *args, kept = args
+    x, gates, w_gate, w_up, w_down, _, rows = args
+    trip = functools.partial(_backward_trip, scope, size, args, ct)
+    carry = trip(kept)(0, (jnp.zeros_like(x),
+                           jnp.zeros(gates.size, gates.dtype),
+                           [jnp.zeros_like(w) for w in (w_gate, w_up,
+                                                        w_down)]))
     d_x, d_gates, d_weights = jax.lax.fori_loop(
-        0, blocks_run(rows, size), block,
-        (jnp.zeros_like(x), jnp.zeros(gates.size, gates.dtype),
-         [jnp.zeros_like(w) for w in (w_gate, w_up, w_down)]))
-    return d_x, d_gates.reshape(gates.shape), *d_weights, None, None
+        1, blocks_run(rows, size), trip(), carry)
+    return (d_x, d_gates.reshape(gates.shape), *d_weights, None, None,
+            jax.tree.map(lambda _: None, kept))
 
 
 _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 
 
+def _first_products(scope, size, x, gates, w_gate, w_up, order, rows):
+    """Block 0's gate and up products ``(h, u)``, [size, F] each, as a
+    recompute region's forward keeps them: computed outside any
+    differentiation (the gradient goes through ``_walk_blocks``)."""
+    x, w_gate, w_up = map(jax.lax.stop_gradient, (x, w_gate, w_up))
+    _, _, xs, _, sizes = _block_inputs(scope, size, 0, x, gates, order,
+                                       rows)
+    return _gate_and_up(scope, xs, w_gate, w_up, sizes)
+
+
+def _gates_in_places(idx, chosen, gates):
+    """gates [T, K], the router's for its choices ``chosen``, in the
+    places of ``idx`` [T, K]: each gate goes to its expert's place where a
+    token's choices are the same experts in another order, and a place
+    whose expert the router did not choose keeps the gate it holds.  A
+    masked sum over the K places, one of them at most matching (a token's
+    choices are distinct experts): no gather, and so no scatter in the
+    backward."""
+    same = idx[:, :, None] == chosen[:, None, :]
+    return jnp.where(same.any(-1),
+                     jnp.sum(jnp.where(same, gates[:, None, :], 0), -1),
+                     gates)
+
+
 def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
-                     scope=jax.named_scope, total=None):
+                     scope=jax.named_scope, total=None, residuals=None,
+                     return_residuals=False):
     """The held experts' part of ``sum_k gates[t,k] * E_idx[t,k](x[t])``
     with ``E(x) = W_down (silu(W_gate x) * W_up x)``.
 
@@ -491,16 +576,40 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
     tokens that chose an expert elsewhere; their products are zero, so
     they add nothing and take no gradient to a weight.  A loop with a
     traced trip count has no reverse mode, so the backward pass is the
-    same loop again (``_walk_blocks_bwd``).  ``scope(name)`` names the
-    three parts of a block for a device trace: ``dispatch``, ``products``
-    and ``combine``."""
+    same walk again (``_walk_blocks_bwd``), each block's gate and up
+    products computed anew.  ``scope(name)`` names the three parts of a
+    block for a device trace: ``dispatch``, ``products`` and ``combine``.
+
+    ``return_residuals``: return ``((y, rows), residuals)``, where
+    residuals are ``idx`` and the first block's gate and up products
+    ``h``, ``u`` ([block rows, F] each in x's dtype).  ``residuals``: such
+    a tuple from an earlier call on the same inputs (a recompute region's
+    re-run: ``ops/control_flow.py``).  The call then plans from that idx
+    and not from its own: a re-run whose router chose otherwise by a
+    rounding (a near tie in the top k) would pair the kept rows with other
+    tokens.  Its gates are taken to the kept choices' places
+    (:func:`_gates_in_places`).  The first block runs its down product
+    alone, and its backward runs six transposed products from ``h`` and
+    ``u`` and no forward one; the blocks after the first are computed
+    anew in the backward pass as without them."""
     held = w_gate.shape[0]
     size = block_rows(idx.size, held, total)
+    kept = None
     with scope("dispatch"):
+        if residuals is not None:
+            kept_idx, *kept = residuals
+            gates = _gates_in_places(kept_idx, idx, gates)
+            idx = kept_idx
         order, rows = plan_held_rows(idx, first, held)
         # padded places lie past every routed row: choice 0, which adds 0
         order = jnp.pad(order, (0, -order.size % size))
-    out = _walk_blocks(scope, size, x, gates, w_gate.astype(x.dtype),
-                       w_up.astype(x.dtype), w_down.astype(x.dtype),
-                       order, rows)
+    weights = (w_gate.astype(x.dtype), w_up.astype(x.dtype),
+               w_down.astype(x.dtype))
+    if return_residuals and kept is None:
+        kept = _first_products(scope, size, x, gates, *weights[:2], order,
+                               rows)
+    out = _walk_blocks(scope, size, x, gates, *weights, order, rows,
+                       None if kept is None else tuple(kept))
+    if return_residuals:
+        return (out, rows), (idx, *kept)
     return out, rows

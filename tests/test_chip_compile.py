@@ -29,7 +29,7 @@ from paddle_tpu.ops.pallas.flash_decode import flash_decode
 from paddle_tpu.ops.pallas.fused_ln import fused_dropout_add_ln
 from paddle_tpu.ops.pallas.paged_flash_decode import paged_flash_decode
 from paddle_tpu.ops.pallas.ssd_scan import ssd_scan
-from paddle_tpu.parallel.moe import held_experts_ffn
+from paddle_tpu.parallel.moe import block_rows, held_experts_ffn
 from paddle_tpu.quant.blockwise import block_dequantize, block_quantize
 
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
@@ -189,6 +189,45 @@ def test_expert_layer_loop(chip, grad):
     kernels = pallas.pallas_kernels_in(compiled.as_text())
     assert any("ragged-dot" in name for name in kernels)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.08 * 2**30 / 4
+
+
+def test_expert_layer_under_a_region(chip):
+    """The mellum2 cell's expert layer (16,384 tokens, top 8, 8 of 64
+    experts held, widths 2304 / 896, bf16) as a recompute region runs it:
+    the forward keeps the router's choices and the first block's gate and
+    up products, the re-run takes them across the barrier and its
+    backward runs from them (what follows the layer in the region is
+    linear, so the re-run's own output is not needed).  The grouped kernel is in the step, and keeping adds
+    to the temporaries less than what crosses: h and u, [24,576, 896]
+    bf16 each, and the choices, [16,384, 8] int32 (with nothing kept the
+    backward computes its own h and u).  (Neither form stays under the
+    kanana-shaped layer's bound at these widths and the cell's block:
+    0.55 and 0.61 GB.)"""
+    t, d, f, k, held, total = 16384, 2304, 896, 8, 8, 64
+
+    def region(keep):
+        def step(x, idx, gates, *w):
+            (out, _), kept = held_experts_ffn(
+                x, idx, gates, *w, total=total, return_residuals=True)
+            kept = jax.lax.optimization_barrier(kept if keep else None)
+            return out, jax.grad(lambda x, gates, *w: jnp.sum(
+                held_experts_ffn(x, idx, gates, *w, total=total,
+                                 residuals=kept)[0].astype(F32)
+                * jax.lax.stop_gradient(x).astype(F32)),
+                argnums=(0, 1, 2, 3, 4))(x, gates, *w)
+        return step
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        ((t, d), BF16), ((t, k), I32), ((t, k), F32), ((held, d, f), BF16),
+        ((held, d, f), BF16), ((held, f, d), BF16))]
+    kept, anew = (jax.jit(region(keep)).lower(*args).compile()
+                  for keep in (True, False))
+    assert any("ragged-dot" in name
+               for name in pallas.pallas_kernels_in(kept.as_text()))
+    crossing = 2 * block_rows(t * k, held, total) * f * 2 + t * k * 4
+    assert crossing == 2 * 24576 * 896 * 2 + 16384 * 8 * 4
+    assert (kept.memory_analysis().temp_size_in_bytes
+            < anew.memory_analysis().temp_size_in_bytes + crossing)
 
 
 # -- the Mamba-2 scan ----------------------------------------------------------

@@ -24,9 +24,15 @@ from paddle_tpu.executor import Scope, scope_guard
 from paddle_tpu.models import bert, decoder
 from paddle_tpu.observability import metrics, tracing
 from paddle_tpu.ops import control_flow, registry
+from paddle_tpu.parallel import moe
 
 T = 128
 FLASH = "fused_multihead_attention"
+MOE = "moe_experts"
+# the "mla" case's one expert layer keeps h and u of its first block,
+# [1,024 rows (the least block), 32] float32 each, and the router's
+# choices, [2 x 128 tokens, 2] int32
+MOE_KEPT_BYTES = 2 * 1024 * 32 * 4 + 2 * T * 2 * 4
 # name: (builder, flash sites in regions); every layer is one region
 CASES = {
     # attention dropout off and on (the re-run draws the forward's mask)
@@ -111,6 +117,21 @@ def _train(case, steps=2):
 _kept_run = functools.lru_cache(maxsize=None)(_train)
 
 
+def _experts_recomputed(monkeypatch):
+    """The expert layer with nothing kept across its region, as in the
+    parent's lowering (``tests/test_moe_kept.py`` holds that crossing):
+    its backward, whose first block the kept h and u change by a rounding
+    of its sums, is then the same on both sides of a comparison of the
+    flash sites alone."""
+    whole = moe.held_experts_ffn
+
+    def unkept(*args, residuals=None, return_residuals=False, **kw):
+        out = whole(*args, **kw)
+        return (out, None) if return_residuals else out
+
+    monkeypatch.setattr(moe, "held_experts_ffn", unkept)
+
+
 def _parents_lowering(monkeypatch):
     """The forward ops and the rest of every op list in two
     ``_run_ops_into_env`` calls: no region's two ops meet in one, so
@@ -149,18 +170,36 @@ def test_equal_to_the_parents_lowering_bit_for_bit(case, monkeypatch):
     same backward kernels; on the CPU in float32 forward and re-run
     compute the same Q, K and V, so the backward kernels read the same
     ``o, m, l`` either way: losses of two steps and the first step's
-    gradient of every parameter are the same bytes."""
+    gradient of every parameter are the same bytes, the decoders' expert
+    layers taking the parent's path on both sides
+    (``_experts_recomputed``).  The program as it ships, its expert
+    layers keeping h and u too, has the same first loss, and every
+    gradient within ``tests/test_moe_kept.py``'s float32 allowance."""
     kept = _kept_run(case)
-    _parents_lowering(monkeypatch)
-    parent = _train(case)
+    with monkeypatch.context() as mp:
+        _parents_lowering(mp)
+        parent = _train(case)
     n = CASES[case][2]
     kernels = _kernels(parent[2])
     assert (kernels["flash_attention_fwd"], kernels["flash_attention_dkv"],
             kernels["flash_attention_dq"]) == (2 * n, n, n)
     assert parent[3][FLASH, "recomputed"] == n
     assert (FLASH, "kept_across_region") not in parent[3]
-    assert kept[0] == parent[0]
     assert kept[1].keys() == parent[1].keys() and len(kept[1]) > 8
+    if CASES[case][0] == "decoder":
+        assert parent[3][MOE, "recomputed"] \
+            == kept[3][MOE, "kept_across_region"] >= 1
+        assert kept[0][0] == parent[0][0]
+        grad = {n: [np.frombuffer(g[n], "float32") for g in (kept[1],
+                                                              parent[1])]
+                for n in parent[1]}
+        moved = {n: np.linalg.norm(a - b) / np.linalg.norm(b)
+                 for n, (a, b) in grad.items() if np.linalg.norm(b) > 0}
+        assert max(moved.values()) < 1e-5, sorted(moved.items())[-5:]
+        assert any(".experts." in n for n in moved)
+        _experts_recomputed(monkeypatch)
+        kept = _train(case)
+    assert kept[0] == parent[0]
     assert [k for k in kept[1] if kept[1][k] != parent[1][k]] == []
 
 
@@ -203,8 +242,11 @@ def test_forward_only_programs_with_regions_lower_as_before(
 
 def test_a_composite_site_in_a_region_keeps_nothing(monkeypatch):
     """PADDLE_TPU_PALLAS=off: XLA attention in the region, no kernel, so
-    nothing to keep, nothing counted, and the region's barrier holds the
-    captured inputs and the incoming gradients alone."""
+    the attention keeps nothing and is not counted, and the region's
+    barrier holds the captured inputs, the incoming gradients and what
+    the expert layer keeps (its first block's h and u, [1,024, 32]
+    float32 each: ``tests/test_moe_kept.py``), three fewer than with the
+    flash kernel's ``o, m, l``."""
     with_kernel = [len(e.invars) for e in _kept_run("mla")[2].jaxpr.eqns
                    if e.primitive.name == "optimization_barrier"]
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "off")
@@ -212,7 +254,7 @@ def test_a_composite_site_in_a_region_keeps_nothing(monkeypatch):
         losses, _, jaxpr, sites = _train("mla", steps=1)
     assert np.isfinite(np.frombuffer(losses[0], "float32")).all()
     assert not _kernels(jaxpr) and "_flash_kept" not in str(jaxpr)
-    assert sites == {}
+    assert sites == {(MOE, "kept_across_region"): 1}
     composite = [len(e.invars) for e in jaxpr.jaxpr.eqns
                  if e.primitive.name == "optimization_barrier"]
     assert composite == [n - 3 for n in with_kernel]
@@ -220,7 +262,7 @@ def test_a_composite_site_in_a_region_keeps_nothing(monkeypatch):
              if r["name"] == "executor.compile"
              and "recompute_kept_bytes" in r["attrs"]][-1]
     assert (attrs["grad_residual_sites_kept_across_region"],
-            attrs["recompute_kept_bytes"]) == (0, 0)
+            attrs["recompute_kept_bytes"]) == (1, MOE_KEPT_BYTES)
 
 
 def test_a_fused_ln_site_in_a_region_keeps_nothing():
@@ -237,8 +279,9 @@ def test_a_fused_ln_site_in_a_region_keeps_nothing():
 
 
 def test_compile_span_carries_the_kept_count_and_bytes():
-    """``executor.compile`` of the step that traced the block: two sites,
-    each ``o`` [B*H, T, d_v] and ``m``, ``l`` [B*H, 1, T] in float32."""
+    """``executor.compile`` of the step that traced the block: two flash
+    sites, each ``o`` [B*H, T, d_v] and ``m``, ``l`` [B*H, 1, T] in
+    float32, and the one expert layer's first block's h and u."""
     with tracing.span("test.root"):     # inside a trace every step records
         _train("mla", steps=1)
     attrs = [r["attrs"] for r in tracing.get_tracer().records()
@@ -247,9 +290,9 @@ def test_compile_span_carries_the_kept_count_and_bytes():
     heads, dv = 4, 16
     assert (attrs["grad_residual_sites_kept_across_region"],
             attrs["grad_residual_sites_reused"],
-            attrs["grad_residual_sites_recomputed"]) == (2, 0, 0)
+            attrs["grad_residual_sites_recomputed"]) == (3, 0, 0)
     assert attrs["recompute_kept_bytes"] == 2 * (
-        2 * heads * T * dv * 4 + 2 * 2 * heads * T * 4)
+        2 * heads * T * dv * 4 + 2 * 2 * heads * T * 4) + MOE_KEPT_BYTES
 
 
 def test_a_site_in_a_loop_inside_a_region_keeps_nothing(monkeypatch):
