@@ -467,7 +467,8 @@ class _CompiledBlock:
         # ``ssd_scan.noting_chunks``), reported the same way
         self.flash_blocks = {}
         self.ssd_chunks = {}
-        self.compile_phase = None
+        # and why the runner built this block (``_miss_cause``)
+        self.compile_phase = self.compile_cause = None
         ext_reads, written, persist_written = _analyze_block(
             block, feed_names, fetch_names
         )
@@ -1089,17 +1090,52 @@ def _feed_signature(feed_vals):
                  for n, v in sorted(feed_vals.items()))
 
 
-def _compile_step(runner, build, program, feed_vals, fetch_names):
+#: the runners' cache key after the resolved program's identity and
+#: version: what tells two compiled blocks of one program apart, in the
+#: order in which a recompile's cause is named (the first that differs).
+#: ``program_version`` is the caller's program's, before fusion resolves
+#: its twin
+CompileFields = collections.namedtuple("CompileFields", [
+    "program_version", "scope", "feed_signature", "fetch_list",
+    "trip_counts", "nan_guard", "fusion_signature"])
+
+
+def _miss_cause(newest, program, fields, use_cache=True):
+    """Why a runner's lookup missed: the first of ``fields`` that differs
+    from those of the newest block compiled for ``program`` (the caller's
+    program); ``first`` when there is none, ``no_program_cache`` when the
+    cache is off, and ``fused_program`` when the fields agree and the
+    resolved twin is a new object.  ``newest`` (id of the program ->
+    fields) then holds these."""
+    if not use_cache:
+        return "no_program_cache"
+    prev = newest.get(id(program))
+    newest[id(program)] = fields
+    if prev is None:
+        return "first"
+    for name, now, before in zip(fields._fields, fields, prev):
+        if now != before:
+            return name
+    return "fused_program"
+
+
+def _compile_step(runner, build, program, feed_vals, fetch_names, cause):
     """The ``compile`` phase of both runners: ``build()`` makes the
-    :class:`_CompiledBlock` (trace, lower, jit)."""
-    with _tr.phase(runner + ".compile") as ph:
+    :class:`_CompiledBlock`; the cost model's registration is its child
+    ``compile.telemetry``.  jax traces, lowers and compiles the block at
+    its first dispatch (``_dispatch_step``)."""
+    _tr.watch_compiles()
+    _tr.mark_compiled()
+    with _tr.phase(runner + ".compile", cause=cause) as ph:
         compiled = build()
+        with _tr.phase(runner + ".compile.telemetry"):
+            _register_compile_telemetry(compiled, program, feed_vals,
+                                        fetch_names)
     compiled.compile_phase = ph
+    compiled.compile_cause = cause
     ph.set_attr("compile_ms", round(ph.dur_ms, 2))
-    _obs.record_compile(ph.dur_ms, runner=runner)
     _obs.record_moe_layers(program, ph)
     _obs.record_attention_layers(program, ph)
-    _register_compile_telemetry(compiled, program, feed_vals, fetch_names)
     return compiled
 
 
@@ -1131,14 +1167,25 @@ def _dispatch_step(runner, step_phase, compiled, program, scope, feed_vals,
     with _tr.phase(runner + ".dispatch",
                    **_obs.collective_step_shape()) as dispatch:
         fetches, new_rw, fresh = compiled.jitted(feed_vals, rw, ro, base_key)
-    if compiled.compile_phase is not None:
-        # this block's first dispatch: jax.jit traced the step just now
-        _obs.record_grad_residual_sites(
-            compiled.residual_sites.values(), compiled.compile_phase)
-        _obs.record_flash_blocks(compiled.flash_blocks,
-                                 compiled.compile_phase)
-        _obs.record_ssd_chunks(compiled.ssd_chunks, compiled.compile_phase)
+    built = compiled.compile_phase
+    if built is not None:
+        # this block's first dispatch: jax.jit traced, lowered and
+        # compiled the step just now
+        _obs.record_grad_residual_sites(compiled.residual_sites.values(),
+                                        built)
+        _obs.record_flash_blocks(compiled.flash_blocks, built)
+        _obs.record_ssd_chunks(compiled.ssd_chunks, built)
+        _obs.record_compile(runner, cur_step, compiled.compile_cause,
+                            built.dur_ms, _tr.compile_stages(dispatch))
         compiled.compile_phase = None
+    else:
+        stages = _tr.compile_stages(dispatch)
+        if stages is not None and stages["backend_ms"]:
+            # the lookup hit and jax compiled all the same: it
+            # specialised the step anew on its arguments (their
+            # shardings, weak types or devices)
+            step_phase.set_attr("cause", "arguments")
+            _obs.record_compile(runner, cur_step, "arguments", 0.0, stages)
     with _tr.phase(runner + ".apply_results"):
         fetches = _apply_step_results(
             compiled, scope, fetches, new_rw, fresh, fetch_names,
@@ -1175,6 +1222,7 @@ class Executor:
     def __init__(self, place=None):
         self.place = place if place is not None else core.TPUPlace(0)
         self._cache = {}
+        self._newest = {}       # id(program) -> CompileFields, newest
         self._feed_cache = _pipeline.FeedCache()
         self._step = 0
         # [step, perf_counter_ns] of the newest step of this executor
@@ -1184,6 +1232,7 @@ class Executor:
 
     def close(self):
         self._cache.clear()
+        self._newest.clear()
         self._feed_cache.clear()
 
     def run(
@@ -1267,6 +1316,7 @@ class Executor:
         # silently re-enabling families the user disabled.
         from .static_analysis import fusion as _fusion
 
+        source = program
         with _tr.phase("executor.fusion_resolve"):
             program, _fusion_report = _fusion.resolve_fused_program(
                 program, config=fusion_config, targets=fetch_names)
@@ -1325,23 +1375,21 @@ class Executor:
             if _has_unbounded_while_grad(program):
                 trip_counts = _probe_trip_counts(
                     program.global_block(), feed_vals, scope, fetch_names)
-            key_tuple = (
-                id(program),
-                program._version,
-                id(scope),
-                _feed_signature(feed_vals),
+            fields = CompileFields(
+                source._version, id(scope), _feed_signature(feed_vals),
                 tuple(fetch_names),
-                tuple(sorted((trip_counts or {}).items())),
-                nan_guard,
+                tuple(sorted((trip_counts or {}).items())), nan_guard,
                 # fusion config is part of the compilation identity: the
                 # same source program under a different fusion config is
                 # a different (cloned) program object, and the signature
                 # makes the separation explicit/debuggable
-                getattr(program, "_fusion_sig", None),
-            )
+                getattr(program, "_fusion_sig", None))
+            key_tuple = (id(program), program._version) + fields
             compiled = (self._cache.get(key_tuple) if use_program_cache
                         else None)
-            _obs.record_jit_cache(compiled is not None)
+            cause = None if compiled is not None else _miss_cause(
+                self._newest, source, fields, use_program_cache)
+            _obs.record_jit_cache(compiled is not None, cause=cause)
         if compiled is None:
             def _compile():
                 # injectable site (compile_fail) — and transient
@@ -1364,7 +1412,7 @@ class Executor:
                 "executor",
                 lambda: _rretry.retry_call(_compile,
                                            site="executor.compile"),
-                program, feed_vals, fetch_names)
+                program, feed_vals, fetch_names, cause)
             if use_program_cache:
                 self._cache[key_tuple] = compiled
 

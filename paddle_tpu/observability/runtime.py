@@ -225,24 +225,41 @@ def _observe_step_time(runner, step, step_ms, drift_key):
         _drift.monitor().observe_step(step_ms, key=drift_key, step=step)
 
 
-def record_jit_cache(hit, runner="executor"):
+def record_jit_cache(hit, runner="executor", cause=None):
+    """A runner's lookup; a miss counts under its ``cause``
+    (``executor._miss_cause``)."""
     _tracing.phase_attr("hit", bool(hit))
     if not telemetry_enabled():
         return
-    key = (runner, bool(hit))
+    key = (runner, bool(hit), cause)
     c = _jit_handles.get(key)
     if c is None:
-        c = _m.counter("jit_cache_hits_total" if hit
-                       else "jit_cache_misses_total", runner=runner)
+        c = _m.counter("jit_cache_hits_total", runner=runner) if hit \
+            else _m.counter("jit_cache_misses_total", runner=runner,
+                            cause=str(cause))
         _jit_handles[key] = c
     c.inc()
 
 
-def record_compile(ms, runner="executor"):
+def record_compile(runner, step, cause, build_ms, stages):
+    """A compile has returned: a block's first dispatch (``build_ms`` its
+    ``compile`` phase), or a dispatch whose lookup hit and which jax
+    compiled again (``cause`` ``arguments``, ``build_ms`` 0).  ``stages``
+    are ``tracing.compile_stages`` of that ``dispatch`` phase (None where
+    nothing was recorded, as under the kill switch): ``compile_ms{runner}``
+    observes the build plus the union of the stages, and the journal's
+    ``compile`` event carries the step, the cause and each stage."""
     if not telemetry_enabled():
         return
+    stages = stages or {}
+    ms = build_ms + stages.get("union_ms", 0.0)
     _m.histogram("compile_ms", runner=runner).observe(ms)
-    _journal.emit("compile", runner=runner, compile_ms=round(ms, 2))
+    _journal.emit(
+        "compile", runner=runner, step=step, cause=cause,
+        compile_ms=round(ms, 2),
+        **{k: round(stages.get(k, 0.0), 2)
+           for k in ("trace_ms", "lower_ms", "backend_ms")},
+        source=stages.get("source"))
 
 
 def record_grad_residual_sites(sites, compile_phase):

@@ -32,11 +32,16 @@ lies on the host plane of whatever device trace is running, on the
 device ops' clock) plus two ``time.perf_counter_ns()`` stamps in a
 per-step list.  The stamps become spans only when the step is *kept*:
 inside an ambient trace, head-sampled 1 in ``PADDLE_TPU_TRACE_SAMPLE``,
-or **slow** (over :data:`SLOW_FACTOR` times the running median of its
-kind).  GC pauses of a millisecond or more are ``host.gc`` spans; the
-``gc.callbacks`` hook that notes them takes no lock and builds no span
-(it runs wherever a collection fires, inside this module's own critical
-sections too): what it noted is recorded later, outside any lock.
+**slow** (over :data:`SLOW_FACTOR` times the running median of its
+kind), or **compiled** (it built a block, or jax lowered or compiled
+inside it).  jax's own compile events (``jax.monitoring``: trace, lower,
+backend compile or persistent-cache retrieval) are closed children of
+the phase that paid for them, ``<runner>.compile.<stage>``
+(:func:`watch_compiles`).  GC pauses of a millisecond or more are
+``host.gc`` spans; the ``gc.callbacks`` hook that notes them takes no
+lock and builds no span (it runs wherever a collection fires, inside
+this module's own critical sections too): what it noted is recorded
+later, outside any lock.
 
 Reconstruct and analyze with ``python -m paddle_tpu.tools.trace DIR``.
 """
@@ -58,7 +63,8 @@ __all__ = [
     "SCHEMA_VERSION", "TRACEPARENT_ENV", "SpanContext", "Span",
     "Tracer", "get_tracer", "reset_tracing", "tracing_enabled",
     "set_tracing_enabled", "set_rank", "span", "start_span",
-    "phase", "phase_attr", "phase_count",
+    "phase", "phase_attr", "phase_count", "mark_compiled",
+    "watch_compiles", "compile_stages",
     "sample_step", "step_sample_every",
     "current_span",
     "current_context", "current_trace_id", "current_traceparent",
@@ -586,8 +592,9 @@ class phase:
     the list becomes ``Span`` records (:func:`start_span` backdated, so
     ids, parents, the ring, JSONL and the flight recorder are the
     ordinary ones) if the step is inside an ambient trace, if
-    ``head_sample`` and :func:`sample_step` says so, **or if it was
-    slow** (:data:`SLOW_FACTOR`; attribute ``slow=true``, not a status).
+    ``head_sample`` and :func:`sample_step` says so, **if it was
+    slow** (:data:`SLOW_FACTOR`; attribute ``slow=true``, not a status),
+    **or if it compiled** (:func:`mark_compiled`; ``compiled=true``).
     Otherwise the list is dropped; a GC pause noted inside it is then a
     ``host.gc`` span of its own."""
 
@@ -623,6 +630,7 @@ class phase:
         if entries is None:
             entries = tls.phases = []
             tls.cur = -1
+            tls.stages = tls.cache_source = None
             _open_phases[threading.get_ident()] = (_thread_name(), entries)
         entry[_PARENT] = tls.cur
         tls.cur = len(entries)
@@ -681,7 +689,8 @@ def _finish_root(entries, head_sample):
     slow = _is_slow(root[_NAME], root[_T1] - root[_T0])
     if slow:
         root[_ATTRS]["slow"] = True
-    keep = (slow or current_context() is not None
+    keep = (slow or root[_ATTRS].get("compiled")
+            or current_context() is not None
             or (head_sample and sample_step(root[_ATTRS].get("step"))))
     for e in entries:
         if e[_NAME] != "host.gc":
@@ -694,19 +703,164 @@ def _finish_root(entries, head_sample):
     if not keep:
         return
     now_ns, now_ts = time.perf_counter_ns(), time.time()
+    # by the entry's place in the list; None for a compile stage that a
+    # wider one covered (no entry is a child of a stage)
     spans = []
     for e in entries:
-        spans.append(start_span(
+        spans.append(None if e[_NAME] is None else start_span(
             e[_NAME], parent=spans[e[_PARENT]] if e[_PARENT] >= 0 else None,
             start_ts=now_ts - (now_ns - e[_T0]) / 1e9, t0_ns=e[_T0],
             **e[_ATTRS]))
     if not spans[0].recording:      # switched off under our feet
         return
+    closed = []
     for s, e in zip(spans, entries):
-        s._close(e[_STATUS], (e[_T1] - e[_T0]) / 1e6)
+        if s is not None:
+            s._close(e[_STATUS], (e[_T1] - e[_T0]) / 1e6)
+            closed.append(s)
     # children before their parents, as live spans would have closed;
     # the whole tree under one lock and in one write
-    get_tracer()._on_end(spans[::-1])
+    get_tracer()._on_end(closed[::-1])
+
+
+# ---------------------------------------------------------------------------
+# compile stages: jax's own compile events, children of the paying phase
+# ---------------------------------------------------------------------------
+
+#: ``jax.monitoring`` duration event -> stage of a ``<runner>.compile.*``
+#: span; a stage's work may run a former stage's (a lowering rule that
+#: traces a function), never a later one's
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_STAGES = tuple(COMPILE_STAGES.values())
+# event -> the ``source`` of the next ``backend`` stage on that thread
+# (no event, as when the persistent cache is off or skips a small
+# module: ``compiled``)
+_CACHE_SOURCES = {
+    "/jax/compilation_cache/cache_hits": "persistent_cache",
+    "/jax/compilation_cache/cache_misses": "compiled",
+}
+_watch_lock = threading.Lock()
+_watching = False
+
+
+def mark_compiled():
+    """The step this thread is inside compiled: it is kept whatever the
+    sampling said, with the attribute ``compiled=true``."""
+    entries = getattr(_tls, "phases", None)
+    if entries:
+        entries[0][_ATTRS]["compiled"] = True
+
+
+def watch_compiles():
+    """Register the compile-event listeners, once per process (the
+    runners call this before their first compile).  From then on every
+    trace, lowering and backend compile jax runs inside a :class:`phase`
+    is a closed child of this thread's innermost open phase."""
+    global _watching
+    if _watching:
+        return
+    with _watch_lock:
+        if _watching:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_compile_event)
+        monitoring.register_event_listener(_on_cache_event)
+        _watching = True
+
+
+def _on_cache_event(event, **kwargs):
+    if not tracing_enabled():
+        return
+    source = _CACHE_SOURCES.get(event)
+    if source is not None and getattr(_tls, "phases", None):
+        _tls.cache_source = source
+
+
+def _on_compile_event(event, duration, **kwargs):
+    """One of :data:`COMPILE_STAGES` closed just now, ``duration`` seconds
+    after it began: a closed ``<runner>.compile.<stage>`` entry (``fun``:
+    jax's name of the function; ``source`` on ``backend``) under the
+    innermost open phase.  **Nested events count once:** jax reports a
+    trace inside a trace, inner first, so an event inside a kept one of
+    its stage is dropped, and a kept one that a new event of its stage or
+    a later stage covers goes (the traces a lowering rule runs are the
+    lowering's time).  A stage's entries therefore never overlap, nor
+    does one lie inside a later stage's.  A step that lowered or compiled
+    is kept (:func:`mark_compiled`); a trace alone (a function jax traced
+    again, whose lowering it had) keeps nothing.  Outside any phase:
+    nothing."""
+    if not tracing_enabled():
+        return
+    stage = COMPILE_STAGES.get(event)
+    tls = _tls
+    entries = getattr(tls, "phases", None)
+    if stage is None or not entries:
+        return
+    t1 = time.perf_counter_ns()
+    # jax times the event on its own clock: it began inside this phase
+    t0 = max(t1 - int(duration * 1e9), entries[tls.cur][_T0])
+    stages = tls.stages
+    if stages is None:
+        stages = tls.stages = {}
+    # kept entries end in order (each was stamped when it closed) and a
+    # stage's do not overlap, so those this one covers are the last ones
+    for former in _STAGES[:_STAGES.index(stage) + 1]:
+        kept = stages.get(former)
+        while kept and kept[-1][_T0] >= t0:
+            kept.pop()[_NAME] = None
+    kept = stages.setdefault(stage, [])
+    if kept and kept[-1][_T1] >= t1:
+        return
+    root = entries[0]
+    attrs = {"fun": kwargs.get("fun_name")}
+    if stage == "backend":
+        attrs["source"] = tls.cache_source or "compiled"
+        tls.cache_source = None
+    if stage != "trace":
+        root[_ATTRS]["compiled"] = True
+    entry = ["%s.compile.%s" % (root[_NAME].partition(".")[0], stage),
+             t0, t1, tls.cur, attrs, "ok"]
+    kept.append(entry)
+    entries.append(entry)
+
+
+def compile_stages(ph):
+    """What jax compiled directly under the :class:`phase` ``ph`` of the
+    step this thread is inside: ``{"trace_ms", "lower_ms",
+    "backend_ms"}``, each stage's union, ``"union_ms"``, the union of
+    all three, and ``"source"`` of the last ``backend`` stage.  None when
+    nothing was recorded there."""
+    stages = getattr(_tls, "stages", None)
+    entries = getattr(_tls, "phases", None)
+    if not stages or not entries:
+        return None
+    out = {"trace_ms": 0.0, "lower_ms": 0.0, "backend_ms": 0.0,
+           "source": None}
+    spans = []
+    for stage, kept in stages.items():
+        for e in kept:
+            if entries[e[_PARENT]] is ph._entry:
+                out[stage + "_ms"] += (e[_T1] - e[_T0]) / 1e6
+                spans.append((e[_T0], e[_T1]))
+                if stage == "backend":
+                    out["source"] = e[_ATTRS]["source"]
+    if not spans:
+        return None
+    union, end = 0, None
+    for t0, t1 in sorted(spans):
+        if end is None or t0 > end:
+            union += t1 - t0
+            end = t1
+        elif t1 > end:
+            union += t1 - end
+            end = t1
+    out["union_ms"] = union / 1e6
+    return out
 
 
 def _open_phase_records(rank, now_ns):
@@ -1020,7 +1174,7 @@ def reset_tracing():
     _gc_counts[:] = _gc_folded[:] = [0, 0, 0]
     for noted in (_gc_handles, _gc_pauses, _open_phases):
         noted.clear()
-    _tls.phases = _tls.medians = None
+    _tls.phases = _tls.medians = _tls.stages = _tls.cache_source = None
     set_tracing_enabled(None)
     set_remote_parent(None)
     global _sample_every

@@ -14,9 +14,9 @@ the XLA partitioner.
 import numpy as np
 
 from .. import core
-from ..executor import (_CompiledBlock, _compile_step, _dispatch_step,
-                        _feed_signature, _host_table_prefetch,
-                        _stage_feeds, global_scope)
+from ..executor import (CompileFields, _CompiledBlock, _compile_step,
+                        _dispatch_step, _feed_signature, _host_table_prefetch,
+                        _miss_cause, _stage_feeds, global_scope)
 from ..observability import runtime as _obs
 from ..observability import tracing as _tr
 from ..framework import Variable, default_main_program
@@ -69,6 +69,7 @@ class SPMDRunner:
             or getattr(program, "_shard_optimizer_state", False))
         self._last_fusion_report = None
         self._cache = {}
+        self._newest = {}       # id(program) -> CompileFields, newest
         from ..pipeline import FeedCache
 
         self._feed_cache = FeedCache()
@@ -146,11 +147,16 @@ class SPMDRunner:
             program, feed, feed_vals)
         fetch_names = fetch_names + host_grad_fetches
         with _tr.phase("spmd.lookup"):
-            key_tuple = (id(program), program._version, id(scope),
-                         _feed_signature(feed_vals), tuple(fetch_names),
-                         nan_guard, getattr(program, "_fusion_sig", None))
+            fields = CompileFields(
+                self.program._version, id(scope), _feed_signature(feed_vals),
+                tuple(fetch_names), None, nan_guard,
+                getattr(program, "_fusion_sig", None))
+            key_tuple = (id(program), program._version) + fields
             compiled = self._cache.get(key_tuple)
-            _obs.record_jit_cache(compiled is not None, runner="spmd")
+            cause = None if compiled is not None else _miss_cause(
+                self._newest, self.program, fields)
+            _obs.record_jit_cache(compiled is not None, runner="spmd",
+                                  cause=cause)
         if compiled is None:
             compiled = self._cache[key_tuple] = _compile_step(
                 "spmd",
@@ -167,7 +173,7 @@ class SPMDRunner:
                     shard_opt_state=self.shard_opt_state,
                     nan_guard=nan_guard,
                 ),
-                program, feed_vals, fetch_names)
+                program, feed_vals, fetch_names, cause)
 
         return _dispatch_step(
             "spmd", step_phase, compiled, program, scope, feed_vals,

@@ -368,8 +368,11 @@ class TestStepPhases:
     def test_kept_step_tree(self, runner, monkeypatch):
         """Both runners: a kept step's tree has exactly the table's
         children under the runner's prefix, in order, none overlapping,
-        each inside its parent; ``compile`` on the first step alone;
-        ``host.sync`` inside ``finish_fetches`` when the call synced."""
+        each inside its parent; ``compile`` on the first step alone, with
+        its ``compile.telemetry`` child, and jax's trace, lower and backend
+        stages under the phases that paid for them (the step is kept as
+        ``compiled``); ``host.sync`` inside ``finish_fetches`` when the
+        call synced."""
         monkeypatch.setenv("PADDLE_TPU_TRACE_SAMPLE", "1")
         obs.reset_telemetry()
         main, startup, loss = _train_program()
@@ -406,10 +409,25 @@ class TestStepPhases:
             for a, b in zip(direct, direct[1:]):
                 assert _end_ns(a) <= b["t0_ns"] + 1e3, (a, b)
             inner = [k for k in kids if k["parent"] != root["span"]]
+            stages = [k for k in inner if ".compile." in k["name"]]
+            inner = [k for k in inner if k not in stages]
             assert [k["name"] for k in inner] == (
                 ["host.sync"] if synced else [])
             if synced:
                 assert inner[0]["parent"] == direct[-1]["span"]
+            if names is PHASES and not (runner == "spmd"
+                                        and root is steady[0]):
+                assert not stages and "compiled" not in root["attrs"]
+            elif names is PHASES:
+                # the lookup hit, and jax compiled the step again: the
+                # startup program left the state off the mesh's shardings
+                assert root["attrs"]["cause"] == "arguments"
+                assert "spmd.compile.backend" in {k["name"] for k in stages}
+            else:
+                assert root["attrs"]["compiled"] is True
+                assert {k["name"] for k in stages} >= {
+                    "%s.compile.%s" % (runner, s)
+                    for s in ("telemetry", "trace", "lower", "backend")}
             stage = direct[1]["attrs"]
             assert stage["bytes"] == 8 * 8 * 4 and stage["misses"] == 1
             assert direct[2]["attrs"]["hit"] is (names is PHASES)
@@ -444,15 +462,18 @@ class TestStepPhases:
             for i in range(12):
                 exe.run(main, feed=_batch(i), fetch_list=[loss])
             # sampled out: whatever the ring holds so far was kept for
+            # compiling (the startup program and the first step) or for
             # being slow (a busy CPU can make a plain step three times
             # the median)
-            assert all(s["attrs"].get("slow") for s, _ in _steps_of(
-                tr.get_tracer().records(), "executor"))
+            assert all(s["attrs"].get("slow") or s["attrs"].get("compiled")
+                       for s, _ in _steps_of(tr.get_tracer().records(),
+                                             "executor"))
             monkeypatch.setattr(pipeline, "_stage", slow_stage)
             exe.run(main, feed=_batch(13), fetch_list=[loss])
         (root, kids), = [s for s in _steps_of(tr.get_tracer().records(),
                                               "executor")
-                         if s[0]["dur_ms"] >= 250]
+                         if s[0]["dur_ms"] >= 250
+                         and not s[0]["attrs"].get("compiled")]
         assert root["attrs"]["slow"] is True and root["status"] == "ok"
         longest = max(kids, key=lambda k: k["dur_ms"])
         assert longest["name"] == "executor.feed_stage"
@@ -609,7 +630,10 @@ class TestStepPhases:
              and r["attrs"]["generation"] == 2])
         steps = _steps_of(records, "executor")
         if sample == "0":
-            assert steps == [] and pauses[0]["parent"] is None
+            # the startup program and the first step compiled: kept
+            assert [s["attrs"]["step"] for s, _ in steps] == [0, 1]
+            assert all(s["attrs"]["compiled"] for s, _ in steps)
+            assert pauses[0]["parent"] is None
             return
         stage_span, = [k for k in steps[-1][1]
                        if k["name"] == "executor.feed_stage"]
