@@ -5,10 +5,12 @@ points, at the full width of BERT-base (L12 D768 H12 FF3072 V30522,
 random weights from a fixed seed), and fails loudly:
 
     python chip_smoke.py            # one chip: train seq128, train seq512,
-                                    # the window's edge, the scan, serve
+                                    # the window's edge, the scan, the
+                                    # grouped products, serve
     python chip_smoke.py --chips 4  # four chips: data-parallel vs one chip, only
     python chip_smoke.py --probe window_edge    # that phase alone
     python chip_smoke.py --probe ssd_scan       # the scan kernels alone
+    python chip_smoke.py --probe grouped_dot    # the grouped products alone
 
 Without a TPU it exits non-zero before building anything — there is no CPU
 branch and no small model.  Any phase that raises, loses a kernel, or
@@ -46,6 +48,15 @@ DP_LOSS_RTOL = 2.0 ** -6
 # ssd_scan probe: the scan kernels (bf16 operands, float32 accumulation)
 # against the float32 recurrence, each of y and the six gradients
 SSD_REL_L2_TOL = 3e-2
+# grouped_dot probe: the grouped kernels against XLA's ragged_dot on the
+# same bf16 operands, both accumulating in float32 (the kernels round
+# their results to bf16: about 0.1%)
+GROUPED_REL_L2_TOL = 5e-3
+# (M, D, F, G, mean rows a group) of the expert layers' blocks: the
+# block's rows, the widths, the held experts and the rows the counters
+# show each given a step
+GROUPED_CELLS = {"mellum2": (24576, 2304, 896, 8, 2048),
+                 "kanana": (18432, 2048, 768, 16, 768)}
 
 
 def _emit(phase, **fields):
@@ -266,6 +277,100 @@ def phase_ssd_scan(t=2048, heads=64, d_head=64, state=128, chunk=256,
     return rel
 
 
+def _device_ms(fn, *args, calls=20):
+    """Milliseconds a call of the jitted ``fn`` takes on the device: the
+    calls queue one behind another, so the host's dispatch hides."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) * 1e3 / calls
+
+
+def _tilings(rule, m):
+    """The rule's tiles and their neighbours: tm halved and doubled, and
+    the wider of tk and tn at its next divisor in whole lane tiles."""
+    tm, tk, tn = rule
+    out = [rule] + [(t, tk, tn) for t in (tm // 2, tm * 2)
+                    if 128 <= t <= 1024 and m % t == 0]
+    wide = tk if tk >= tn else tn
+    narrower = [d for d in range(wide - 128, 0, -128)
+                if wide % d == 0 and d % 128 == 0][:1]
+    for d in narrower:
+        out.append((tm, d, tn) if tk >= tn else (tm, tk, d))
+    return out
+
+
+def phase_grouped_dot(cells=GROUPED_CELLS, calls=20):
+    """The expert layer's grouped products (``ops/pallas/grouped_matmul
+    .py``) at each cell's block: the forward (lhs [M, D] by [G, D, F], and
+    the down product's [M, F] by [G, F, D]), lhs's cotangent and the
+    weights' cotangent, each in both orientations, with rows given to the
+    groups about as the counters show.  Each against XLA's ``ragged_dot``
+    (its pullbacks for the two cotangents) on the same bf16 operands, by
+    rel. L2, and the device time of both paths; the kernel's at the tile
+    rule's tiles and at their neighbours (how the rule was chosen)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_matmul as gm, use_pallas
+
+    interpret = use_pallas()[1]
+    rng = np.random.default_rng(SEED)
+    result = {}
+    for cell, (m, d, f, g, mean) in cells.items():
+        rows = rng.multinomial(g * mean, np.full(g, 1.0 / g))
+        rows = jnp.asarray(rows, jnp.int32)
+        covered = np.arange(m) < int(rows.sum())
+        for k, n in ((d, f), (f, d)):
+            lhs, ct = (jnp.asarray(rng.standard_normal((m, w)), jnp.bfloat16)
+                       for w in (k, n))
+            rhs = jnp.asarray(rng.standard_normal((g, k, n)) / np.sqrt(k),
+                              jnp.bfloat16)
+            rhs_t = jnp.asarray(rng.standard_normal((g, n, k)) / np.sqrt(n),
+                                jnp.bfloat16)
+            for mode, args, kernel, xla in (
+                    ("forward", (lhs, rhs, rows),
+                     lambda t: lambda a, b, r: gm._gmm(a, b, r, t, False,
+                                                       interpret),
+                     gm._xla_gmm),
+                    ("lhs_grad", (lhs, rhs_t, rows),
+                     lambda t: lambda a, b, r: gm._gmm(a, b, r, t, True,
+                                                       interpret),
+                     gm._xla_gmm_t),
+                    ("rhs_grad", (lhs, ct, rows),
+                     lambda t: lambda a, b, r: gm._tgmm(a, b, r, t,
+                                                        interpret),
+                     gm._xla_tgmm)):
+                rule = gm.tiles(m, k, n, g, jnp.bfloat16, mode)
+                want = np.asarray(jax.jit(xla)(*args), "float64")
+                got = np.asarray(jax.jit(kernel(rule))(*args), "float64")
+                if mode != "rhs_grad":
+                    want, got = want[covered], got[covered]
+                rel = float(np.linalg.norm(got - want)
+                            / np.linalg.norm(want))
+                if not rel < GROUPED_REL_L2_TOL:
+                    _fail("grouped_dot: %s %s %dx%dx%d: rel. L2 from "
+                          "ragged_dot %g, over %g" % (cell, mode, m, k, n,
+                                                      rel,
+                                                      GROUPED_REL_L2_TOL))
+                times = {"x".join(map(str, t)): round(
+                    _device_ms(jax.jit(kernel(t)), *args, calls=calls), 4)
+                    for t in _tilings(rule, m)}
+                name = "%s %s %dx%dx%d" % (cell, mode, m, k, n)
+                result[name] = rel
+                _emit("grouped_dot", product=name,
+                      rows=[int(r) for r in rows], rel_l2=rel,
+                      xla_ms=round(_device_ms(jax.jit(xla), *args,
+                                              calls=calls), 4),
+                      kernel_ms=times["x".join(map(str, rule))],
+                      tiles="x".join(map(str, rule)), kernel_ms_by_tiles=times)
+    return result
+
+
 def _encoder_program(cfg, seq_len):
     import paddle_tpu as fluid
     from paddle_tpu.models import bert
@@ -437,7 +542,8 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the data-parallel path and the "
                          "one-chip run it is compared with")
-    ap.add_argument("--probe", choices=("window_edge", "ssd_scan"),
+    ap.add_argument("--probe", choices=("window_edge", "ssd_scan",
+                                        "grouped_dot"),
                     help="run only this phase (one chip)")
     args = ap.parse_args(argv)
 
@@ -473,6 +579,8 @@ def main(argv=None):
         phase_window_edge()
     elif args.probe == "ssd_scan":
         phase_ssd_scan()
+    elif args.probe == "grouped_dot":
+        phase_grouped_dot()
     elif args.chips == 1:
         # the shipped flagship graph (bench.py child_bert): fused QKV at
         # seq128 only, fused LN, fuse_attn="auto", masked-gather head
@@ -485,6 +593,7 @@ def main(argv=None):
                      "flash_attention_dkv", "flash_attention_dq"))
         phase_window_edge()
         phase_ssd_scan()
+        phase_grouped_dot()
         phase_serve(bert.BERT_BASE, 128, (1, 3, 2, 4, 1, 1, 2),
                     buckets=(2, 4))
     else:

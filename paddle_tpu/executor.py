@@ -36,6 +36,7 @@ from .framework import Program, default_main_program, Variable
 from .ops import registry as op_registry
 from .ops.pallas.flash_attention import noting_blocks
 from .ops.pallas.ssd_scan import noting_chunks
+from .ops.pallas.grouped_matmul import noting_sites
 from .ops.registry import EMPTY_VAR_NAME
 from .pipeline import FetchHandle
 
@@ -462,11 +463,13 @@ class _CompiledBlock:
         # was traced (``LoweringContext.residual_sites``), and the open
         # ``compile`` phase that reports it after the first dispatch
         self.residual_sites = {}
-        # the grid blocks of the flash kernels and the scan kernels' chunks
-        # the step's last trace held (``flash_attention.noting_blocks``,
-        # ``ssd_scan.noting_chunks``), reported the same way
+        # the grid blocks of the flash kernels, the scan kernels' chunks
+        # and the grouped products the step's last trace held
+        # (``flash_attention.noting_blocks``, ``ssd_scan.noting_chunks``,
+        # ``grouped_matmul.noting_sites``), reported the same way
         self.flash_blocks = {}
         self.ssd_chunks = {}
+        self.grouped_sites = {}
         # and why the runner built this block (``_miss_cause``)
         self.compile_phase = self.compile_cause = None
         ext_reads, written, persist_written = _analyze_block(
@@ -540,7 +543,8 @@ class _CompiledBlock:
                     .make_value_hook(gate, loss_name=getattr(
                         program, "_guard_loss_name", None))
             with noting_blocks(collections.Counter()) as self.flash_blocks, \
-                    noting_chunks(collections.Counter()) as self.ssd_chunks:
+                    noting_chunks(collections.Counter()) as self.ssd_chunks, \
+                    noting_sites(collections.Counter()) as self.grouped_sites:
                 _run_ops_into_env(block, env, ctx, ops=_top_ops)
             fetches = [env[n] for n in self.fetch_names]
             new_rw = {n: env[n] for n in self.rw_names}
@@ -767,7 +771,8 @@ class _AccumRunner:
             ctx.fault_value_hook = fault_hook
             ctx.residual_sites = cb.residual_sites
             with noting_blocks(collections.Counter()) as cb.flash_blocks, \
-                    noting_chunks(collections.Counter()) as cb.ssd_chunks:
+                    noting_chunks(collections.Counter()) as cb.ssd_chunks, \
+                    noting_sites(collections.Counter()) as cb.grouped_sites:
                 _run_ops_into_env(self.block, e, ctx, ops=self.head)
             return (
                 {n: e[n] for n in self.grad_reads},
@@ -1175,6 +1180,7 @@ def _dispatch_step(runner, step_phase, compiled, program, scope, feed_vals,
                                         built)
         _obs.record_flash_blocks(compiled.flash_blocks, built)
         _obs.record_ssd_chunks(compiled.ssd_chunks, built)
+        _obs.record_grouped_dot_sites(compiled.grouped_sites, built)
         _obs.record_compile(runner, cur_step, compiled.compile_cause,
                             built.dur_ms, _tr.compile_stages(dispatch))
         compiled.compile_phase = None

@@ -21,6 +21,7 @@ __all__ = [
     "record_step", "record_step_done", "record_jit_cache",
     "record_compile", "record_grad_residual_sites",
     "record_flash_blocks", "record_attention_layers", "record_ssd_chunks",
+    "record_grouped_dot_sites",
     "record_moe_layers", "publish_moe_counters",
     "record_fusion_resolve", "record_feed_cache",
     "record_feed_cache_eviction", "record_feed_h2d", "record_sync",
@@ -342,6 +343,35 @@ def record_ssd_chunks(chunks, compile_phase):
                    "steps, times batch and heads, by pass: forward, a "
                    "recompute region's re-run, backward",
                    **{"pass": kind}).inc(n)
+
+
+def record_grouped_dot_sites(sites, compile_phase):
+    """The grouped products a newly traced step holds, ``sites[(mode,
+    path, shape, tiles)]`` as ``grouped_matmul.noting_sites`` counted them
+    (mode ``forward``, ``lhs_grad``, ``rhs_grad``; path ``kernel`` or
+    ``xla``): attributes ``grouped_dot_sites_<mode>_<path>`` and
+    ``grouped_dot_tiles`` (``<mode> <m>x<k>x<n>: <tm>x<tk>x<tn>`` for each
+    kernel's shape) of the block's ``compile`` phase, and the counter
+    ``grouped_dot_sites_total{mode, path}``; a step without one gets
+    none."""
+    if not sites:
+        return
+    counts, tiles = collections.Counter(), set()
+    for (mode, path, shape, tiling), n in sites.items():
+        counts[(mode, path)] += n
+        if tiling:
+            tiles.add("%s %s: %s" % (mode, shape, tiling))
+    for (mode, path), n in sorted(counts.items()):
+        compile_phase.set_attr("grouped_dot_sites_%s_%s" % (mode, path), n)
+    if tiles:
+        compile_phase.set_attr("grouped_dot_tiles", "; ".join(sorted(tiles)))
+    if not telemetry_enabled():
+        return
+    for (mode, path), n in counts.items():
+        _m.counter("grouped_dot_sites_total",
+                   "grouped products in traced steps, by mode (forward, "
+                   "lhs_grad, rhs_grad) and path (the Pallas kernel or "
+                   "XLA's ragged_dot)", mode=mode, path=path).inc(n)
 
 
 def record_attention_layers(program, compile_phase):

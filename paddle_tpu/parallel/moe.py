@@ -35,10 +35,10 @@ the held experts first and sorted by expert, and one loop walks that
 order in blocks (:func:`block_rows`) as far as the rows routed here reach,
 its trip count read from the row counts on the device.  A block gathers
 its tokens' rows, runs the three products as :func:`grouped_dot`
-(``jax.lax.ragged_dot``) over the held experts' row counts inside the
-block (on the TPU XLA lowers it to one grouped Mosaic kernel,
-``ragged-dot-none``, that visits only the tiles the counts cover) and
-scatter-adds the results to their tokens: dispatch, products and
+over the held experts' row counts inside the block (on the TPU one
+grouped Pallas kernel a product, ``ops/pallas/grouped_matmul.py``, that
+visits only the tiles the counts cover; ``jax.lax.ragged_dot`` off it)
+and scatter-adds the results to their tokens: dispatch, products and
 combine all follow the rows routed here, not ``tokens * top_k`` and not
 ``rows * experts``, and no buffer of every choice exists.  On one device
 it runs without an exchange; under expert parallelism the exchange moves
@@ -51,6 +51,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from ..ops.pallas.grouped_matmul import (grouped_matmul, grouped_matmul_t,
+                                         grouped_matmul_rhs_grad)
 
 __all__ = ["moe_ffn", "moe_ffn_local", "init_moe_params",
            "moe_dispatch", "moe_combine", "MOE_RING_ID",
@@ -328,15 +331,14 @@ def plan_held_rows(idx, first, held):
 def grouped_dot(lhs, rhs, rows):
     """``out[r] = lhs[r] @ rhs[g(r)]``: lhs [R, K] with its first
     ``sum(rows)`` rows sorted by group, rhs [G, K, N], rows [G] the rows
-    of each group.  ``jax.lax.ragged_dot``, whose work follows
-    ``sum(rows)`` and not R or G (on the TPU one grouped Mosaic kernel,
-    ``ragged-dot-none``), with what it leaves unwritten made zero at the
-    source, forward and backward: the rows past ``sum(rows)`` of the
-    result and of lhs's cotangent, and the cotangent of a group with no
-    row.  Left as they come they are whatever the buffer held, and a
-    product rule downstream multiplies by them."""
-    covered = (jnp.arange(lhs.shape[0]) < jnp.sum(rows))[:, None]
-    return jnp.where(covered, jax.lax.ragged_dot(lhs, rhs, rows), 0)
+    of each group.  ``ops/pallas/grouped_matmul.py``, whose work follows
+    ``sum(rows)`` and not R or G (on the TPU one grouped Pallas kernel a
+    product, ``ragged-dot-gmm``; off it ``jax.lax.ragged_dot``), with
+    what it does not cover zero, forward and backward: the rows past
+    ``sum(rows)`` of the result and of lhs's cotangent, and the cotangent
+    of a group with no row.  Left as they come they would be whatever the
+    buffer held, and a product rule downstream multiplies by them."""
+    return grouped_matmul(lhs, rhs, rows)
 
 
 def _grouped_dot_fwd(lhs, rhs, rows):
@@ -345,12 +347,8 @@ def _grouped_dot_fwd(lhs, rhs, rows):
 
 def _grouped_dot_bwd(res, ct):
     lhs, rhs, rows = res
-    _, pullback = jax.vjp(
-        lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, rows), lhs, rhs)
-    d_lhs, d_rhs = pullback(ct)
-    covered = (jnp.arange(lhs.shape[0]) < jnp.sum(rows))[:, None]
-    return (jnp.where(covered, d_lhs, 0),
-            jnp.where((rows > 0)[:, None, None], d_rhs, 0), None)
+    return (grouped_matmul_t(ct, rhs, rows),
+            grouped_matmul_rhs_grad(lhs, ct, rows), None)
 
 
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
@@ -413,24 +411,25 @@ def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes, kept=None):
 def _block_ffn_bwd(scope, xs, g, w_gate, w_up, w_down, sizes, h, u, ct):
     """The backward of :func:`_block_ffn` at ``ct`` [B, D] float32, from
     the block's gate and up products ``h``, ``u``: six grouped products,
-    none of them forward (the pullbacks' other halves are dead, and XLA
-    drops them).  The gate's cotangent ``<y[r], ct[r]>`` is taken as
-    ``<a[r], (ct W_down^T)[r]>`` (``a = silu(h) * u``, ``y = a W_down``),
-    and ``ct W_down^T``, times the gate, is the cotangent of ``a``.
-    Returns ``(d_xs, d_g, [d_w_gate, d_w_up, d_w_down])``."""
+    the transposes each cotangent needs and none forward.  The gate's
+    cotangent ``<y[r], ct[r]>`` is taken as ``<a[r], (ct W_down^T)[r]>``
+    (``a = silu(h) * u``, ``y = a W_down``), and ``ct W_down^T``, times
+    the gate, is the cotangent of ``a``.  Returns ``(d_xs, d_g, [d_w_gate,
+    d_w_up, d_w_down])``."""
     with scope("products"):
         a, swiglu_pullback = jax.vjp(_swiglu, h, u)
-        _, d_w_down, _ = _grouped_dot_bwd(
-            (a, w_down, sizes), (ct * g[:, None]).astype(xs.dtype))
-        ct_a, _, _ = _grouped_dot_bwd((a, w_down, sizes),
-                                      ct.astype(xs.dtype))
+        d_w_down = grouped_matmul_rhs_grad(
+            a, (ct * g[:, None]).astype(xs.dtype), sizes)
+        ct_a = grouped_matmul_t(ct.astype(xs.dtype), w_down, sizes)
         d_g = jnp.sum(a.astype(jnp.float32) * ct_a.astype(jnp.float32),
                       axis=1)
         d_h, d_u = swiglu_pullback(
             (ct_a.astype(jnp.float32) * g[:, None]).astype(xs.dtype))
-        d_xs_gate, d_w_gate, _ = _grouped_dot_bwd((xs, w_gate, sizes), d_h)
-        d_xs_up, d_w_up, _ = _grouped_dot_bwd((xs, w_up, sizes), d_u)
-    return d_xs_gate + d_xs_up, d_g, [d_w_gate, d_w_up, d_w_down]
+        d_xs = (grouped_matmul_t(d_h, w_gate, sizes)
+                + grouped_matmul_t(d_u, w_up, sizes))
+        d_w_gate = grouped_matmul_rhs_grad(xs, d_h, sizes)
+        d_w_up = grouped_matmul_rhs_grad(xs, d_u, sizes)
+    return d_xs, d_g, [d_w_gate, d_w_up, d_w_down]
 
 
 def _block_inputs(scope, size, i, x, gates, order, rows):

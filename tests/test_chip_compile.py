@@ -27,6 +27,9 @@ from paddle_tpu.ops.pallas.conv_bn_act import (bn_act_epilogue,
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.flash_decode import flash_decode
 from paddle_tpu.ops.pallas.fused_ln import fused_dropout_add_ln
+from paddle_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                  grouped_matmul_rhs_grad,
+                                                  grouped_matmul_t)
 from paddle_tpu.ops.pallas.paged_flash_decode import paged_flash_decode
 from paddle_tpu.ops.pallas.ssd_scan import ssd_scan
 from paddle_tpu.parallel.moe import block_rows, held_experts_ffn
@@ -169,7 +172,8 @@ def test_flash_attention_with_grouped_heads_and_a_window(chip, window, grad):
 def test_expert_layer_loop(chip, grad):
     """The kanana cell's expert layer (16,384 tokens, top 6, 16 of 128
     experts held, widths 2048 / 768, bf16): the loop over blocks holds
-    XLA's grouped kernel, and forward + backward keep their temporaries
+    the grouped kernels and no XLA ``ragged-dot-none``, and forward +
+    backward keep their temporaries
     under a quarter of the 2.08 GiB that one buffer of every choice
     compiled to (PR 29's form; 1.13 GiB forward)."""
     t, d, f, k, held = 16384, 2048, 768, 6, 16
@@ -187,7 +191,12 @@ def test_expert_layer_loop(chip, grad):
         ((held, d, f), BF16), ((held, f, d), BF16))]
     compiled = jax.jit(backward if grad else layer).lower(*args).compile()
     kernels = pallas.pallas_kernels_in(compiled.as_text())
-    assert any("ragged-dot" in name for name in kernels)
+    # block 0 and the loop's body: three forward products each; the
+    # backward's two bodies add their gate and up products and six
+    # transposed ones each
+    assert kernels == ({"ragged-dot-gmm": 10, "ragged-dot-gmm-t": 6,
+                        "ragged-dot-tgmm": 6} if grad
+                       else {"ragged-dot-gmm": 6})
     assert compiled.memory_analysis().temp_size_in_bytes < 2.08 * 2**30 / 4
 
 
@@ -197,7 +206,8 @@ def test_expert_layer_under_a_region(chip):
     the forward keeps the router's choices and the first block's gate and
     up products, the re-run takes them across the barrier and its
     backward runs from them (what follows the layer in the region is
-    linear, so the re-run's own output is not needed).  The grouped kernel is in the step, and keeping adds
+    linear, so the re-run's own output is not needed).  The grouped
+    kernels are in the step, none of them XLA's, and keeping adds
     to the temporaries less than what crosses: h and u, [24,576, 896]
     bf16 each, and the choices, [16,384, 8] int32 (with nothing kept the
     backward computes its own h and u).  (Neither form stays under the
@@ -222,12 +232,37 @@ def test_expert_layer_under_a_region(chip):
         ((held, d, f), BF16), ((held, f, d), BF16))]
     kept, anew = (jax.jit(region(keep)).lower(*args).compile()
                   for keep in (True, False))
-    assert any("ragged-dot" in name
-               for name in pallas.pallas_kernels_in(kept.as_text()))
+    # the forward's first block's gate and up products, its down product
+    # and the loop's three; the backward from what was kept: six
+    # transposed products (3 + 6 a trip), and the loop's body its eight
+    assert pallas.pallas_kernels_in(kept.as_text()) == {
+        "ragged-dot-gmm": 2 + 1 + 3 + 2, "ragged-dot-gmm-t": 3 + 3,
+        "ragged-dot-tgmm": 3 + 3}
     crossing = 2 * block_rows(t * k, held, total) * f * 2 + t * k * 4
     assert crossing == 2 * 24576 * 896 * 2 + 16384 * 8 * 4
     assert (kept.memory_analysis().temp_size_in_bytes
             < anew.memory_analysis().temp_size_in_bytes + crossing)
+
+
+@pytest.mark.parametrize("m,d,f,g", [(18432, 2048, 768, 16),
+                                     (24576, 2304, 896, 8)])
+@pytest.mark.parametrize("mode", ["forward", "lhs_grad", "rhs_grad"])
+def test_grouped_products(chip, m, d, f, g, mode):
+    """The grouped kernels at the kanana and mellum2 cells' blocks, in both
+    orientations of the layer's products (gate and up: D to F; down: F to
+    D), each one kernel of its own name."""
+    name = {"forward": "ragged-dot-gmm", "lhs_grad": "ragged-dot-gmm-t",
+            "rhs_grad": "ragged-dot-tgmm"}[mode]
+    for k, n in ((d, f), (f, d)):
+        fn, shapes = {
+            "forward": (grouped_matmul, ((m, k), (g, k, n))),
+            "lhs_grad": (grouped_matmul_t, ((m, n), (g, k, n))),
+            "rhs_grad": (grouped_matmul_rhs_grad, ((m, k), (m, n)))}[mode]
+        args = [jax.ShapeDtypeStruct(s, BF16, sharding=chip)
+                for s in shapes]
+        args.append(jax.ShapeDtypeStruct((g,), I32, sharding=chip))
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert pallas.pallas_kernels_in(text) == {name: 1}
 
 
 # -- the Mamba-2 scan ----------------------------------------------------------

@@ -374,3 +374,28 @@ def test_phase_ssd_scan_at_a_small_size(chip_smoke, capsys, monkeypatch):
     with pytest.raises(SystemExit) as exc:
         chip_smoke.phase_ssd_scan(**small)
     assert "ssd_scan" in str(exc.value.code) and "db" in str(exc.value.code)
+
+
+def test_phase_grouped_dot_at_a_small_size(chip_smoke, capsys, monkeypatch):
+    """The probe of the grouped kernels in interpret mode at a block of
+    512 rows, widths 384 and 256, 4 groups: six products, each within
+    its tolerance of ragged_dot; and a weights' kernel a third too large
+    fails it."""
+    import importlib
+
+    G = importlib.import_module("paddle_tpu.ops.pallas.grouped_matmul")
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    small = dict(cells={"small": (512, 384, 256, 4, 100)}, calls=1)
+    rel = chip_smoke.phase_grouped_dot(**small)
+    lines = _phase_lines(capsys)
+    assert [line["product"] for line in lines] == list(rel) == [
+        "small %s %s" % (mode, shape) for shape in ("512x384x256",
+                                                   "512x256x384")
+        for mode in ("forward", "lhs_grad", "rhs_grad")]
+    assert max(rel.values()) < chip_smoke.GROUPED_REL_L2_TOL / 3
+    assert all(line["tiles"] in line["kernel_ms_by_tiles"] for line in lines)
+    real = G._tgmm
+    monkeypatch.setattr(G, "_tgmm", lambda *a: real(*a) * 1.3)
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.phase_grouped_dot(**small)
+    assert "rhs_grad" in str(exc.value.code)

@@ -142,6 +142,14 @@ def test_training_lowers_the_loss_and_the_compile_phase_says_what_it_holds():
         == attrs["flash_blocks_masked"]
     assert attrs["flash_window_blocks_visited"] * 4 == \
         attrs["flash_blocks_visited"] * 3
+    # every grouped product of the step took its kernel
+    sites = {a: n for a, n in attrs.items()
+             if a.startswith("grouped_dot_sites_")}
+    assert sorted(sites) == ["grouped_dot_sites_%s_kernel" % mode
+                             for mode in ("forward", "lhs_grad",
+                                          "rhs_grad")]
+    assert sites["grouped_dot_sites_lhs_grad_kernel"] \
+        == sites["grouped_dot_sites_rhs_grad_kernel"] > 0
 
 
 def test_an_unknown_attention_kind_or_layer_type_is_refused():
@@ -610,12 +618,16 @@ def test_the_expert_loops_block_holds_one_and_a_half_even_shares(
     assert moe.block_rows(choices, held, total) == want
 
 
-def test_the_block_size_changes_the_trips_and_not_the_result():
+def test_the_block_size_changes_the_trips_and_not_the_result(monkeypatch):
     """The same layer walked in blocks of 8,192 (share unknown: every
     routed row in one trip here) and of 2,048 (2 of 8 experts held, and
     given most of the choices: two trips): equal results and
-    gradients."""
+    gradients.  The walk is what is compared, through XLA's grouped
+    products (the kernels' own comparison with them is
+    ``tests/test_grouped_matmul.py``)."""
     from paddle_tpu.parallel import moe
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "off")
 
     rng = np.random.default_rng(0)
     t, d, f, k, held = 1500, 16, 24, 2, 2

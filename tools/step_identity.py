@@ -10,7 +10,9 @@ result, for holding a change against its parent at the same seed:
   (source paths and lines move with every checkout and every edit above
   the traced code; the program they annotate does not);
 * ``grad_residual_sites``: the program's ``grad_residual_sites_total`` by
-  ``op_type/path`` (empty where the program has no such counter).
+  ``op_type/path``, and ``grouped_dot_sites``: its
+  ``grouped_dot_sites_total`` by ``mode/path`` (each empty where the
+  program has no such counter).
 
 Nothing of the run changes: the traffic driver's ``measure`` and
 ``check_step`` are wrapped where ``run.py`` loads it.
@@ -51,13 +53,13 @@ _SOURCES = re.compile(
 seen = {"tag": tag, "workload": workload, "seed": int(seed)}
 
 
-def residual_sites():
+def sites(counter, kind):
+    """``{"<kind>/<path>": count}`` of the program's counter ``counter``."""
     from paddle_tpu.observability import metrics
 
-    return {"%s/%s" % (dict(m.labels).get("op_type"),
+    return {"%s/%s" % (dict(m.labels).get(kind),
                        dict(m.labels).get("path")): m.value
-            for m in metrics.registry().collect()
-            if m.name == "grad_residual_sites_total"}
+            for m in metrics.registry().collect() if m.name == counter}
 
 
 def load_and_wrap(kind, name, *args, **kwargs):
@@ -79,7 +81,10 @@ def load_and_wrap(kind, name, *args, **kwargs):
                 "", text).encode()).hexdigest()
             res = check_step(compiled, *a, **k)
             seen["kernels"] = res[1]
-            seen["grad_residual_sites"] = residual_sites()
+            seen["grad_residual_sites"] = sites("grad_residual_sites_total",
+                                                "op_type")
+            seen["grouped_dot_sites"] = sites("grouped_dot_sites_total",
+                                              "mode")
             print(json.dumps(seen), flush=True)
             return res
 
