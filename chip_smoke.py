@@ -228,13 +228,16 @@ def phase_window_edge(t=8192, heads=32, kv_heads=4, d=128, window=1024,
 
 
 def phase_ssd_scan(t=2048, heads=64, d_head=64, state=128, chunk=256,
-                   batch=1):
+                   batch=1, groups=1):
     """The Mamba-2 scan kernels at the granite cell's widths (64 heads of
-    64, a state of 128, chunks of 256; 8 chunks, so the state crosses
-    seven boundaries) against ``chipbench/reference/granite.py``'s
-    recurrence, a ``lax.scan`` over positions in float32 at the highest
-    matmul precision: y, and dx, dΔ, dA, dB, dC and dD through the
-    backward kernel, with x, B, C and dy in bf16 as AMP hands them over.
+    64, a state of 128 in one group, chunks of 256; 8 chunks, so the state
+    crosses seven boundaries), or with ``groups`` of B and C at the
+    nemotron cell's (8 groups, chunks of 128), against the plain
+    reference's recurrence (``chipbench/reference/granite.py``, with
+    groups ``nemotron.py``), a ``lax.scan`` over positions in float32 at
+    the highest matmul precision: y, and dx, dΔ, dA, dB, dC and dD through
+    the backward kernel, with x, B, C and dy in bf16 as AMP hands them
+    over.
     The benchmark's ``correct`` compares a forward only, and the
     interpreter takes the products' operands in float32: this is the
     comparison of the kernels' bf16 products, backward too."""
@@ -245,9 +248,10 @@ def phase_ssd_scan(t=2048, heads=64, d_head=64, state=128, chunk=256,
     from paddle_tpu.ops.pallas.ssd_scan import routes_to_kernel, ssd_scan
 
     rng = np.random.default_rng(SEED)
+    bc = (batch, t, state) if groups == 1 else (batch, t, groups, state)
     x, b, c, dy = (jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
-                   for s in ((batch, t, heads, d_head), (batch, t, state),
-                             (batch, t, state), (batch, t, heads, d_head)))
+                   for s in ((batch, t, heads, d_head), bc, bc,
+                             (batch, t, heads, d_head)))
     dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
                                         (batch, t, heads))), jnp.float32)
     a = jnp.asarray(-rng.uniform(1, 16, heads), jnp.float32)
@@ -257,7 +261,8 @@ def phase_ssd_scan(t=2048, heads=64, d_head=64, state=128, chunk=256,
     y, pullback = jax.vjp(lambda *v: ssd_scan(*v, chunk=chunk),
                           x, dt, a, b, c, d)
     got = (y,) + pullback(dy)
-    plain = mf.load_by_name("reference", "granite").scan
+    plain = mf.load_by_name(
+        "reference", "granite" if groups == 1 else "nemotron").scan
     f32 = [v.astype(jnp.float32) for v in (x, dt, a, b, c, d)]
     with jax.default_matmul_precision("highest"):
         want, pullback = jax.vjp(plain, *f32)
@@ -273,7 +278,7 @@ def phase_ssd_scan(t=2048, heads=64, d_head=64, state=128, chunk=256,
         _fail("ssd_scan: rel. L2 from the recurrence %s, over %g"
               % (rel, SSD_REL_L2_TOL))
     _emit("ssd_scan", seq_len=t, heads=heads, d_head=d_head, state=state,
-          chunk=chunk, rel_l2=rel)
+          groups=groups, chunk=chunk, rel_l2=rel)
     return rel
 
 
@@ -579,6 +584,7 @@ def main(argv=None):
         phase_window_edge()
     elif args.probe == "ssd_scan":
         phase_ssd_scan()
+        phase_ssd_scan(groups=8, chunk=128)
     elif args.probe == "grouped_dot":
         phase_grouped_dot()
     elif args.chips == 1:
@@ -593,6 +599,7 @@ def main(argv=None):
                      "flash_attention_dkv", "flash_attention_dq"))
         phase_window_edge()
         phase_ssd_scan()
+        phase_ssd_scan(groups=8, chunk=128)
         phase_grouped_dot()
         phase_serve(bert.BERT_BASE, 128, (1, 3, 2, 4, 1, 1, 2),
                     buckets=(2, 4))

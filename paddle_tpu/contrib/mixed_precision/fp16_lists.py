@@ -42,6 +42,7 @@ white_list = {
     "leaky_relu",
     "dropout",
     "swiglu",
+    "relu2",
     "rotary_embedding",
     # shape glue (cast-free but keeps dtype propagation consistent)
     "reshape",

@@ -45,6 +45,7 @@ __all__ = [
     "rms_norm",
     "rotary_embedding",
     "swiglu",
+    "relu2",
     "causal_conv1d",
     "rms_norm_gated",
     "ssd_scan",
@@ -1211,6 +1212,15 @@ def swiglu(x, y, name=None):
     return out
 
 
+def relu2(x, name=None):
+    """``relu(x)^2``: the activation of a relu² feed-forward."""
+    helper = LayerHelper("relu2", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="relu2", inputs={"X": [x]},
+                     outputs={"Out": [out]})
+    return out
+
+
 def causal_conv1d(input, filter_size, param_attr=None, bias_attr=None,
                   name=None):
     """Depthwise causal convolution along time over input [B, T, C]:
@@ -1233,19 +1243,25 @@ def causal_conv1d(input, filter_size, param_attr=None, bias_attr=None,
     return out
 
 
-def rms_norm_gated(input, gate, epsilon=1e-6, param_attr=None, name=None):
+def rms_norm_gated(input, gate, epsilon=1e-6, param_attr=None,
+                   group_size=None, name=None):
     """``g = input * silu(gate)``, then ``g / sqrt(mean(g^2) + epsilon) *
-    scale`` over the LAST axis, in float32 (Mamba-2's gated norm with one
-    group); creates the float32 scale [D], ones."""
+    scale`` in float32 (Mamba-2's gated norm): the mean over the whole
+    LAST axis (one group), or over each run of ``group_size`` channels of
+    it (groups of the mixer's width); creates the float32 scale [D],
+    ones."""
     helper = LayerHelper("rms_norm_gated", **locals())
     scale = helper.create_parameter(
         attr=helper.param_attr, shape=[input.shape[-1]], dtype="float32",
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"epsilon": float(epsilon)}
+    if group_size:
+        attrs["group_size"] = int(group_size)
     helper.append_op(
         type="rms_norm_gated",
         inputs={"X": [input], "Z": [gate], "Scale": [scale]},
-        outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+        outputs={"Y": [out]}, attrs=attrs)
     return out
 
 
@@ -1286,7 +1302,8 @@ def ssd_scan(x, dt, b, c, chunk_size=256, a_log_attr=None, d_attr=None,
              dt_bias_attr=None, name=None):
     """Mamba-2's state-space-dual scan.  x [B, T, H, P]; dt [B, T, H], the
     step before its bias and softplus; b, c [B, T, N], one group shared by
-    every head.  ``Δ = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; per
+    every head, or [B, T, G, N], head h reading group ``h // (H / G)``.
+    ``Δ = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; per
     head ``S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t b_t^T`` (S: P x N, S_0 = 0),
     ``y_t = S_t c_t + D x_t``; returns y [B, T, H, P].  Computed in chunks
     of ``chunk_size`` (on the TPU by the Pallas kernels of
@@ -1373,16 +1390,19 @@ def moe_route(input, num_experts, top_k, scale=1.0, norm_topk_prob=True,
 
 def moe_experts(input, index, gate, expert_width, experts_held,
                 first_expert=0, param_attr=None, experts_total=None,
-                name=None):
+                activation="swiglu", name=None):
     """The part of a top-k expert layer that the ``experts_held`` experts
     from ``first_expert`` on give: ``sum_k gate[t,k] * E_index[t,k](x_t)``
-    over the choices that name a held expert, ``E(x) = W_down (silu(W_gate
-    x) * W_up x)`` of width ``expert_width``.  Dropless: every row routed
+    over the choices that name a held expert, of width ``expert_width``:
+    by ``activation``, ``E(x) = W_down (silu(W_gate x) * W_up x)``
+    (``"swiglu"``) or ``E(x) = W_down relu(W_up x)^2`` (``"relu2"``, no
+    gate weights).  Dropless: every row routed
     here is computed, whatever the imbalance, by grouped products whose
     work follows those rows.  ``index`` and ``gate`` come from
     :func:`moe_route` and count over all the layer's experts, held or
-    not.  Creates each held expert's own ``<name>.<e>.gate``, ``.up``
-    [D, F] and ``.down`` [F, D], e counted from 0 over the experts held,
+    not.  Creates each held expert's own ``<name>.<e>.gate`` (SwiGLU
+    only), ``.up`` [D, F] and ``.down`` [F, D], e counted from 0 over the
+    experts held,
     ``<name>`` and the initializer from ``param_attr`` (as a checkpoint of
     such a model stores them, an expert a tensor; the lowering stacks
     them).  ``experts_total``: how many experts ``index`` counts over;
@@ -1405,17 +1425,21 @@ def moe_experts(input, index, gate, expert_width, experts_held,
                            trainable=attr.trainable),
             shape=shape, dtype="float32") for e in range(experts_held)]
 
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError("moe_experts has no activation %r" % (activation,))
     out = helper.create_variable_for_type_inference(input.dtype)
     rows = helper.create_variable_for_type_inference("int32", True)
-    helper.append_op(
-        type="moe_experts",
-        inputs={"X": [input], "Index": [index], "Gate": [gate],
-                "WGate": weights("gate", [d, expert_width]),
-                "WUp": weights("up", [d, expert_width]),
-                "WDown": weights("down", [expert_width, d])},
-        outputs={"Out": [out], "Rows": [rows]},
-        attrs={"first_expert": int(first_expert),
-               "experts_total": int(experts_total or 0)})
+    inputs = {"X": [input], "Index": [index], "Gate": [gate]}
+    attrs = {"first_expert": int(first_expert),
+             "experts_total": int(experts_total or 0)}
+    if activation == "swiglu":
+        inputs["WGate"] = weights("gate", [d, expert_width])
+    else:
+        attrs["activation"] = activation
+    inputs["WUp"] = weights("up", [d, expert_width])
+    inputs["WDown"] = weights("down", [expert_width, d])
+    helper.append_op(type="moe_experts", inputs=inputs,
+                     outputs={"Out": [out], "Rows": [rows]}, attrs=attrs)
     return out, rows
 
 

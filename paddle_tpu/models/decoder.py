@@ -1,7 +1,8 @@
 """A decoder-only language model built from a configuration dict, through
 the public API as ``models/bert.py`` is: pre-norm blocks ``h = x +
-Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, a final RMSNorm and a
-head.  The configuration's keys are the model's own (the names a
+Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))`` (or, with a
+``hybrid_override_pattern``, layers of one part each, below), a final
+RMSNorm and a head.  The configuration's keys are the model's own (the names a
 DeepSeek-V3-family ``config.json`` gives them, and for grouped-query
 attention those of a ``layer_types`` / ``rope_parameters`` config) plus
 what this device holds of it:
@@ -43,15 +44,17 @@ what this device holds of it:
 * a hybrid of Mamba-2 and attention layers (the keys of a
   ``granitemoehybrid`` config, IBM Granite 4.0-H; Bamba's are the same):
   ``layer_types[i]`` ``mamba`` is a Mamba-2 mixer (``_mamba2``):
-  ``[z | xBC | dt] = W_in u`` (widths ``mamba_expand * hidden_size`` =
-  ``mamba_n_heads * mamba_d_head``, that plus ``2 * mamba_d_state``, and
+  ``[z | xBC | dt] = W_in u`` (widths ``mamba_n_heads * mamba_d_head``,
+  that plus ``2 * mamba_n_groups * mamba_d_state``, and
   ``mamba_n_heads``), ``xBC = silu(causal_conv1d(xBC) + b)`` of width
   ``mamba_d_conv`` (a bias where ``mamba_conv_bias``), split into x (heads
-  of ``mamba_d_head``), B and C (``mamba_d_state``, ``mamba_n_groups``
-  1), ``y = ssd_scan(x, dt, B, C)`` in chunks of ``mamba_chunk_size``:
+  of ``mamba_d_head``), B and C (``mamba_n_groups`` groups of
+  ``mamba_d_state``; head h reads group ``h // (heads / groups)``),
+  ``y = ssd_scan(x, dt, B, C)`` in chunks of ``mamba_chunk_size``:
   ``Δ = softplus(dt + dt_bias)``, ``A = -exp(A_log)``, per head ``S_t =
   exp(Δ_t A) S_{t-1} + Δ_t x_t B_t^T`` and ``y_t = S_t C_t + D x_t``;
-  ``o = rms_norm_gated(y, z)`` over the whole width, ``W_out o``;
+  ``o = rms_norm_gated(y, z)`` over each group's ``width / groups``
+  channels (the whole width for one group), ``W_out o``;
   ``attention`` is grouped-query attention as above, with no rotary where
   ``position_embedding_type`` is ``nope``.  The multipliers:
   ``attention_multiplier`` is the softmax scale (else ``1 /
@@ -61,6 +64,15 @@ what this device holds of it:
   ``tie_word_embeddings``: the head is the embedding's matrix,
   transposed.  Each key is absent, and does nothing, in the other
   families' configs.
+* layers of one part each (the keys of a ``nemotron_h`` config, NVIDIA
+  Nemotron-H / Nemotron 3): ``hybrid_override_pattern[i]`` says what
+  layer i is, ``h = x + part(RMSNorm(x))``: ``M`` a Mamba-2 mixer as
+  above, ``E`` an expert layer as above, ``*`` grouped-query attention
+  (``position_embedding_type`` ``nope``: no rotary).
+* ``mlp_hidden_act`` ``relu2``: every MLP and expert is ``E(x) = W_down
+  relu(W_up x)^2``, no gate (else SwiGLU);
+  ``moe_shared_expert_intermediate_size``, where given, is the shared
+  expert's width (else ``n_shared_experts * moe_intermediate_size``).
 * ``vocab_size`` is the rows of the embedding and of the head held here.
 * ``recompute``: each layer in ``fluid.layers.recompute()``.
 
@@ -127,6 +139,28 @@ GRANITE_TINY = {
     "tie_word_embeddings": True, "rms_norm_eps": 1e-5,
     "first_k_dense_replace": 3, "intermediate_size": 128,
     "initializer_range": 0.02, "recompute": True,
+}
+
+
+# hidden 64; the pattern "MEM*E": Mamba-2 mixers of 4 heads of 32 in two
+# groups of B and C (state 16), relu² expert layers (2 of 8 experts held,
+# top 2, a shared expert of 48), NoPE attention of 4 query heads on 2
+# key-value heads of 16; an untied head
+NEMOTRON_TINY = {
+    "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 5,
+    "hybrid_override_pattern": "MEM*E", "attention": "gqa",
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "position_embedding_type": "nope", "mamba_n_heads": 4,
+    "mamba_d_head": 32, "mamba_d_state": 16, "mamba_n_groups": 2,
+    "mamba_d_conv": 4, "mamba_chunk_size": 16, "mamba_conv_bias": True,
+    "mamba_proj_bias": False, "mlp_hidden_act": "relu2",
+    "moe_intermediate_size": 32, "moe_shared_expert_intermediate_size": 48,
+    "n_routed_experts": 8, "experts_held": 2, "first_expert": 0,
+    "n_shared_experts": 1, "num_experts_per_tok": 2,
+    "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+    "rms_norm_eps": 1e-5, "initializer_range": 0.02,
+    "router_bias_std": 0.01, "router_bias_from_batch": True,
+    "first_k_dense_replace": 0, "recompute": True,
 }
 
 
@@ -244,12 +278,11 @@ def _gqa(x, cfg, prefix, kind):
 def _mamba2(u, cfg, prefix):
     heads, dh = cfg["mamba_n_heads"], cfg["mamba_d_head"]
     state, inner = cfg["mamba_d_state"], cfg["mamba_n_heads"] * dh
-    if cfg.get("mamba_n_groups", 1) != 1 or cfg.get("mamba_proj_bias") \
-            or inner != cfg["mamba_expand"] * cfg["hidden_size"]:
-        raise ValueError("models/decoder.py builds Mamba-2 mixers of one "
-                         "group, no projection bias, and heads * d_head = "
-                         "expand * hidden_size")
-    conv = inner + 2 * state
+    groups = cfg.get("mamba_n_groups", 1)
+    if cfg.get("mamba_proj_bias"):
+        raise ValueError("models/decoder.py builds Mamba-2 mixers with no "
+                         "projection bias")
+    conv = inner + 2 * groups * state
     proj = _linear(u, inner + conv + heads, prefix + ".in_proj", cfg)
 
     def part(lo, width):
@@ -268,12 +301,18 @@ def _mamba2(u, cfg, prefix):
             name=prefix + ".conv.b",
             initializer=fluid.initializer.Uniform(-bound, bound))
         if cfg["mamba_conv_bias"] else False))
+
+    def group_state(lo):
+        # B or C: [B, T, N] for one group, else [B, T, G, N]
+        v = fluid.layers.slice(xbc, [2], [lo], [lo + groups * state])
+        return v if groups == 1 else fluid.layers.reshape(
+            v, [0, 0, groups, state])
+
     y = fluid.layers.ssd_scan(
         fluid.layers.reshape(fluid.layers.slice(xbc, [2], [0], [inner]),
                              [0, 0, heads, dh]),
-        part(inner + conv, heads),
-        fluid.layers.slice(xbc, [2], [inner], [inner + state]),
-        fluid.layers.slice(xbc, [2], [inner + state], [conv]),
+        part(inner + conv, heads), group_state(inner),
+        group_state(inner + groups * state),
         chunk_size=cfg["mamba_chunk_size"],
         a_log_attr=fluid.ParamAttr(name=prefix + ".A_log"),
         d_attr=fluid.ParamAttr(name=prefix + ".D"),
@@ -281,7 +320,8 @@ def _mamba2(u, cfg, prefix):
     o = fluid.layers.rms_norm_gated(
         fluid.layers.reshape(y, [0, 0, inner]), part(0, inner),
         epsilon=cfg["rms_norm_eps"],
-        param_attr=fluid.ParamAttr(name=prefix + ".norm.scale"))
+        param_attr=fluid.ParamAttr(name=prefix + ".norm.scale"),
+        group_size=inner // groups if groups > 1 else None)
     return _linear(o, cfg["hidden_size"], prefix + ".out_proj", cfg)
 
 
@@ -311,6 +351,15 @@ def _swiglu_mlp(x, width, prefix, cfg):
         cfg["hidden_size"], prefix + ".down", cfg)
 
 
+def _mlp(x, width, prefix, cfg):
+    """SwiGLU, or ``W_down relu(W_up x)^2`` where ``mlp_hidden_act`` is
+    ``relu2``."""
+    if cfg.get("mlp_hidden_act") != "relu2":
+        return _swiglu_mlp(x, width, prefix, cfg)
+    return _linear(fluid.layers.relu2(_linear(x, width, prefix + ".up", cfg)),
+                   cfg["hidden_size"], prefix + ".down", cfg)
+
+
 def _expert_layer(x, cfg, prefix, train):
     """Returns the layer's result, what ``moe_count_rows`` takes, and
     ``(the correction bias's name, the bias the step used)`` where the
@@ -335,13 +384,16 @@ def _expert_layer(x, cfg, prefix, train):
         x, index, gate, cfg["moe_intermediate_size"], cfg["experts_held"],
         first_expert=cfg.get("first_expert", 0),
         experts_total=cfg["n_routed_experts"],
+        activation="relu2" if cfg.get("mlp_hidden_act") == "relu2"
+        else "swiglu",
         param_attr=fluid.ParamAttr(name=prefix + ".experts",
                                    initializer=init))
     out = routed
     if cfg["n_shared_experts"]:
         with device_tag("moe_shared"):
-            shared = _swiglu_mlp(
-                x, cfg["n_shared_experts"] * cfg["moe_intermediate_size"],
+            shared = _mlp(
+                x, cfg.get("moe_shared_expert_intermediate_size")
+                or cfg["n_shared_experts"] * cfg["moe_intermediate_size"],
                 prefix + ".shared", cfg)
             out = fluid.layers.elementwise_add(routed, shared)
     return out, (rows, index), [(bias, u) for u in used]
@@ -354,7 +406,32 @@ def _residual(x, branch, cfg):
     return fluid.layers.elementwise_add(x, branch)
 
 
+def _one_part_layer(x, i, cfg, train):
+    """``h = x + part(RMSNorm(x))``, the part by ``hybrid_override_pattern
+    [i]``: ``M`` a Mamba-2 mixer, ``E`` an expert layer, ``*`` attention."""
+    prefix = "decoder.layer%d" % i
+    kind = cfg["hybrid_override_pattern"][i]
+    if kind == "E":
+        part, counted, used = _expert_layer(
+            _rms_norm(x, prefix + ".ln1", cfg), cfg, prefix + ".moe", train)
+        return fluid.layers.elementwise_add(x, part), counted, used
+    if kind == "M":
+        tag, build, name = "ssm_mixer", _mamba2, ".mixer"
+    elif kind == "*":
+        tag, name = "gqa_attention", ".attn"
+        build = functools.partial(_gqa, kind="full_attention")
+    else:
+        raise ValueError("models/decoder.py has no layer kind %r in "
+                         "hybrid_override_pattern" % kind)
+    with device_tag(tag):
+        return fluid.layers.elementwise_add(x, build(
+            _rms_norm(x, prefix + ".ln1", cfg), cfg, prefix + name)), \
+            None, []
+
+
 def _layer(x, i, cfg, train):
+    if "hybrid_override_pattern" in cfg:
+        return _one_part_layer(x, i, cfg, train)
     prefix = "decoder.layer%d" % i
     tag, mixer = _mixer(i, cfg)
     with device_tag(tag):
@@ -365,8 +442,8 @@ def _layer(x, i, cfg, train):
     counted, used = None, []
     if i < cfg["first_k_dense_replace"]:
         with device_tag("dense_mlp"):
-            ff = _swiglu_mlp(normed, cfg["intermediate_size"],
-                             prefix + ".mlp", cfg)
+            ff = _mlp(normed, cfg["intermediate_size"], prefix + ".mlp",
+                      cfg)
     else:
         ff, counted, used = _expert_layer(normed, cfg, prefix + ".moe",
                                           train)

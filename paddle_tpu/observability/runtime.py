@@ -412,7 +412,7 @@ def record_moe_layers(program, compile_phase):
     if not experts:
         return
     compile_phase.set_attr("moe_layers", len(experts))
-    compile_phase.set_attr("experts_held", len(experts[0].input("WGate")))
+    compile_phase.set_attr("experts_held", len(experts[0].input("WDown")))
     for op in ops:
         if op.type == "moe_route":
             weight = op.block._find_var_recursive(op.input("Weight")[0])

@@ -701,6 +701,12 @@ def swiglu(ctx, attrs, X, Y):
             * Y.astype(jnp.float32)).astype(X.dtype)
 
 
+@register_op("relu2", inputs=["X"], outputs=["Out"])
+def relu2(ctx, attrs, X):
+    """``relu(x)^2``, in float32 (the activation of a relu² MLP)."""
+    return jnp.square(jax.nn.relu(X.astype(jnp.float32))).astype(X.dtype)
+
+
 @register_op("causal_conv1d", inputs=["X", "Filter", "Bias"],
              outputs=["Out"])
 def causal_conv1d(ctx, attrs, X, Filter, Bias=None):
@@ -720,11 +726,19 @@ def causal_conv1d(ctx, attrs, X, Filter, Bias=None):
 @register_op("rms_norm_gated", inputs=["X", "Z", "Scale"], outputs=["Y"])
 def rms_norm_gated(ctx, attrs, X, Z, Scale):
     """``g = x * silu(z)``, then ``g / sqrt(mean(g^2) + eps) * scale``
-    over the last axis, in float32 (Mamba-2's gated norm, one group)."""
+    in float32 (Mamba-2's gated norm): the mean over the whole last axis
+    (one group), or with attr ``group_size`` over each run of that many
+    channels of it (``G`` groups: Nemotron-H's ``MambaRMSNormGated``)."""
     eps = float(attrs.get("epsilon", 1e-6))
     g = X.astype(jnp.float32) * jax.nn.silu(Z.astype(jnp.float32))
+    size = int(attrs.get("group_size", 0) or 0)
+    if size:
+        shape = g.shape
+        g = g.reshape(shape[:-1] + (shape[-1] // size, size))
     y = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
                           + eps)
+    if size:
+        y = y.reshape(shape)
     if Scale is not None:
         y = y * Scale.astype(jnp.float32)
     return y.astype(X.dtype)
@@ -741,7 +755,8 @@ def _ssd_site(ctx, attrs, X, Dt, ALog, B, C, D=None, DtBias=None):
 def ssd_scan(ctx, attrs, X, Dt, ALog, B, C, D=None, DtBias=None):
     """Mamba-2's state-space-dual scan (``ops/pallas/ssd_scan.py``): X [B,
     T, H, P], Dt [B, T, H], B and C [B, T, N] (one group, shared by every
-    head), ALog, D, DtBias [H].  ``Δ = softplus(Dt + DtBias)``, ``A =
+    head) or [B, T, G, N] (head h reads group ``h // (H / G)``), ALog, D,
+    DtBias [H].  ``Δ = softplus(Dt + DtBias)``, ``A =
     -exp(ALog)``, both in float32; per head ``S_t = exp(Δ_t A) S_{t-1} +
     Δ_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``, in chunks of
     ``chunk_size``.  Lowered to the
@@ -795,7 +810,7 @@ def _moe_experts_shapes(op, block):
     out = block._find_var_recursive(op.output("Out")[0])
     out.shape, out.dtype = tuple(x.shape), x.dtype
     rows = block._find_var_recursive(op.output("Rows")[0])
-    rows.shape, rows.dtype = (len(op.input("WGate")),), "int32"
+    rows.shape, rows.dtype = (len(op.input("WDown")),), "int32"
 
 
 @register_op("moe_route", inputs=["X", "Weight", "Bias"],
@@ -839,20 +854,23 @@ def moe_experts(ctx, attrs, X, Index, Gate, WGate, WUp, WDown):
     (``parallel/moe.py`` ``held_experts_ffn``): WGate, WUp (each expert's
     [D, F]) and WDown ([F, D]) are experts ``first_expert .. first_expert
     + held - 1`` of those Index counts over, stacked here for the grouped
-    products.  Dropless.  Out: X's shape; Rows: [held] int32, the rows
-    each held expert was given this step.  In a recompute region
-    (``ctx.region``) the site keeps its plan (the choices' order and the
-    rows) and the loop's first block's gate and up products across the
-    region; the re-run walks that plan and its backward takes the
-    products in place of that block's three forward products."""
+    products.  Attr ``activation``: ``swiglu`` (the default, ``E(x) =
+    W_down (silu(W_gate x) * W_up x)``) or ``relu2`` (``E(x) = W_down
+    relu(W_up x)^2``, no WGate).  Dropless.  Out: X's shape; Rows: [held]
+    int32, the rows each held expert was given this step.  In a recompute
+    region (``ctx.region``) the site keeps its plan (the choices' order and
+    the rows) and the loop's first block's in-products (gate and up, or
+    up) across the region; the re-run walks that plan and its backward
+    takes the products in place of that block's forward in-products."""
     from ..parallel.moe import held_experts_ffn
 
     shape = jnp.shape(X)
     ffn = functools.partial(
         held_experts_ffn, X.reshape(-1, shape[-1]), Index, Gate,
-        jnp.stack(WGate), jnp.stack(WUp), jnp.stack(WDown),
-        first=int(attrs.get("first_expert", 0)), scope=ctx.part_scope,
-        total=int(attrs.get("experts_total", 0)))
+        jnp.stack(WGate) if WGate else None, jnp.stack(WUp),
+        jnp.stack(WDown), first=int(attrs.get("first_expert", 0)),
+        scope=ctx.part_scope, total=int(attrs.get("experts_total", 0)),
+        activation=attrs.get("activation", "swiglu"))
     out, rows = _keeping_across_region(ctx, "moe_experts", ffn,
                                        lambda: True)
     return {"Out": out.reshape(shape), "Rows": rows}

@@ -2,8 +2,9 @@
 TPU kernels.
 
 Per head h, with a scalar decay rate ``A_h < 0``, a step ``dt_t > 0`` (Δ),
-``x_t`` of ``P`` features and ``B_t``, ``C_t`` of ``N`` shared by every
-head (one group)::
+``x_t`` of ``P`` features and ``B_t``, ``C_t`` of ``N`` of the head's group
+(``G`` groups of ``H / G`` heads, head h reading group ``h // (H / G)``;
+with one group they are shared by every head)::
 
     S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T      (S: P x N, S_0 = 0)
     y_t = S_t C_t + D_h x_t
@@ -17,7 +18,8 @@ over the chunk up to t (inclusive) and ``u_s = dt_s x_s``::
     S   = exp(cum_last) S_prev + sum_s exp(cum_last - cum_s) u_s B_s^T
 
 * forward (``ssd_scan_fwd``): grid (batch, blocks of ``hb`` heads,
-  chunks), the chunk axis sequential; the carried state of the block's
+  chunks), the chunk axis sequential; a block lies inside one group,
+  whose B and C its block specs pick; the carried state of the block's
   heads, ``S^T`` as ``[N, hb P]`` float32, lives in VMEM scratch from
   chunk to chunk.  A grid step computes ``C B^T`` once for its heads,
   each head's decay matrix from its ``cum`` row, the within-chunk product
@@ -28,7 +30,7 @@ over the chunk up to t (inclusive) and ``u_s = dt_s x_s``::
   shaped values keep the source position ``s`` down the rows (the
   transpose of the forward's), so every product is a plain one or an
   ``a @ b^T``; it writes dx, the rows of dΔ and d(cum), and dB and dC of
-  its heads (summed over the head blocks outside).
+  its heads (summed outside over the head blocks of each group).
 
 Heads lie ``P`` lanes apart in ``[.., H P]`` (the layout ``in_proj``
 gives): a 128-lane tile holds ``128 / P`` heads, and a head's product is
@@ -276,17 +278,25 @@ def _bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, bt_ref, ct_ref, s_ref,
 # The calls
 # ---------------------------------------------------------------------------
 
-def _specs(hb, head_dim, chunk, n, reverse, chunks):
+def _specs(hb, head_dim, chunk, n, reverse, chunks, blocks_a_group=None):
     """Block specs over (batch, head block, chunk): ``reverse`` walks
-    the chunks from the last."""
+    the chunks from the last; ``blocks_a_group``: B and C hold a group
+    of ``n`` lanes for each run of that many head blocks (None: one
+    group, the same for every block)."""
     def at(c):
         return chunks - 1 - c if reverse else c
 
     lanes = pl.BlockSpec((1, chunk, hb * head_dim),
                          lambda b, h, c: (b, at(c), h))
     rows = pl.BlockSpec((1, hb, chunk), lambda b, h, c: (b, h, at(c)))
-    shared = pl.BlockSpec((1, chunk, n), lambda b, h, c: (b, at(c), 0))
-    turned = pl.BlockSpec((1, n, chunk), lambda b, h, c: (b, 0, at(c)))
+    if blocks_a_group is None:
+        shared = pl.BlockSpec((1, chunk, n), lambda b, h, c: (b, at(c), 0))
+        turned = pl.BlockSpec((1, n, chunk), lambda b, h, c: (b, 0, at(c)))
+    else:
+        shared = pl.BlockSpec((1, chunk, n), lambda b, h, c: (
+            b, at(c), h // blocks_a_group))
+        turned = pl.BlockSpec((1, n, chunk), lambda b, h, c: (
+            b, h // blocks_a_group, at(c)))
     state = pl.BlockSpec((1, 1, n, hb * head_dim),
                          lambda b, h, c: (b, at(c), 0, h))
     per_block = pl.BlockSpec((1, 1, chunk, n),
@@ -302,14 +312,25 @@ def _operands(x, interpret):
     return jnp.float32 if interpret else x.dtype
 
 
+def _grouped(b, c, width, hb, head_dim):
+    """B and C as the kernels take them, ``[Bt, T, G N]``, and the head
+    blocks a group spans (None for one group: ``[Bt, T, N]`` as given)."""
+    if b.ndim == 3:
+        return b, c, None
+    bt, t, groups, n = b.shape
+    return (b.reshape(bt, t, groups * n), c.reshape(bt, t, groups * n),
+            width // (hb * head_dim * groups))
+
+
 def _ssd_fwd(x, dtT, cumT, b, c, static):
     """``(y, the state each chunk begins with [Bt, chunks, N, H P])``."""
     head_dim, chunk, hb, interpret = static
     bt, t, width = x.shape
     n, chunks = b.shape[-1], t // chunk
     _note_chunks("forward", bt * chunks * (width // head_dim))
+    b, c, per_group = _grouped(b, c, width, hb, head_dim)
     lanes, rows, shared, turned, state, _ = _specs(
-        hb, head_dim, chunk, n, False, chunks)
+        hb, head_dim, chunk, n, False, chunks, per_group)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, head_dim=head_dim,
                           dtype=_operands(x, interpret)),
@@ -330,8 +351,10 @@ def _ssd_bwd(x, dtT, cumT, b, c, states, dy, static):
     bt, t, width = x.shape
     n, chunks, blocks = b.shape[-1], t // chunk, width // (hb * head_dim)
     _note_chunks("backward", bt * chunks * (width // head_dim))
+    groups = _groups(b)
+    b, c, per_group = _grouped(b, c, width, hb, head_dim)
     lanes, rows, shared, turned, state, per_block = _specs(
-        hb, head_dim, chunk, n, True, chunks)
+        hb, head_dim, chunk, n, True, chunks, per_group)
     dx, ddt, dcum, db, dc = pl.pallas_call(
         functools.partial(_bwd_kernel, head_dim=head_dim,
                           dtype=_operands(x, interpret)),
@@ -349,8 +372,16 @@ def _ssd_bwd(x, dtT, cumT, b, c, states, dy, static):
         interpret=interpret,
     )(x, dtT, cumT, b, c, jnp.swapaxes(b, 1, 2), jnp.swapaxes(c, 1, 2),
       states, dy)
-    return (dx, ddt.astype(dtT.dtype), dcum, db.sum(1).astype(b.dtype),
-            dc.sum(1).astype(c.dtype))
+
+    def over_blocks(v):
+        """[Bt, blocks, T, N] summed over each group's head blocks."""
+        if per_group is None:
+            return v.sum(1)
+        return jnp.swapaxes(v.reshape(bt, groups, per_group, t, n).sum(2),
+                            1, 2)
+
+    return (dx, ddt.astype(dtT.dtype), dcum,
+            over_blocks(db).astype(b.dtype), over_blocks(dc).astype(c.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -395,30 +426,31 @@ _scan_kept.defvjp(_scan_kept_fwd_rule, _scan_kept_bwd_rule)
 
 def _chunked_xla(x, dt, cum, b, c, chunk):
     """``y`` without ``D x``: x [Bt, T, H, P], dt and cum [Bt, T, H], b
-    and c [Bt, T, N], T a multiple of ``chunk``; float32."""
+    and c [Bt, T, G, N] (head h reads group ``h // (H / G)``), T a
+    multiple of ``chunk``; float32."""
     bt, t, h, p = x.shape
-    k = t // chunk
-    xc = x.reshape(bt, k, chunk, h, p)
-    dtc, cc = dt.reshape(bt, k, chunk, h), cum.reshape(bt, k, chunk, h)
-    bc, ccm = (v.reshape(bt, k, chunk, -1) for v in (b, c))
+    g, k = b.shape[2], t // chunk
+    xc = x.reshape(bt, k, chunk, g, h // g, p)
+    dtc, cc = (v.reshape(bt, k, chunk, g, h // g) for v in (dt, cum))
+    bc, ccm = (v.reshape(bt, k, chunk, g, -1) for v in (b, c))
     u = xc * dtc[..., None]
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))[None, None, :, :,
-                                                       None]
-    seg = cc[:, :, :, None, :] - cc[:, :, None, :, :]      # [.., t, s, H]
+                                                       None, None]
+    seg = cc[:, :, :, None] - cc[:, :, None]         # [.., t, s, G, H/G]
     decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    g = jnp.einsum("bktn,bksn->bkts", ccm, bc)
-    y = jnp.einsum("bkts,bktsh,bkshp->bkthp", g, decay, u)
-    w = jnp.exp(cc[:, :, -1:, :] - cc)
-    into = jnp.einsum("bksh,bkshp,bksn->bkhpn", w, u, bc)
+    gm = jnp.einsum("bktgn,bksgn->bktsg", ccm, bc)
+    y = jnp.einsum("bktsg,bktsgr,bksgrp->bktgrp", gm, decay, u)
+    w = jnp.exp(cc[:, :, -1:] - cc)
+    into = jnp.einsum("bksgr,bksgrp,bksgn->bkgrpn", w, u, bc)
 
     def carry(s, step):
         add, fall = step
         return s * fall[..., None, None] + add, s
 
     _, begins = jax.lax.scan(
-        carry, jnp.zeros((bt, h, p, bc.shape[-1]), x.dtype),
+        carry, jnp.zeros((bt, g, h // g, p, bc.shape[-1]), x.dtype),
         (jnp.moveaxis(into, 1, 0), jnp.moveaxis(jnp.exp(cc[:, :, -1]), 1, 0)))
-    y = y + jnp.einsum("bktn,kbhpn->bkthp", ccm, begins) \
+    y = y + jnp.einsum("bktgn,kbgrpn->bktgrp", ccm, begins) \
         * jnp.exp(cc)[..., None]
     return y.reshape(bt, t, h, p)
 
@@ -427,20 +459,33 @@ def _chunked_xla(x, dt, cum, b, c, chunk):
 # Public entry
 # ---------------------------------------------------------------------------
 
+def _groups(b):
+    return 1 if len(b.shape) == 3 else b.shape[2]
+
+
 def routes_to_kernel(x, b, chunk=CHUNK):
     """Does :func:`ssd_scan` run the Pallas kernels for x [Bt, T, H, P]
-    and b [Bt, T, N] (anything with ``.shape``), or XLA ops?"""
+    and b [Bt, T, N] or [Bt, T, G, N] (anything with ``.shape``), or XLA
+    ops?"""
     _, _, h, p = x.shape
     return (use_pallas()[0] and 128 % p == 0 and h * p % 128 == 0
-            and b.shape[-1] % 16 == 0 and chunk % 128 == 0)
+            and b.shape[-1] % 16 == 0 and chunk % 128 == 0
+            and _heads_a_block(h, p, _groups(b)) is not None)
 
 
-def _heads_a_block(h, p):
-    """Heads a grid step: about ``HEADS_A_BLOCK``, dividing ``h``, whole
-    128-lane tiles, and a multiple of 8 rows of ``cum`` (or all of them)."""
-    hb = min(HEADS_A_BLOCK, h)
-    while h % hb or hb * p % 128 or (hb % 8 and hb != h):
+def _heads_a_block(h, p, groups=1):
+    """Heads a grid step: about ``HEADS_A_BLOCK``, dividing the ``h /
+    groups`` heads of a group (a block reads one group's B and C), whole
+    128-lane tiles, and a multiple of 8 rows of ``cum`` (or all of them);
+    None where no such block exists."""
+    if h % groups:
+        return None
+    per = h // groups
+    hb = min(HEADS_A_BLOCK, per)
+    while per % hb or hb * p % 128 or (hb % 8 and hb != h):
         hb += 1
+        if hb > per:
+            return None
     return hb
 
 
@@ -448,7 +493,8 @@ def ssd_scan(x, dt, a, b, c, d=None, chunk=CHUNK, residuals=None,
              return_residuals=False):
     """Mamba-2's scan.  x: [Bt, T, H, P]; dt (Δ, after its softplus):
     [Bt, T, H]; a (A, negative): [H]; b, c: [Bt, T, N], one group shared
-    by every head; d: [H] or None.  Returns y [Bt, T, H, P] in x's dtype.
+    by every head, or [Bt, T, G, N], head h reading group ``h // (H /
+    G)``; d: [H] or None.  Returns y [Bt, T, H, P] in x's dtype.
     dt, a and d are taken in float32.  T need not be a multiple of
     ``chunk``: the tail is padded with Δ = 0, which moves no state.
 
@@ -471,11 +517,14 @@ def ssd_scan(x, dt, a, b, c, d=None, chunk=CHUNK, residuals=None,
                      axis=2).reshape(bt, tp, h)
     kept = None
     if not routes_to_kernel(x, b, chunk):
-        y = _chunked_xla(x.astype(jnp.float32), dt, cum,
-                         b.astype(jnp.float32), c.astype(jnp.float32),
-                         chunk).astype(x.dtype)
+        def grouped(v):
+            v = v.astype(jnp.float32)
+            return v[:, :, None] if v.ndim == 3 else v
+        y = _chunked_xla(x.astype(jnp.float32), dt, cum, grouped(b),
+                         grouped(c), chunk).astype(x.dtype)
     else:
-        static = (p, chunk, _heads_a_block(h, p), use_pallas()[1])
+        static = (p, chunk, _heads_a_block(h, p, _groups(b)),
+                  use_pallas()[1])
         args = (x.reshape(bt, tp, h * p), jnp.swapaxes(dt, 1, 2),
                 jnp.swapaxes(cum, 1, 2), b.astype(x.dtype),
                 c.astype(x.dtype))

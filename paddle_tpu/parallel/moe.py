@@ -34,7 +34,8 @@ top_k`` choices (every choice of every token may land here), those of
 the held experts first and sorted by expert, and one loop walks that
 order in blocks (:func:`block_rows`) as far as the rows routed here reach,
 its trip count read from the row counts on the device.  A block gathers
-its tokens' rows, runs the three products as :func:`grouped_dot`
+its tokens' rows, runs the expert's products (three for SwiGLU, gate,
+up and down; two for relu², up and down) as :func:`grouped_dot`
 over the held experts' row counts inside the block (on the TPU one
 grouped Pallas kernel a product, ``ops/pallas/grouped_matmul.py``, that
 visits only the tiles the counts cover; ``jax.lax.ragged_dot`` off it)
@@ -47,6 +48,7 @@ The Switch path above is as it was.
 """
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -389,47 +391,56 @@ def _swiglu(h, u):
             * u.astype(jnp.float32)).astype(h.dtype)
 
 
-def _gate_and_up(scope, xs, w_gate, w_up, sizes):
-    """A block's gate and up products, [B, F] each."""
+def _relu2(u):
+    """``relu(u)^2`` in float32, in u's dtype."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)
+
+
+# an expert ``E(x) = W_down act(*(W x for W in its in-products))``, by
+# name: the in-products are (gate, up) for SwiGLU and (up,) for relu²
+ACTIVATIONS = {"swiglu": _swiglu, "relu2": _relu2}
+
+
+def _in_products(scope, xs, w_in, sizes):
+    """A block's in-products (gate and up, or up alone), [B, F] each."""
     with scope("products"):
-        return grouped_dot(xs, w_gate, sizes), grouped_dot(xs, w_up, sizes)
+        return tuple(grouped_dot(xs, w, sizes) for w in w_in)
 
 
-def _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes, kept=None):
+def _block_ffn(scope, act, xs, g, w_in, w_down, sizes, kept=None):
     """One block of the buffer: xs [B, D] the rows' tokens, g [B] their
     gates, sizes [held] the rows of each held expert inside the block,
-    ``kept`` its gate and up products where they were computed before.
-    Returns [B, D] float32, what each row adds to its token."""
-    h, u = _gate_and_up(scope, xs, w_gate, w_up, sizes) if kept is None \
+    ``kept`` its in-products where they were computed before.  Returns
+    [B, D] float32, what each row adds to its token."""
+    products = _in_products(scope, xs, w_in, sizes) if kept is None \
         else kept
     with scope("products"):
-        y = grouped_dot(_swiglu(h, u), w_down, sizes)
+        y = grouped_dot(ACTIVATIONS[act](*products), w_down, sizes)
     with scope("combine"):
         return y.astype(jnp.float32) * g[:, None]
 
 
-def _block_ffn_bwd(scope, xs, g, w_gate, w_up, w_down, sizes, h, u, ct):
+def _block_ffn_bwd(scope, act, xs, g, w_in, w_down, sizes, products, ct):
     """The backward of :func:`_block_ffn` at ``ct`` [B, D] float32, from
-    the block's gate and up products ``h``, ``u``: six grouped products,
-    the transposes each cotangent needs and none forward.  The gate's
-    cotangent ``<y[r], ct[r]>`` is taken as ``<a[r], (ct W_down^T)[r]>``
-    (``a = silu(h) * u``, ``y = a W_down``), and ``ct W_down^T``, times
-    the gate, is the cotangent of ``a``.  Returns ``(d_xs, d_g, [d_w_gate,
-    d_w_up, d_w_down])``."""
+    the block's in-products: the transposes each cotangent needs and no
+    forward product (six grouped products for SwiGLU, four for relu²).
+    The gate's cotangent ``<y[r], ct[r]>`` is taken as ``<a[r], (ct
+    W_down^T)[r]>`` (``a = act(..)``, ``y = a W_down``), and ``ct
+    W_down^T``, times the gate, is the cotangent of ``a``.  Returns
+    ``(d_xs, d_g, [d_w for each in-product, d_w_down])``."""
     with scope("products"):
-        a, swiglu_pullback = jax.vjp(_swiglu, h, u)
+        a, act_pullback = jax.vjp(ACTIVATIONS[act], *products)
         d_w_down = grouped_matmul_rhs_grad(
             a, (ct * g[:, None]).astype(xs.dtype), sizes)
         ct_a = grouped_matmul_t(ct.astype(xs.dtype), w_down, sizes)
         d_g = jnp.sum(a.astype(jnp.float32) * ct_a.astype(jnp.float32),
                       axis=1)
-        d_h, d_u = swiglu_pullback(
+        d_in = act_pullback(
             (ct_a.astype(jnp.float32) * g[:, None]).astype(xs.dtype))
-        d_xs = (grouped_matmul_t(d_h, w_gate, sizes)
-                + grouped_matmul_t(d_u, w_up, sizes))
-        d_w_gate = grouped_matmul_rhs_grad(xs, d_h, sizes)
-        d_w_up = grouped_matmul_rhs_grad(xs, d_u, sizes)
-    return d_xs, d_g, [d_w_gate, d_w_up, d_w_down]
+        d_xs = functools.reduce(operator.add, (
+            grouped_matmul_t(d, w, sizes) for d, w in zip(d_in, w_in)))
+        d_w_in = [grouped_matmul_rhs_grad(xs, d, sizes) for d in d_in]
+    return d_xs, d_g, d_w_in + [d_w_down]
 
 
 def _block_inputs(scope, size, i, x, gates, order, rows):
@@ -448,34 +459,34 @@ def _block_inputs(scope, size, i, x, gates, order, rows):
     return choice, token, xs, g, sizes
 
 
-def _forward_trip(scope, size, x, gates, w_gate, w_up, w_down, order,
-                  rows, kept=None):
+def _forward_trip(scope, size, act, x, gates, w_in, w_down, order, rows,
+                  kept=None):
     """The forward walk's body: block i computed (from ``kept`` where
     given) and added to the ``[T, D]`` float32 result it carries."""
     def trip(i, out):
         _, token, xs, g, sizes = _block_inputs(scope, size, i, x, gates,
                                                order, rows)
-        y = _block_ffn(scope, xs, g, w_gate, w_up, w_down, sizes, kept)
+        y = _block_ffn(scope, act, xs, g, w_in, w_down, sizes, kept)
         with scope("combine"):
             return out.at[token].add(y, mode="promise_in_bounds")
     return trip
 
 
-def _backward_trip(scope, size, args, ct, kept=None):
+def _backward_trip(scope, size, act, args, ct, kept=None):
     """The backward walk's body: block i differentiated from ``kept``
-    where given, else from its gate and up products computed anew, and
-    the cotangents of x (in x's dtype, as the transpose of a gather from x
+    where given, else from its in-products computed anew, and the
+    cotangents of x (in x's dtype, as the transpose of a gather from x
     adds them), the gates and the weights added to those it carries."""
-    x, gates, w_gate, w_up, w_down, order, rows = args
+    x, gates, w_in, w_down, order, rows = args
 
     def trip(i, carry):
         d_x, d_gates, d_weights = carry
         choice, token, xs, g, sizes = _block_inputs(scope, size, i, x,
                                                     gates, order, rows)
-        h, u = _gate_and_up(scope, xs, w_gate, w_up, sizes) \
+        products = _in_products(scope, xs, w_in, sizes) \
             if kept is None else kept
         d_xs, d_g, d_w = _block_ffn_bwd(
-            scope, xs, g, w_gate, w_up, w_down, sizes, h, u,
+            scope, act, xs, g, w_in, w_down, sizes, products,
             ct.at[token].get(mode="promise_in_bounds").astype(jnp.float32))
         return (d_x.at[token].add(d_xs, mode="promise_in_bounds"),
                 d_gates.at[choice].add(d_g, mode="promise_in_bounds"),
@@ -483,55 +494,56 @@ def _backward_trip(scope, size, args, ct, kept=None):
     return trip
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _walk_blocks(scope, size, x, gates, w_gate, w_up, w_down, order, rows,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _walk_blocks(scope, size, act, x, gates, w_in, w_down, order, rows,
                  kept):
-    """``held_experts_ffn`` behind its plan: order [a multiple of the
-    block's ``size``] and rows [held] from :func:`plan_held_rows`, and
-    ``kept`` block 0's gate and up products (:func:`_first_products`) or
-    None.  Block 0 runs ahead of the loop whatever the load (on no routed
-    row it adds zero, as no trip does), from ``kept`` where given; the
-    loop takes the blocks after it."""
-    trip = functools.partial(_forward_trip, scope, size, x, gates, w_gate,
-                             w_up, w_down, order, rows)
+    """``held_experts_ffn`` behind its plan: ``act`` a name of
+    :data:`ACTIVATIONS`, w_in its in-products' weights, order [a multiple
+    of the block's ``size``] and rows [held] from :func:`plan_held_rows`,
+    and ``kept`` block 0's in-products (:func:`_first_products`) or None.
+    Block 0 runs ahead of the loop whatever the load (on no routed row it
+    adds zero, as no trip does), from ``kept`` where given; the loop
+    takes the blocks after it."""
+    trip = functools.partial(_forward_trip, scope, size, act, x, gates,
+                             w_in, w_down, order, rows)
     out = trip(kept)(0, jnp.zeros(x.shape, jnp.float32))
     out = jax.lax.fori_loop(1, blocks_run(rows, size), trip(), out)
     return out.astype(x.dtype)
 
 
-def _walk_blocks_fwd(scope, size, *args):
-    return _walk_blocks(scope, size, *args), args
+def _walk_blocks_fwd(scope, size, act, *args):
+    return _walk_blocks(scope, size, act, *args), args
 
 
-def _walk_blocks_bwd(scope, size, args, ct):
+def _walk_blocks_bwd(scope, size, act, args, ct):
     """The same walk again (``_backward_trip``), block 0 from ``kept``
     where the forward was given it; nothing is kept between blocks.
     ``kept`` takes no cotangent: it is what a forward computed, not an
     input."""
     *args, kept = args
-    x, gates, w_gate, w_up, w_down, _, rows = args
-    trip = functools.partial(_backward_trip, scope, size, args, ct)
+    x, gates, w_in, w_down, _, rows = args
+    trip = functools.partial(_backward_trip, scope, size, act, args, ct)
     carry = trip(kept)(0, (jnp.zeros_like(x),
                            jnp.zeros(gates.size, gates.dtype),
-                           [jnp.zeros_like(w) for w in (w_gate, w_up,
-                                                        w_down)]))
+                           [jnp.zeros_like(w) for w in w_in + (w_down,)]))
     d_x, d_gates, d_weights = jax.lax.fori_loop(
         1, blocks_run(rows, size), trip(), carry)
-    return (d_x, d_gates.reshape(gates.shape), *d_weights, None, None,
-            jax.tree.map(lambda _: None, kept))
+    return (d_x, d_gates.reshape(gates.shape), tuple(d_weights[:-1]),
+            d_weights[-1], None, None, jax.tree.map(lambda _: None, kept))
 
 
 _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 
 
-def _first_products(scope, size, x, gates, w_gate, w_up, order, rows):
-    """Block 0's gate and up products ``(h, u)``, [size, F] each, as a
-    recompute region's forward keeps them: computed outside any
-    differentiation (the gradient goes through ``_walk_blocks``)."""
-    x, w_gate, w_up = map(jax.lax.stop_gradient, (x, w_gate, w_up))
+def _first_products(scope, size, x, gates, w_in, order, rows):
+    """Block 0's in-products (``(h, u)`` for SwiGLU, ``(u,)`` for relu²),
+    [size, F] each, as a recompute region's forward keeps them: computed
+    outside any differentiation (the gradient goes through
+    ``_walk_blocks``)."""
+    x, w_in = jax.lax.stop_gradient((x, w_in))
     _, _, xs, _, sizes = _block_inputs(scope, size, 0, x, gates, order,
                                        rows)
-    return _gate_and_up(scope, xs, w_gate, w_up, sizes)
+    return _in_products(scope, xs, w_in, sizes)
 
 
 def _gates_in_places(idx, chosen, gates):
@@ -550,9 +562,11 @@ def _gates_in_places(idx, chosen, gates):
 
 def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
                      scope=jax.named_scope, total=None, residuals=None,
-                     return_residuals=False):
+                     return_residuals=False, activation="swiglu"):
     """The held experts' part of ``sum_k gates[t,k] * E_idx[t,k](x[t])``
-    with ``E(x) = W_down (silu(W_gate x) * W_up x)``.
+    with, by ``activation``, ``E(x) = W_down (silu(W_gate x) * W_up x)``
+    (``"swiglu"``) or ``E(x) = W_down relu(W_up x)^2`` (``"relu2"``, no
+    gate weights: ``w_gate`` None).
 
     x: [T, D]; idx, gates: [T, K] from :func:`sigmoid_topk_route`;
     w_gate, w_up: [held, D, F]; w_down: [held, F, D]: the experts
@@ -566,7 +580,7 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
     first and sorted by expert (:func:`plan_held_rows`), and one loop
     walks that order in blocks of :func:`block_rows` as far as the routed
     rows reach (:func:`blocks_run`, read from ``rows`` on the device).  A
-    block gathers its choices' tokens from x, runs the three products
+    block gathers its choices' tokens from x, runs the expert's products
     grouped by the held experts' row counts cut to the block's range,
     and scatter-adds the results to their tokens, times their gates, in
     float32, into the ``[T, D]`` result the loop carries: no buffer of
@@ -575,23 +589,24 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
     tokens that chose an expert elsewhere; their products are zero, so
     they add nothing and take no gradient to a weight.  A loop with a
     traced trip count has no reverse mode, so the backward pass is the
-    same walk again (``_walk_blocks_bwd``), each block's gate and up
-    products computed anew.  ``scope(name)`` names the three parts of a
-    block for a device trace: ``dispatch``, ``products`` and ``combine``.
+    same walk again (``_walk_blocks_bwd``), each block's in-products
+    (gate and up, or up) computed anew.  ``scope(name)`` names the three
+    parts of a block for a device trace: ``dispatch``, ``products`` and
+    ``combine``.
 
     ``return_residuals``: return ``((y, rows), residuals)``, where
-    residuals are ``idx`` and the first block's gate and up products
-    ``h``, ``u`` ([block rows, F] each in x's dtype).  ``residuals``: such
+    residuals are ``idx`` and the first block's in-products (``h``, ``u``,
+    or ``u`` alone; [block rows, F] each in x's dtype).  ``residuals``: such
     a tuple from an earlier call on the same inputs (a recompute region's
     re-run: ``ops/control_flow.py``).  The call then plans from that idx
     and not from its own: a re-run whose router chose otherwise by a
     rounding (a near tie in the top k) would pair the kept rows with other
     tokens.  Its gates are taken to the kept choices' places
     (:func:`_gates_in_places`).  The first block runs its down product
-    alone, and its backward runs six transposed products from ``h`` and
-    ``u`` and no forward one; the blocks after the first are computed
-    anew in the backward pass as without them."""
-    held = w_gate.shape[0]
+    alone, and its backward runs the transposed products (six, or four
+    for relu²) from them and no forward one; the blocks after the first
+    are computed anew in the backward pass as without them."""
+    held = w_up.shape[0]
     size = block_rows(idx.size, held, total)
     kept = None
     with scope("dispatch"):
@@ -602,13 +617,13 @@ def held_experts_ffn(x, idx, gates, w_gate, w_up, w_down, first=0,
         order, rows = plan_held_rows(idx, first, held)
         # padded places lie past every routed row: choice 0, which adds 0
         order = jnp.pad(order, (0, -order.size % size))
-    weights = (w_gate.astype(x.dtype), w_up.astype(x.dtype),
-               w_down.astype(x.dtype))
+    *w_in, w_down = (w.astype(x.dtype) for w in (w_gate, w_up, w_down)
+                     if w is not None)
+    w_in = tuple(w_in)
     if return_residuals and kept is None:
-        kept = _first_products(scope, size, x, gates, *weights[:2], order,
-                               rows)
-    out = _walk_blocks(scope, size, x, gates, *weights, order, rows,
-                       None if kept is None else tuple(kept))
+        kept = _first_products(scope, size, x, gates, w_in, order, rows)
+    out = _walk_blocks(scope, size, activation, x, gates, w_in, w_down,
+                       order, rows, None if kept is None else tuple(kept))
     if return_residuals:
         return (out, rows), (idx, *kept)
     return out, rows

@@ -244,6 +244,37 @@ def test_expert_layer_under_a_region(chip):
             < anew.memory_analysis().temp_size_in_bytes + crossing)
 
 
+def test_relu2_expert_layer_under_a_region(chip):
+    """The nemotron cell's expert layer (16,384 tokens, top 6, 8 of 128
+    experts held, widths 2688 / 1856, bf16, relu² experts with no gate)
+    as a recompute region runs it: the forward keeps the choices and the
+    first block's up product alone, and the backward runs from it: the
+    forward's first block's up and down products and the loop's two, the
+    backward's four transposed products and the loop's body its five."""
+    t, d, f, k, held, total = 16384, 2688, 1856, 6, 8, 128
+
+    def step(x, idx, gates, up, down):
+        (out, _), kept = held_experts_ffn(
+            x, idx, gates, None, up, down, total=total,
+            return_residuals=True, activation="relu2")
+        assert [v.shape for v in kept[1:]] == [
+            (block_rows(t * k, held, total), f)]
+        kept = jax.lax.optimization_barrier(kept)
+        return out, jax.grad(lambda x, gates, up, down: jnp.sum(
+            held_experts_ffn(x, idx, gates, None, up, down, total=total,
+                             residuals=kept, activation="relu2")[0]
+            .astype(F32) * jax.lax.stop_gradient(x).astype(F32)),
+            argnums=(0, 1, 2, 3))(x, gates, up, down)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in (
+        ((t, d), BF16), ((t, k), I32), ((t, k), F32), ((held, d, f), BF16),
+        ((held, f, d), BF16))]
+    compiled = jax.jit(step).lower(*args).compile()
+    assert pallas.pallas_kernels_in(compiled.as_text()) == {
+        "ragged-dot-gmm": 1 + 1 + 2 + 1, "ragged-dot-gmm-t": 2 + 2,
+        "ragged-dot-tgmm": 2 + 2}
+
+
 @pytest.mark.parametrize("m,d,f,g", [(18432, 2048, 768, 16),
                                      (24576, 2304, 896, 8)])
 @pytest.mark.parametrize("mode", ["forward", "lhs_grad", "rhs_grad"])
@@ -276,6 +307,22 @@ def test_ssd_scan(chip, grad):
     n = _kernels_in(fn, chip, ((1, 8192, 64, 64), BF16), ((1, 8192, 64), F32),
                     ((64,), F32), ((1, 8192, 128), BF16),
                     ((1, 8192, 128), BF16), ((64,), F32))
+    assert n == (2 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True])
+def test_ssd_scan_with_groups(chip, grad):
+    """The nemotron cell's scan (2 x 8,192 positions, 64 heads of 64, B
+    and C in 8 groups of a state of 128, chunks of 128, bf16): a head
+    block a group, each reading its group's lanes."""
+    def fn(*a):
+        return ssd_scan(*a, chunk=128)
+
+    if grad:
+        fn = _grad_sum(fn, (0, 1, 2, 3, 4, 5))
+    n = _kernels_in(fn, chip, ((2, 8192, 64, 64), BF16), ((2, 8192, 64), F32),
+                    ((64,), F32), ((2, 8192, 8, 128), BF16),
+                    ((2, 8192, 8, 128), BF16), ((64,), F32))
     assert n == (2 if grad else 1)
 
 

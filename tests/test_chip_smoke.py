@@ -376,6 +376,34 @@ def test_phase_ssd_scan_at_a_small_size(chip_smoke, capsys, monkeypatch):
     assert "ssd_scan" in str(exc.value.code) and "db" in str(exc.value.code)
 
 
+def test_phase_ssd_scan_with_groups_at_a_small_size(chip_smoke, capsys,
+                                                    monkeypatch):
+    """The probe with B and C in groups, as the nemotron cell runs it, in
+    interpret mode: 16 heads of 64 in 2 groups (a head block of 8 a
+    group), a state of 16, chunks of 128 over 384 positions, held to
+    ``reference/nemotron.py``'s recurrence; and a backward kernel whose dC
+    is half again too large fails it."""
+    import importlib
+
+    S = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    small = dict(t=384, heads=16, d_head=64, state=16, chunk=128, groups=2)
+    rel = chip_smoke.phase_ssd_scan(**small)
+    (line,) = _phase_lines(capsys)
+    assert line["phase"] == "ssd_scan" and line["groups"] == 2
+    assert max(rel.values()) < chip_smoke.SSD_REL_L2_TOL / 3
+    real = S._ssd_bwd
+
+    def wrong_dc(*a):
+        dx, ddt, dcum, db, dc = real(*a)
+        return dx, ddt, dcum, db, 1.5 * dc
+
+    monkeypatch.setattr(S, "_ssd_bwd", wrong_dc)
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.phase_ssd_scan(**small)
+    assert "dc" in str(exc.value.code)
+
+
 def test_phase_grouped_dot_at_a_small_size(chip_smoke, capsys, monkeypatch):
     """The probe of the grouped kernels in interpret mode at a block of
     512 rows, widths 384 and 256, 4 groups: six products, each within

@@ -17,6 +17,7 @@ from chipbench.selftest.test_program_spans import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_kanana import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_mellum2 import *  # noqa: E402,F401,F403
 from chipbench.selftest.test_granite import *  # noqa: E402,F401,F403
+from chipbench.selftest.test_nemotron import *  # noqa: E402,F401,F403
 
 from chipbench import manifest as _mf  # noqa: E402
 from chipbench.selftest import test_mellum2 as _mellum2  # noqa: E402

@@ -241,10 +241,23 @@ def test_the_mixer_starts_as_mamba_ssm_starts_it():
 
 
 def test_only_one_group_and_no_projection_bias_are_built():
-    for bad in ({"mamba_n_groups": 2}, {"mamba_proj_bias": True},
-                {"mamba_expand": 3}):
-        with fluid.unique_name.guard(), pytest.raises(ValueError):
-            decoder.build_train(dict(CFG, **bad), seq_len=16)
+    """A projection bias is refused.  Groups of B and C are built since
+    Nemotron-H (``tests/test_nemotron.py``), and the mixer's width is
+    ``mamba_n_heads * mamba_d_head`` whatever ``mamba_expand`` says; at
+    the default of one group the scan takes B and C as [B, T, N] and the
+    gated norm normalises the whole width, as granite's layers do."""
+    with fluid.unique_name.guard(), pytest.raises(ValueError):
+        decoder.build_train(dict(CFG, mamba_proj_bias=True), seq_len=16)
+    for cfg, groups in ((CFG, 1), (dict(CFG, mamba_n_groups=2), 2),
+                        (dict(CFG, mamba_expand=3), 1)):
+        with fluid.unique_name.guard():
+            main = decoder.build_train(cfg, seq_len=16)[0]
+        ops = [op for blk in main.blocks for op in blk.ops]
+        scan = [op for op in ops if op.type == "ssd_scan"][0]
+        b = scan.block._find_var_recursive(scan.input("B")[0])
+        assert len(b.shape) == (3 if groups == 1 else 4)
+        norm = [op for op in ops if op.type == "rms_norm_gated"][0]
+        assert ("group_size" in norm.attrs) == (groups > 1)
 
 
 # -- the model against the reference -------------------------------------------
